@@ -430,8 +430,6 @@ def sharded_es_tell(
         raise ValueError(
             f"sharded_es_tell is single-objective; got fitness {fitness.shape}"
         )
-    from ..utils.compat import shard_map  # deferred: utils import cycle-safe
-
     fields = tuple(algorithm.sharded_pop_fields)
     rows = {name: getattr(state, name) for name in fields}
     # global 0-based ranks as the scatter-inverse of ONE stable argsort
@@ -449,7 +447,7 @@ def sharded_es_tell(
             algorithm.pop_moments(rows_local, w_local), axis_name
         )
 
-    moments = shard_map(
+    moments = jax.shard_map(
         island,
         mesh=mesh,
         in_specs=(
@@ -641,8 +639,6 @@ class ShardedES:
                 for name in fields
             }
         else:
-            from ..utils.compat import shard_map  # deferred (cycle-safe)
-
             axis = self.axis_name
             # n_shards may exceed the device count (shrunken survivor
             # mesh resuming a wider run's sampling law): device d owns
@@ -672,7 +668,7 @@ class ShardedES:
                     },
                 )
 
-            pop, art = shard_map(
+            pop, art = jax.shard_map(
                 island,
                 mesh=self.mesh,
                 # the state rides in under its own field annotations (the

@@ -41,14 +41,6 @@ from jax.experimental import pallas as pl
 
 from ..utils.common import dominate_relation
 
-try:  # pltpu imports fail on builds without TPU support compiled in
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
 # Default tiles: 512 rows (16 words) x 2048 lanes — best of the sweep at
 # n=20000 (6.32 ms vs 6.90 for 256x512; every config within ~8%, the op is
 # compute-bound). VMEM per cell ~6 MB (dom + masks + words); 1024x4096
@@ -187,11 +179,6 @@ def packed_dominance(
             every backend.
         interpret: run the kernel in interpreter mode (CPU testing).
     """
-    if use_pallas and not (_HAS_PLTPU or interpret):
-        raise RuntimeError(
-            "use_pallas=True but jax.experimental.pallas.tpu is unavailable "
-            "in this jax build; pass interpret=True or use the fallback"
-        )
     if use_pallas:  # the fallback ignores tiling entirely
         if tile_i <= 0 or tile_i % 32 != 0:
             raise ValueError(

@@ -33,13 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
-
 # environments in SoA form: state is a dict of per-env component arrays
 SoAState = Dict[str, jax.Array]
 
@@ -398,10 +391,6 @@ def fused_rollout(
     Returns:
         ``(episodes * n,)`` total rewards, episode-major.
     """
-    if not (_HAS_PLTPU or interpret):
-        raise RuntimeError(
-            "fused_rollout needs pallas TPU support (or interpret=True)"
-        )
     if tile % (8 * _LANES) != 0:
         raise ValueError(f"tile must be a multiple of {8 * _LANES}, got {tile}")
     n, dim = theta.shape

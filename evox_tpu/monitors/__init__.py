@@ -5,7 +5,6 @@ from .checkpoint_monitor import CheckpointMonitor
 from .profiler import StepTimerMonitor, trace as profiler_trace
 from .telemetry import TelemetryMonitor, TelemetryState
 from .lineage import LineageMonitor, LineageState
-from .common import backend_supports_callbacks
 from . import profiler
 
 __all__ = [
@@ -19,7 +18,6 @@ __all__ = [
     "TelemetryState",
     "LineageMonitor",
     "LineageState",
-    "backend_supports_callbacks",
     "profiler_trace",
     "profiler",
 ]

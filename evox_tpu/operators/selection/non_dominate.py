@@ -24,7 +24,6 @@ from ...core.distributed import POP_AXIS
 from ...kernels.dominance import pack_dominator_rows, packed_dominance
 from ...kernels.topk import default_use_kernel, partial_topk
 from ...utils.common import dominate_relation
-from ...utils.compat import shard_map
 
 INF = jnp.inf
 
@@ -235,7 +234,7 @@ def _non_dominated_sort_sharded(
     # check_vma=False: every output is derived from psum results (hence
     # genuinely replicated), but the device-varying dynamic_slice start
     # defeats the static replication analysis
-    rank, cut = shard_map(
+    rank, cut = jax.shard_map(
         island,
         mesh=mesh,
         in_specs=(P(axis_name), P()),

@@ -266,7 +266,10 @@ def spawn_local_workers(
 
     Returns the ``multiprocessing.Process`` handles (daemonized; join or
     let ``ProcessRolloutFarm.shutdown`` end them). Spawn start-method so
-    workers never inherit an initialized JAX backend."""
+    workers never inherit an initialized JAX backend, and CPU-pinned: a
+    local worker shares its machine with the coordinator, which may hold
+    the accelerator (a chip belongs to one process). Remote workers (the
+    CLI) own their machine and keep jax's default."""
     import multiprocessing as mp
 
     ctx = mp.get_context("spawn")
@@ -274,8 +277,20 @@ def spawn_local_workers(
         ctx.Process(target=worker_main, args=(address, authkey), daemon=True)
         for _ in range(n)
     ]
-    for p in procs:
-        p.start()
+    # a spawned child reads os.environ as it stands at start(), and the
+    # package import alone initializes its backend — so the pin has to be
+    # in the environment, not in the child's code. This process is not
+    # affected: jax read the variable when it was imported.
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for p in procs:
+            p.start()
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
     return procs
 
 

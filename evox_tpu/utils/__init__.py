@@ -15,7 +15,7 @@ from .common import (
 from .aggregation import AggregationFunction
 from .io import to_x32_if_needed, x32_func_call
 from .optimizers import clipup, make_optimizer
-from . import compat
+from .compile_cache import enable_compile_cache
 
 __all__ = [
     "TreeAndVector",
@@ -35,5 +35,5 @@ __all__ = [
     "AggregationFunction",
     "clipup",
     "make_optimizer",
-    "compat",
+    "enable_compile_cache",
 ]

@@ -31,7 +31,7 @@ from evox_tpu.problems.neuroevolution import (
     native_available,
 )
 from evox_tpu.problems.supervised import DatasetProblem, InMemoryDataLoader
-from evox_tpu.utils import TreeAndVector
+from evox_tpu.utils import TreeAndVector, enable_compile_cache
 
 
 def host_env_cartpole():
@@ -84,5 +84,6 @@ def supervised_with_validation():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     host_env_cartpole()
     supervised_with_validation()

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from evox_tpu import IslandWorkflow, create_mesh
 from evox_tpu.algorithms.so.de import DE
 from evox_tpu.problems.numerical import Ackley
+from evox_tpu.utils import enable_compile_cache
 
 
 def run(migrate_every, mesh=None):
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

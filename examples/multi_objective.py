@@ -12,6 +12,7 @@ from evox_tpu.algorithms.mo import NSGA2
 from evox_tpu.metrics import igd
 from evox_tpu.monitors import EvalMonitor, PopMonitor
 from evox_tpu.problems.numerical import ZDT1
+from evox_tpu.utils import enable_compile_cache
 
 
 def main():
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

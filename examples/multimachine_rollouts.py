@@ -24,6 +24,7 @@ from evox_tpu.problems.neuroevolution import (
     spawn_local_workers,
 )
 from evox_tpu.problems.neuroevolution.hostenv import NumpyCartPoleVec
+from evox_tpu.utils import enable_compile_cache
 from evox_tpu.workflows.pipelined import run_host_pipelined
 
 D_IN, D_H, D_OUT = 4, 8, 2
@@ -93,4 +94,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,7 +12,7 @@ from evox_tpu.algorithms.so.es import OpenES
 from evox_tpu.monitors import EvalMonitor
 from evox_tpu.problems.neuroevolution import PolicyRolloutProblem, mlp_policy
 from evox_tpu.problems.neuroevolution.control import envs
-from evox_tpu.utils import TreeAndVector, rank_based_fitness
+from evox_tpu.utils import TreeAndVector, enable_compile_cache, rank_based_fitness
 
 
 def main():
@@ -48,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

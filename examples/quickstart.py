@@ -10,6 +10,7 @@ from evox_tpu import StdWorkflow
 from evox_tpu.algorithms.so.pso import PSO
 from evox_tpu.monitors import EvalMonitor
 from evox_tpu.problems.numerical import Ackley
+from evox_tpu.utils import enable_compile_cache
 
 
 def main():
@@ -32,4 +33,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

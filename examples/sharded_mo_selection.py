@@ -23,6 +23,7 @@ from evox_tpu.algorithms.mo import NSGA2
 from evox_tpu.core.distributed import create_mesh
 from evox_tpu.metrics import igd
 from evox_tpu.problems.numerical import LSMOP1
+from evox_tpu.utils import enable_compile_cache
 
 
 def run(mesh, d, m, pop, gens):
@@ -53,4 +54,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -24,5 +24,18 @@ if "xla_backend_optimization_level" not in _flags:  # allow override
 os.environ["XLA_FLAGS"] = _flags
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
+
+
+@pytest.fixture
+def ceilings():
+    """Stand-in peaks for roofline tests: the CPU has no entry in
+    ``core.xla_cost.CHIP_CEILINGS`` (no default peak exists), so a test
+    that wants fractions of peak hands the analyzer its own."""
+    return {
+        "mxu_bf16_tflops": 1.0,
+        "hbm_gbps": 10.0,
+        "source": "tests/conftest.py stand-in, not a device's peaks",
+    }

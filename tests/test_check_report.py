@@ -10,7 +10,7 @@ import pathlib
 import jax
 import jax.numpy as jnp
 
-from evox_tpu import StdWorkflow, instrument, run_report
+from evox_tpu import CostAnalyzer, StdWorkflow, instrument, run_report
 from evox_tpu.algorithms.so.es import CMAES
 from evox_tpu.monitors import TelemetryMonitor
 from evox_tpu.problems.numerical import Sphere
@@ -31,10 +31,17 @@ def _fresh_report(analyze):
         Sphere(),
         monitors=(tm,),
     )
-    rec = instrument(wf, analyze=analyze)
+    rec = instrument(wf)
     state = wf.init(jax.random.PRNGKey(0))
     state = wf.run(state, 4)
-    return run_report(wf, state, recorder=rec)
+    # the CPU has no entry in CHIP_CEILINGS (no default peak exists):
+    # the analyzer is handed stand-in peaks
+    analyzer = (
+        CostAnalyzer(ceilings={"mxu_bf16_tflops": 1.0, "hbm_gbps": 10.0})
+        if analyze
+        else None
+    )
+    return run_report(wf, state, recorder=rec, analyzer=analyzer)
 
 
 def test_fresh_run_report_validates():

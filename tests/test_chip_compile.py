@@ -1,0 +1,184 @@
+"""Compiles for a described TPU v5e — the only file that describes the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). Interpret-mode
+tests cannot see what Mosaic refuses (block shapes off the (8, 128) tiling,
+float iotas, too much VMEM) or what the SPMD partitioner inserts; these
+compiles can, at real widths, at no chip time. Nothing runs: a compile that
+passes is not a chip run.
+
+Only one process may load the TPU's library, so the topology is described
+inside a module-scoped fixture (never at import, never ``autouse``, never in
+``conftest.py``), every compile happens in this process, and every such test
+lives in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from evox_tpu import StdWorkflow
+from evox_tpu.algorithms.so.pso import CSO
+from evox_tpu.core.distributed import POP_AXIS, state_sharding
+from evox_tpu.problems.numerical import Ackley
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip would be written to the persistent
+    cache but cannot be read back without a chip (the next one warns and
+    compiles again): keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _shapes_on(tree, sharding):
+    """The tree's shapes, placed (there is no device to hold an array):
+    ``sharding`` is one sharding for every leaf or a matching tree of them."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, sharding
+    )
+
+
+# ------------------------------------------------------------------ kernels
+
+
+def _walker_kernel(n=16384, hidden=64, T=100):
+    """``fused_mlp_rollout`` at 244-64-64-17 through the problem that calls it."""
+    from evox_tpu.kernels.rollout_mlp import chain_walker_planes
+    from evox_tpu.problems.neuroevolution import PolicyRolloutProblem, mlp_policy
+
+    penv = chain_walker_planes(max_steps=T)
+    env = penv.base
+    init_params, apply = mlp_policy((env.obs_dim, hidden, hidden, env.act_dim))
+    prob = PolicyRolloutProblem(
+        apply, env, num_episodes=1, stochastic_reset=False,
+        fused_planes=penv, fused_interpret=False,
+    )
+    pop = jax.eval_shape(
+        lambda k: jax.vmap(init_params)(jax.random.split(k, n)), jax.random.PRNGKey(0)
+    )
+    return prob.evaluate, (jax.eval_shape(prob.init, jax.random.PRNGKey(0)), pop)
+
+
+def _pendulum_kernel(n=65536, episodes=2, hidden=16, T=200):
+    """``fused_rollout`` at hidden 16 through the problem that calls it."""
+    from evox_tpu.kernels.rollout import pendulum_soa
+    from evox_tpu.problems.neuroevolution import PolicyRolloutProblem, flat_mlp_policy
+
+    soa = pendulum_soa(max_steps=T)
+    apply, dim = flat_mlp_policy(soa.base.obs_dim, hidden, soa.base.act_dim)
+    prob = PolicyRolloutProblem(
+        apply, soa.base, num_episodes=episodes, stochastic_reset=False,
+        early_exit=False, fused_env=soa, fused_interpret=False,
+    )
+    pop = jax.ShapeDtypeStruct((n, dim), jnp.float32)
+    return prob.evaluate, (jax.eval_shape(prob.init, jax.random.PRNGKey(0)), pop)
+
+
+def _topk_kernel(n=4096, k=128):
+    # the (20000, 1000) and (65536, 1024) shapes compile too, in ~25 s each:
+    # a builder's rehearsal, not the suite's
+    from evox_tpu.kernels.topk import partial_topk
+
+    fn = lambda v: partial_topk(v, k, use_kernel=True)  # noqa: E731
+    return fn, (jax.ShapeDtypeStruct((n,), jnp.float32),)
+
+
+def _dominance_kernel(n=20000, m=3):
+    from evox_tpu.kernels.dominance import packed_dominance
+
+    fn = lambda f: packed_dominance(f, use_pallas=True)  # noqa: E731
+    return fn, (jax.ShapeDtypeStruct((n, m), jnp.float32),)
+
+
+KERNELS = {
+    "fused_mlp_rollout-244x64x64x17-n16384-T100": _walker_kernel,
+    "fused_rollout-h16-n65536x2-T200": _pendulum_kernel,
+    "partial_topk-n4096-k128": _topk_kernel,
+    "packed_dominance-n20000-m3": _dominance_kernel,
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    """Mosaic accepts the kernel at its real width, and it IS the kernel:
+    the compiled text holds the custom call (an envelope that hands the
+    shape to the XLA path must never pass for the kernel)."""
+    fn, args = KERNELS[name]()
+    compiled = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_topk_outside_envelope_is_not_the_kernel(one_chip, no_persistent_cache):
+    """``k > block_size`` goes to the XLA path even under ``use_kernel=True``
+    (a tested contract of kernels/topk.py). At NSGA-II's own shape — pop
+    10,000, merged n 20,000, k 10,000 — the compiled program therefore holds
+    no kernel: an A/B of ``use_kernel`` there compares XLA with XLA."""
+    from evox_tpu.kernels.topk import partial_topk
+
+    # a small n keeps this a one-second compile; the envelope is on k alone
+    fn = lambda v: partial_topk(v, 1280, use_kernel=True)  # noqa: E731
+    arg = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    assert "tpu_custom_call" not in jax.jit(fn).lower(arg).compile().as_text()
+
+
+# --------------------------------------------------------------- four chips
+
+
+def _cso_step(mesh, pop=4096, dim=1024):
+    """The steady (``first_step=False``) CSO / Ackley step program and its
+    state's shapes; the init-generation peel of a row-wise problem rightly
+    holds no collective."""
+    algo = CSO(lb=-32.0 * jnp.ones(dim), ub=32.0 * jnp.ones(dim), pop_size=pop)
+    wf = StdWorkflow(algo, Ackley(), mesh=mesh)
+    state = jax.eval_shape(wf.init, jax.random.PRNGKey(0)).replace(first_step=False)
+    return jax.jit(wf._step_impl), state
+
+
+def test_sharded_cso_step_compiles_for_four_chips(topo, one_chip, no_persistent_cache):
+    """The population-sharded step partitions for a 2x2 v5e: the pair
+    shuffle crosses shards, so the text holds collectives, and each device
+    is handed about a quarter of the single-device program's arguments."""
+    mesh = Mesh(np.array(topo.devices), (POP_AXIS,))
+    step4, state = _cso_step(mesh)
+    compiled4 = step4.lower(_shapes_on(state, state_sharding(state, mesh))).compile()
+    text = compiled4.as_text()
+    assert any(
+        c in text for c in ("all-gather", "all-reduce", "collective-permute", "all-to-all")
+    )
+
+    step1, state1 = _cso_step(None)
+    compiled1 = step1.lower(_shapes_on(state1, one_chip)).compile()
+    per_device = compiled4.memory_analysis().argument_size_in_bytes
+    whole = compiled1.memory_analysis().argument_size_in_bytes
+    assert whole >= 2 * 4096 * 1024 * 4  # population and velocity, float32
+    assert 0.2 < per_device / whole < 0.3, (per_device, whole)

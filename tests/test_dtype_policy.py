@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from evox_tpu import StdWorkflow, instrument, run_report
+from evox_tpu import CostAnalyzer, StdWorkflow, instrument, run_report
 from evox_tpu.algorithms.mo import NSGA2
 from evox_tpu.algorithms.so.es import CMAES
 from evox_tpu.algorithms.so.pso import CSO, PSO
@@ -291,12 +291,14 @@ def test_donation_shows_alias_bytes_in_memory_analysis():
     assert int(ma_p.alias_size_in_bytes) == 0
 
 
-def test_run_report_roofline_carries_policy_and_donation():
+def test_run_report_roofline_carries_policy_and_donation(ceilings):
     wf = _wf_cso(dtype_policy=BF16_STORAGE, donate_carries=True)
-    rec = instrument(wf, analyze=True)
+    rec = instrument(wf)
     state = wf.init(jax.random.PRNGKey(0))
     state = wf.run(state, 3)
-    report = run_report(wf, state, recorder=rec)
+    report = run_report(
+        wf, state, recorder=rec, analyzer=CostAnalyzer(ceilings=ceilings)
+    )
     roof = report["roofline"]
     assert roof["dtype_policy"] == {
         "storage": "bfloat16",
@@ -307,9 +309,11 @@ def test_run_report_roofline_carries_policy_and_donation():
     assert roof["donation"]["alias_bytes"]["run"] > 0
     # and the default workflow reports itself honestly too
     wf0 = _wf_cso()
-    rec0 = instrument(wf0, analyze=True)
+    rec0 = instrument(wf0)
     s0 = wf0.run(wf0.init(jax.random.PRNGKey(0)), 3)
-    roof0 = run_report(wf0, s0, recorder=rec0)["roofline"]
+    roof0 = run_report(
+        wf0, s0, recorder=rec0, analyzer=CostAnalyzer(ceilings=ceilings)
+    )["roofline"]
     assert roof0["dtype_policy"]["active"] is False
     assert roof0["donation"]["donate_carries"] is False
 

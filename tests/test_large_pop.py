@@ -51,6 +51,7 @@ from evox_tpu import (
     ShardedES,
     StdWorkflow,
     create_mesh,
+    CostAnalyzer,
     instrument,
     run_report,
 )
@@ -487,19 +488,21 @@ def test_sharded_custom_axis_name():
 
 
 @pytest.mark.slow
-def test_run_report_sharding_section():
+def test_run_report_sharding_section(ceilings):
     """The v5 roofline.sharding subsection: per-device peak < full-pop
     bytes for an instrumented sharded run, and the schema validator
     accepts the whole report."""
     mesh = _mesh()
     wf = _sharded_wf(SepCMAES, 64, 1 << 14, mesh)
-    rec = instrument(wf, analyze=True, block_dispatch=True)
+    rec = instrument(wf, block_dispatch=True)
     s = wf.init(jax.random.PRNGKey(7))
     s = wf.run(s, 3)
     s = wf.run(s, 3)
     s = wf.run(s, 12)
     rec.fetch(s.algo.sigma, name="sigma")
-    report = run_report(wf, s, recorder=rec)
+    report = run_report(
+        wf, s, recorder=rec, analyzer=CostAnalyzer(ceilings=ceilings)
+    )
     assert report["schema"] == "evox_tpu.run_report/v14"
     assert report["schema_version"] == 14
     shd = report["roofline"]["sharding"]

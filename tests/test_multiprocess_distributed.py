@@ -21,20 +21,6 @@ import textwrap
 import jax
 import pytest
 
-# jaxlib < 0.5 CPU backend refuses cross-process collectives outright
-# ("Multiprocess computations aren't implemented on the CPU backend"),
-# so the DCN-emulation story is untestable on those versions — skip, not
-# fail: the capability gap is the RUNTIME's (jaxlib), not the code's,
-# hence the gate reads jaxlib's version, not jax's.
-import jaxlib
-
-_JAXLIB_VER = tuple(int(x) for x in jaxlib.__version__.split(".")[:2])
-pytestmark = pytest.mark.skipif(
-    _JAXLIB_VER < (0, 5),
-    reason="CPU backend cannot run multiprocess collectives on jaxlib "
-    f"{jaxlib.__version__} (needs >= 0.5)",
-)
-
 MONITOR_WORKER = textwrap.dedent(
     """
     import os, sys

@@ -6,7 +6,7 @@ in tests/test_pod_chaos.py behind the ``pod_chaos`` marker. This file
 asserts everything the fault domain promises that a single process can
 witness:
 
-- classification folding (pod deadlines -> the PR-5 taxonomy),
+- classification folding (pod deadlines -> the PR-5 error classes),
 - the census / watchdog / drain plumbing,
 - the "zero new behavior when disabled" law (a pod-supervised
   single-process run is bit-identical to a plain run),
@@ -90,7 +90,7 @@ def _sharded_wf(mesh, n_shards, pop=32, dim=16):
 
 
 def test_classify_error_folds_pod_errors():
-    """ISSUE 14: the pod failures fold into the PR-5 taxonomy — barrier
+    """ISSUE 14: the pod failures fold into the PR-5 error classes — barrier
     and collective deadlines are `deadline`, a classified pod fault is
     `fatal` (no in-process rung can heal a pod; re-formation is the
     driver's job)."""

@@ -111,3 +111,34 @@ def test_process_farm_rejects_wrong_authkey():
             p.join(timeout=30)
             if p.is_alive():
                 p.kill()
+
+
+@pytest.mark.parametrize("parent_value", [None, "tpu"])
+def test_local_workers_start_pinned_to_the_cpu(monkeypatch, parent_value):
+    """One process per chip: a local worker shares its machine with the
+    coordinator, which may hold the accelerator. The child reads the
+    environment as it stands at start(), so JAX_PLATFORMS=cpu is there at
+    that moment — and the parent's own value is back afterwards."""
+    import multiprocessing as mp
+    import os
+
+    if parent_value is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", parent_value)
+    seen = []
+
+    class FakeProcess:
+        def __init__(self, **kwargs):
+            pass
+
+        def start(self):
+            seen.append(os.environ.get("JAX_PLATFORMS"))
+
+    class FakeContext:
+        Process = FakeProcess
+
+    monkeypatch.setattr(mp, "get_context", lambda method: FakeContext)
+    procs = spawn_local_workers(("127.0.0.1", 1), 3)
+    assert len(procs) == 3 and seen == ["cpu"] * 3
+    assert os.environ.get("JAX_PLATFORMS") == parent_value

@@ -2,7 +2,7 @@
 checkpoint replay, degradation, and topology-portable resume.
 
 Every fault is injected deterministically at the call boundary
-(tests/_chaos.py::FlakyDispatch — no real tunnel), so the assertions are
+(tests/_chaos.py::FlakyDispatch — no real backend fault), so the assertions are
 exact: a supervised run that healed N transients and one hang produces
 BIT-identical final state and telemetry rings to the same supervised run
 with no faults; an 8-device checkpoint resumes on 4 and 1 devices and

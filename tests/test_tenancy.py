@@ -689,18 +689,20 @@ def test_run_report_tenancy_section_valid():
     assert check_report.validate_run_report(bad) != []
 
 
-def test_fleet_roofline_cites_frac_peak():
+def test_fleet_roofline_cites_frac_peak(ceilings):
     """The AOT roofline of the FUSED FLEET step/run carries achieved
     frac_peak_* rates (ISSUE 8 acceptance) via the differenced slope."""
-    from evox_tpu import instrument
+    from evox_tpu import CostAnalyzer, instrument
 
     wf = VectorizedWorkflow(_cmaes(), Sphere(), n_tenants=N, hyperparams=HP)
-    rec = instrument(wf, analyze=True, block_dispatch=True)
+    rec = instrument(wf, block_dispatch=True)
     state = wf.init(_stacked_keys())
     state = wf.run(state, 5)
     state = wf.run(state, 5)
     state = wf.run(state, 50)
-    report = run_report(wf, state, recorder=rec)
+    report = run_report(
+        wf, state, recorder=rec, analyzer=CostAnalyzer(ceilings=ceilings)
+    )
     entry = report["roofline"]["entries"]["run"]
     assert entry["timing_method"] == "differenced"
     assert entry["frac_peak_compute"] is not None

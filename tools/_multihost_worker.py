@@ -864,7 +864,9 @@ class PodManager:
         self.timeout = float(timeout)
         self.env = dict(os.environ)
         self.env.pop("XLA_FLAGS", None)
-        self.env.pop("JAX_PLATFORMS", None)
+        # CPU pods only: never let a child reach an accelerator the
+        # parent may hold (the workers also pin themselves in main())
+        self.env["JAX_PLATFORMS"] = "cpu"
 
     @staticmethod
     def free_port() -> str:
