@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...core.algorithm import Algorithm
 from ...core.distributed import POP_AXIS
+from ...core.instrument import MERGE, scope
 from ...core.struct import PyTreeNode, field
 from ...operators.crossover.sbx import simulated_binary
 from ...operators.mutation.ops import polynomial
@@ -94,8 +95,9 @@ class GAMOAlgorithm(Algorithm):
         return off, state.replace(offspring=off, key=key)
 
     def tell(self, state: MOState, fitness: jax.Array) -> MOState:
-        merged_pop = jnp.concatenate([state.population, state.offspring], axis=0)
-        merged_fit = jnp.concatenate([state.fitness, fitness], axis=0)
+        with scope(MERGE):
+            merged_pop = jnp.concatenate([state.population, state.offspring], axis=0)
+            merged_fit = jnp.concatenate([state.fitness, fitness], axis=0)
         pop, fit = self.select(state, merged_pop, merged_fit)
         return state.replace(population=pop, fitness=fit)
 

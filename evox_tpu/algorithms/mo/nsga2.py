@@ -24,6 +24,7 @@ from ...operators.selection.non_dominate import (
 from ...operators.selection.basic import tournament_multifit
 from jax.sharding import PartitionSpec as P
 from ...core.distributed import POP_AXIS
+from ...core.instrument import MERGE, SURVIVORS, scope
 from ...core.struct import field
 from .common import GAMOAlgorithm, MOState
 
@@ -70,8 +71,9 @@ class NSGA2(GAMOAlgorithm):
         return tournament_multifit(key, state.population, keys)
 
     def tell(self, state: NSGA2State, fitness: jax.Array) -> NSGA2State:
-        merged_pop = jnp.concatenate([state.population, state.offspring], axis=0)
-        merged_fit = jnp.concatenate([state.fitness, fitness], axis=0)
+        with scope(MERGE):
+            merged_pop = jnp.concatenate([state.population, state.offspring], axis=0)
+            merged_fit = jnp.concatenate([state.fitness, fitness], axis=0)
         order, ranks = rank_crowding_truncate(
             merged_fit,
             self.pop_size,
@@ -79,9 +81,11 @@ class NSGA2(GAMOAlgorithm):
             use_kernel=self.use_kernel,
             interpret=self.topk_interpret,
         )
-        fit_sel = merged_fit[order]
+        with scope(SURVIVORS):
+            fit_sel = merged_fit[order]
+            pop_sel = merged_pop[order]
         return state.replace(
-            population=merged_pop[order],
+            population=pop_sel,
             fitness=fit_sel,
             rank=ranks,
             # crowding for next generation's mating tournament is recomputed

@@ -15,6 +15,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from ....core.algorithm import Algorithm
+from ....core.instrument import GRADIENT, NOISE, PERTURB, UPDATE, scope
 from ....core.struct import PyTreeNode, field
 from .common import make_optimizer
 
@@ -71,6 +72,7 @@ class OpenES(Algorithm):
             key=key,
         )
 
+    @scope(NOISE)
     def _noise(self, k: jax.Array) -> jax.Array:
         if self.mirrored:
             half = jax.random.normal(k, (self.pop_size // 2, self.dim))
@@ -84,7 +86,9 @@ class OpenES(Algorithm):
         # workflow's shard_pop constraint on the emitted population (and
         # from the sharded fitness in tell's contraction) rather than
         # from a state-field annotation as before
-        pop = state.center + self.noise_stdev * self._noise(k)
+        noise = self._noise(k)
+        with scope(PERTURB):
+            pop = state.center + self.noise_stdev * noise
         return pop, state.replace(noise_key=k, key=key)
 
     def tell(self, state: OpenESState, fitness: jax.Array) -> OpenESState:
@@ -93,24 +97,26 @@ class OpenES(Algorithm):
         # persistent (pop, dim) buffer — see OpenESState). Mirrored
         # sampling folds: noise.T @ f == half.T @ (f_pos - f_neg), so the
         # dominant transient is (pop/2, dim), not (pop, dim).
-        if self.mirrored:
-            half = jax.random.normal(
-                state.noise_key, (self.pop_size // 2, self.dim)
+        with scope(GRADIENT):
+            if self.mirrored:
+                half = jax.random.normal(
+                    state.noise_key, (self.pop_size // 2, self.dim)
+                )
+                m = self.pop_size // 2
+                grad = half.T @ (fitness[:m] - fitness[m:])
+            else:
+                noise = jax.random.normal(
+                    state.noise_key, (self.pop_size, self.dim)
+                )
+                grad = noise.T @ fitness
+            grad = grad / (self.pop_size * self.noise_stdev)
+        with scope(UPDATE):
+            updates, opt_state = self.optimizer.update(
+                grad, state.opt_state, state.center
             )
-            m = self.pop_size // 2
-            grad = half.T @ (fitness[:m] - fitness[m:])
-        else:
-            noise = jax.random.normal(
-                state.noise_key, (self.pop_size, self.dim)
-            )
-            grad = noise.T @ fitness
-        grad = grad / (self.pop_size * self.noise_stdev)
-        updates, opt_state = self.optimizer.update(grad, state.opt_state, state.center)
-        if not (isinstance(self.lr_scale, float) and self.lr_scale == 1.0):
-            # only reached when lr_scale was rebound (a traced tenant /
-            # multi-level hyperparameter, or an explicit non-1 float)
-            updates = jax.tree.map(lambda u: u * self.lr_scale, updates)
-        return state.replace(
-            center=optax.apply_updates(state.center, updates),
-            opt_state=opt_state,
-        )
+            if not (isinstance(self.lr_scale, float) and self.lr_scale == 1.0):
+                # only reached when lr_scale was rebound (a traced tenant /
+                # multi-level hyperparameter, or an explicit non-1 float)
+                updates = jax.tree.map(lambda u: u * self.lr_scale, updates)
+            center = optax.apply_updates(state.center, updates)
+        return state.replace(center=center, opt_state=opt_state)

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attest import IntegrityError
+from .instrument import CHECKPOINT_SAVE, HOST_EVAL, span
 
 # Layering note: this module lives in core/ (it is workflow-shape-agnostic
 # infrastructure: any object with pipeline_ask/pipeline_tell or run(state,
@@ -979,20 +980,22 @@ class GenerationExecutor:
             # The save gets its OWN (larger) deadline — a full host
             # gather legitimately outlasts a chunk dispatch, and the
             # chunk bound would abort a healthy pod at every cadence
-            if pod is not None:
-                pod.supervised(
-                    lambda: ckpt.save(state),
-                    entry="checkpoint",
-                    deadline_s=getattr(pod, "checkpoint_deadline_s", None),
-                )
-            else:
-                ckpt.save(state)
+            with span(CHECKPOINT_SAVE):
+                if pod is not None:
+                    pod.supervised(
+                        lambda: ckpt.save(state),
+                        entry="checkpoint",
+                        deadline_s=getattr(pod, "checkpoint_deadline_s", None),
+                    )
+                else:
+                    ckpt.save(state)
             self._span("io:checkpoint", "save", t0, self._clock() - t0,
                        generation=int(state.generation))
             return
 
         def save():
-            ckpt.save(state)
+            with span(CHECKPOINT_SAVE):
+                ckpt.save(state)
             self._span("io:checkpoint", "save", t0, self._clock() - t0,
                        generation=int(state.generation))
 
@@ -1061,9 +1064,12 @@ class GenerationExecutor:
             def run_eval():
                 t0 = self._clock()
                 try:
-                    if host_eval is not None:
-                        return host_eval(pstate, cand, eval_chunk)
-                    return chunked_evaluate(wf.problem, pstate, cand, eval_chunk)
+                    with span(HOST_EVAL):
+                        if host_eval is not None:
+                            return host_eval(pstate, cand, eval_chunk)
+                        return chunked_evaluate(
+                            wf.problem, pstate, cand, eval_chunk
+                        )
                 finally:
                     dt = self._clock() - t0
                     with self._lock:

@@ -40,6 +40,15 @@ layer (core/xla_cost.py):
   as a Chrome trace-event JSON timeline (Perfetto / chrome://tracing),
   with TelemetryMonitor rings and farm health counters as counter tracks.
 
+The module also holds the one table of names the program writes into a
+profiler trace, always: ``scope`` (``jax.named_scope``: the layer each
+device operation belongs to, in its ``op_name``) and ``span``
+(``jax.profiler.TraceAnnotation``: the entry points' host time on the
+device trace's clock). The recorder's wall-clock bookkeeping runs on
+``time.perf_counter``, a clock the device does not share;
+``write_chrome_trace`` is the timeline for runs without the profiler, the
+profiler's trace the one on the device's clock.
+
 ``run_report`` merges this host-side summary with the device counters of
 any attached monitor exposing ``report(mstate)`` (TelemetryMonitor) into
 one JSON-serializable dict — plus, when a :class:`~evox_tpu.core.
@@ -63,6 +72,10 @@ import numpy as np
 from .xla_cost import CostAnalyzer, abstract_signature, roofline_section
 
 __all__ = [
+    "SCOPES",
+    "SPANS",
+    "scope",
+    "span",
     "DispatchRecorder",
     "RetraceError",
     "instrument",
@@ -71,6 +84,86 @@ __all__ = [
     "write_chrome_trace",
     "write_report_jsonl",
 ]
+
+
+# ---------------------------------------------------------------- names
+# The one table of names. Device side: ``scope`` puts a name into the
+# ``op_name`` of every HLO operation traced under it, which the profiler
+# writes beside each device event (the ``tf_op`` stat). Host side: ``span``
+# writes onto the calling thread's line of the profiler's host plane (the
+# main thread's is named after the executable), on the device trace's clock.
+# The benchmark's readers (benchmark/lib/scoped.py) match these strings, so
+# they are written here once and nowhere else.
+
+ASK = "evox.ask"
+EVALUATE = "evox.evaluate"
+TELL = "evox.tell"
+CONSTRAIN = "evox.constrain"
+MONITORS = "evox.monitors"
+# parts, as children of the above, where a cell's trace shows device time
+DECODE = "decode"  # evaluate: pop_transforms, the flat genome cut into layers
+LAYOUT = "layout"  # evaluate: the layers transposed to the kernel's planes
+RESET = "reset"  # evaluate: env reset, its keys and the broadcast
+ROLLOUT_KERNEL = "rollout_kernel"  # evaluate: the call into the Pallas rollout
+FIT_TRANSFORMS = "fit_transforms"  # tell: the workflow's fitness shaping
+NOISE = "noise"  # ask (ES): the normal draw and its mirrored concatenation
+PERTURB = "perturb"  # ask (ES): centre plus sigma times noise
+GRADIENT = "gradient"  # tell (ES): the noise drawn again and the contraction
+UPDATE = "update"  # tell (ES): the optimiser's step
+MATING = "mating"  # ask (GA): tournament selection
+CROSSOVER = "crossover"  # ask (GA)
+MUTATION = "mutation"  # ask (GA)
+MERGE = "merge"  # tell (MO): parents and offspring concatenated
+DOMINANCE_BUILD = "dominance_build"  # tell (MO): the packed dominance matrix
+PEEL = "peel"  # tell (MO): the front-peeling loop
+CROWDING = "crowding"  # tell (MO): crowding distance
+SURVIVORS = "survivors"  # tell (MO): the truncation's sort and gathers
+
+SCOPES = (ASK, EVALUATE, TELL, CONSTRAIN, MONITORS)
+
+RUN = "evox:run"
+RUN_PEEL = "evox:run/peel"  # the eager first wf.step, when it happens
+RUN_LOOP = "evox:run/loop"  # the trip count's transfer and the loop's dispatch
+STEP = "evox:step"
+INIT = "evox:init"
+CHECKPOINT_SAVE = "evox:checkpoint/save"
+HOST_EVAL = "evox:executor/host_eval"
+FETCH = "evox:fetch"
+
+SPANS = (RUN, RUN_PEEL, RUN_LOOP, STEP, INIT, CHECKPOINT_SAVE, HOST_EVAL, FETCH)
+
+
+class scope(contextlib.ContextDecorator):
+    """Name the device operations traced inside the block, or inside the
+    function it decorates: a ``jax.named_scope``, which lands in each
+    operation's ``op_name`` and changes no instruction. Scopes nest into
+    a path (``evox.tell/peel``); under ``vmap``/``shard_map``/``remat``
+    jax wraps a component (``vmap(evox.ask)``)."""
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def _recreate_cm(self):
+        # as a decorator: a fresh scope each call (jax's keeps the name
+        # stack it replaced on the instance, so one instance is not
+        # re-entrant)
+        return jax.named_scope(self.name)
+
+    def __enter__(self):
+        self._cm = jax.named_scope(self.name)
+        return self._cm.__enter__()
+
+    def __exit__(self, *exc):
+        return self._cm.__exit__(*exc)
+
+
+def span(name: str, **args: Any) -> jax.profiler.TraceAnnotation:
+    """Mark a stretch of host time (``with span(RUN, n_steps=n):``): a
+    ``jax.profiler.TraceAnnotation`` on the profiler's host plane, on the
+    device trace's clock. With no profiler session active the annotation
+    records nothing: what is left is building the object and its
+    enter/exit, about 0.7 us a call (PERF.md section 6, PR 26)."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def sanitize_json(obj: Any) -> Any:
@@ -376,7 +469,8 @@ class DispatchRecorder:
         instrumented code should materialize device data, so every
         device-to-host byte is accounted."""
         t0 = self._clock()
-        host = jax.device_get(tree)
+        with span(FETCH, site=name):
+            host = jax.device_get(tree)
         dt = self._clock() - t0
         nbytes = int(
             sum(

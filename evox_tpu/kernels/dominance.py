@@ -218,6 +218,7 @@ def packed_dominance(
             ((n + pad_i) // 32, n + pad_j), jnp.int32
         ),
         interpret=interpret,
+        name="dominance_pack",
     )(x, y_t)
     packed = jax.lax.bitcast_convert_type(packed[:n_words, :n], jnp.uint32)
     count = jnp.sum(

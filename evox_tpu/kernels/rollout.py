@@ -473,6 +473,7 @@ def fused_rollout(
             (episodes, rows_pop, _LANES), theta.dtype
         ),
         interpret=interpret,
+        name="fused_rollout",
     )(theta_t, *state_3d.values())
     total = total.reshape(episodes, n_pad)[:, :n]
     return total.reshape(episodes * n)

@@ -570,6 +570,9 @@ def fused_mlp_rollout(
         out_specs=pl.BlockSpec((1, 1, tile), lambda b, e: (e, 0, b)),
         out_shape=jax.ShapeDtypeStruct((episodes, 1, n_pad), out_dtype),
         interpret=interpret,
+        # the name a trace knows the custom call by (the benchmark's
+        # kernel_event_pattern matches it)
+        name="fused_mlp_rollout",
         **kwargs,
     )(*weights, *biases, *state_3d.values())
     return total[:, 0, :n].reshape(episodes * n)

@@ -190,6 +190,7 @@ def partial_topk(
             jax.ShapeDtypeStruct((nb, 1, k_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="partial_topk",
     )(v_pad)
     # merge: the global k smallest are each their block's <= k-th
     # smallest, so top_k over the nb*k candidates is exact; block-major,

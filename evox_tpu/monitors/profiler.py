@@ -6,9 +6,12 @@ its closest analog is EvoXVisMonitor.record_time; users fall back on
   ordered host callbacks around the step (works inside ``run()``'s fused
   fori_loop too, since the callbacks are ordered effects inside the loop
   body).
-- :func:`trace` — a context manager around ``jax.profiler.trace`` that
-  captures a TPU/XLA profile (TensorBoard format) for any code region,
-  e.g. ``with profiler.trace("/tmp/tb"): state = wf.run(state, 100)``.
+- :func:`trace` — a context manager around ``jax.profiler`` that captures
+  a TPU/XLA profile (TensorBoard format) for any code region, e.g.
+  ``with profiler.trace("/tmp/tb"): state = wf.run(state, 100)``, with
+  the program's own host spans (``evox:run`` ...) and the device's
+  operations (named by scope: ``evox.ask`` ...) in one file on one clock
+  (the names: core/instrument.py).
 """
 
 from __future__ import annotations
@@ -90,10 +93,26 @@ class StepTimerMonitor(Monitor):
 def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace (XLA/TPU timeline) of the region.
 
+    The profiler starts with the options under which the program's host
+    spans (``core.instrument.span``: ``evox:run``, ``evox:run/loop`` ...)
+    and the device's operations share the file and the clock: host tracer
+    level 2 (the program's spans and jax's own dispatch spans), the
+    Python tracer off (every Python call as an event swamps the host
+    plane and slows the host it measures). Each device operation's
+    ``op_name`` carries the scope it was traced under (``evox.ask``,
+    ``evox.evaluate/rollout_kernel``, ``evox.tell/peel`` ...).
+
     View with TensorBoard's profile plugin, or Perfetto when
     ``create_perfetto_link`` is set.
     """
-    jax.profiler.start_trace(log_dir, create_perfetto_link=create_perfetto_link)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(
+        log_dir,
+        create_perfetto_link=create_perfetto_link,
+        profiler_options=options,
+    )
     try:
         yield
     finally:

@@ -7,7 +7,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...core.instrument import CROSSOVER, scope
 
+
+@scope(CROSSOVER)
 def simulated_binary(key: jax.Array, pop: jax.Array, distribution_factor: float = 20.0) -> jax.Array:
     """SBX over consecutive parent pairs; returns offspring of the same shape.
 

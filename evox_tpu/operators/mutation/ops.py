@@ -8,7 +8,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...core.instrument import MUTATION, scope
 
+
+@scope(MUTATION)
 def polynomial(
     key: jax.Array,
     pop: jax.Array,

@@ -10,9 +10,11 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ...core.instrument import MATING, scope
 from ...kernels.topk import partial_topk
 
 
+@scope(MATING)
 def tournament(
     key: jax.Array,
     pop: jax.Array,
@@ -34,6 +36,7 @@ def tournament(
     return pop[winners]
 
 
+@scope(MATING)
 def tournament_multifit(
     key: jax.Array,
     pop: jax.Array,

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...core.distributed import POP_AXIS
+from ...core.instrument import CROWDING, DOMINANCE_BUILD, PEEL, SURVIVORS, scope
 from ...kernels.dominance import pack_dominator_rows, packed_dominance
 from ...kernels.topk import default_use_kernel, partial_topk
 from ...utils.common import dominate_relation
@@ -49,6 +50,7 @@ def _pack_front(front: jax.Array, n_words: int) -> jax.Array:
     )
 
 
+@scope(PEEL)
 def _peel_fronts(count: jax.Array, stop, n_words: int, delta_fn):
     """The front-peel ``while_loop`` shared by the replicated and
     mesh-sharded sorts — ONE source of truth for the rank/done/cut
@@ -138,7 +140,8 @@ def non_dominated_sort(
     n_words = (n + 31) // 32
     # fused compare + pack + count: one Pallas pass on TPU (the bool (n, n)
     # matrix never exists in HBM), identical-output XLA fallback elsewhere
-    dom_packed, count = packed_dominance(fitness)
+    with scope(DOMINANCE_BUILD):
+        dom_packed, count = packed_dominance(fitness)
 
     def delta_fn(front_words):
         # remove the current front's domination counts in one fused
@@ -203,15 +206,18 @@ def _non_dominated_sort_sharded(
     def island(local_rows: jax.Array, fit: jax.Array):
         # local_rows: this device's (rows_pad / D, m) dominator slab;
         # fit: the full (n, m) fitness, replicated (n·m floats — tiny)
-        dom_local = dominate_relation(local_rows, fit)
-        # (words_per, n): this device's slab of the packed matrix
-        packed_local = pack_dominator_rows(dom_local, words_per)
-        count = jax.lax.psum(
-            jnp.sum(
-                jax.lax.population_count(packed_local), axis=0, dtype=jnp.int32
-            ),
-            axis_name,
-        )
+        with scope(DOMINANCE_BUILD):
+            dom_local = dominate_relation(local_rows, fit)
+            # (words_per, n): this device's slab of the packed matrix
+            packed_local = pack_dominator_rows(dom_local, words_per)
+            count = jax.lax.psum(
+                jnp.sum(
+                    jax.lax.population_count(packed_local),
+                    axis=0,
+                    dtype=jnp.int32,
+                ),
+                axis_name,
+            )
         word0 = jax.lax.axis_index(axis_name) * words_per
 
         def delta_fn(front_words):
@@ -246,6 +252,7 @@ def _non_dominated_sort_sharded(
     return rank
 
 
+@scope(CROWDING)
 def crowding_distance(fitness: jax.Array, mask: Optional[jax.Array] = None) -> jax.Array:
     """NSGA-II crowding distance per individual (n,), larger = less crowded.
 
@@ -303,7 +310,8 @@ def non_dominate_indices(
         fitness, until=topk, return_cut_rank=True, mesh=mesh
     )
     crowd = crowding_distance(fitness, mask=rank == worst_rank)
-    return jnp.lexsort((-crowd, rank))[:topk]
+    with scope(SURVIVORS):
+        return jnp.lexsort((-crowd, rank))[:topk]
 
 
 def non_dominate(
@@ -320,7 +328,8 @@ def non_dominate(
     """
     pop_leaf = pop if isinstance(pop, jax.Array) else jax.tree.leaves(pop)[0]
     order = non_dominate_indices(fitness, topk, pop_leaf, deduplicate, mesh)
-    return jax.tree.map(lambda x: x[order], pop), fitness[order]
+    with scope(SURVIVORS):
+        return jax.tree.map(lambda x: x[order], pop), fitness[order]
 
 
 class NonDominate:
@@ -370,12 +379,19 @@ def rank_crowding_truncate(
         fitness, until=k, return_cut_rank=True, mesh=mesh
     )
     crowd = crowding_distance(fitness, mask=rank == worst_rank)
+    return _truncate(rank, worst_rank, crowd, k, use_kernel, interpret)
+
+
+@scope(SURVIVORS)
+def _truncate(rank, worst_rank, crowd, k: int, use_kernel, interpret: bool):
+    """The survivors of :func:`rank_crowding_truncate`, once rank, cut and
+    crowding are known."""
     if use_kernel is None:
         use_kernel = default_use_kernel()
     if not use_kernel:
         order = jnp.lexsort((-crowd, rank))[:k]
         return order, rank[order]
-    n = fitness.shape[0]
+    n = rank.shape[0]
     better = rank < worst_rank  # whole fronts above the cut: all admitted
     n_better = jnp.sum(better, dtype=jnp.int32)  # < k by cut construction
     # stable O(n) compaction of the auto-admitted rows (index order)

@@ -30,6 +30,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...core.instrument import LAYOUT, RESET, ROLLOUT_KERNEL, scope
 from ...core.problem import Problem
 from .control.envs import EnvSpec
 
@@ -341,32 +342,34 @@ class PolicyRolloutProblem(Problem):
         # numbers across the population), then AoS -> SoA component planes,
         # EPISODE-MAJOR so the kernel re-reads one theta per episode block
         # instead of a jnp.repeat-ed copy
-        ep_keys = jax.random.split(k_eps, ep)
-        env_state0 = jax.vmap(self.fused_env.base.reset)(ep_keys)  # (ep, ...)
-        env_flat = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                x[:, None], (ep, pop_size) + x.shape[1:]
-            ).reshape((ep * pop_size,) + x.shape[1:]),
-            env_state0,
-        )
-        soa0 = self.fused_env.to_soa(env_flat)
+        with scope(RESET):
+            ep_keys = jax.random.split(k_eps, ep)
+            env_state0 = jax.vmap(self.fused_env.base.reset)(ep_keys)  # (ep, ...)
+            env_flat = jax.tree.map(
+                lambda x: jnp.broadcast_to(
+                    x[:, None], (ep, pop_size) + x.shape[1:]
+                ).reshape((ep * pop_size,) + x.shape[1:]),
+                env_state0,
+            )
+            soa0 = self.fused_env.to_soa(env_flat)
         interpret = self.fused_interpret
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
-        totals = fused_rollout(
-            pop,
-            soa0,
-            T=int(self.max_len),
-            obs_dim=obs_dim,
-            hidden=hidden,
-            act_dim=act_dim,
-            step_soa=self.fused_env.step_soa,
-            obs_soa=self.fused_env.obs_soa,
-            tile=self.fused_tile,
-            episodes=ep,
-            early_stop=self.fused_env.terminating,
-            interpret=interpret,
-        )
+        with scope(ROLLOUT_KERNEL):
+            totals = fused_rollout(
+                pop,
+                soa0,
+                T=int(self.max_len),
+                obs_dim=obs_dim,
+                hidden=hidden,
+                act_dim=act_dim,
+                step_soa=self.fused_env.step_soa,
+                obs_soa=self.fused_env.obs_soa,
+                tile=self.fused_tile,
+                episodes=ep,
+                early_stop=self.fused_env.terminating,
+                interpret=interpret,
+            )
         # (ep, pop) episode-major -> (pop, ep) so reduce_fn sees the same
         # axis convention as the scan engine
         fitness = self.reduce_fn(totals.reshape(ep, pop_size).T, axis=-1)
@@ -394,8 +397,9 @@ class PolicyRolloutProblem(Problem):
                 "fused_planes expects an mlp_policy params tree "
                 "(list of {'w', 'b'} layers)"
             )
-        weights = tuple(l["w"].transpose(1, 2, 0) for l in pop)  # (in, out, n)
-        biases = tuple(l["b"].T for l in pop)  # (out, n)
+        with scope(LAYOUT):
+            weights = tuple(l["w"].transpose(1, 2, 0) for l in pop)  # (in, out, n)
+            biases = tuple(l["b"].T for l in pop)  # (out, n)
         sizes = (weights[0].shape[0],) + tuple(w.shape[1] for w in weights)
         if sizes[0] != self.env.obs_dim or sizes[-1] != self.env.act_dim:
             raise ValueError(
@@ -407,33 +411,35 @@ class PolicyRolloutProblem(Problem):
         pop_size = pop[0]["b"].shape[0]
         ep = self.num_episodes
 
-        ep_keys = jax.random.split(k_eps, ep)
-        env_state0 = jax.vmap(self.fused_planes.base.reset)(ep_keys)
-        env_flat = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                x[:, None], (ep, pop_size) + x.shape[1:]
-            ).reshape((ep * pop_size,) + x.shape[1:]),
-            env_state0,
-        )
-        planes0 = self.fused_planes.to_planes(env_flat)
+        with scope(RESET):
+            ep_keys = jax.random.split(k_eps, ep)
+            env_state0 = jax.vmap(self.fused_planes.base.reset)(ep_keys)
+            env_flat = jax.tree.map(
+                lambda x: jnp.broadcast_to(
+                    x[:, None], (ep, pop_size) + x.shape[1:]
+                ).reshape((ep * pop_size,) + x.shape[1:]),
+                env_state0,
+            )
+            planes0 = self.fused_planes.to_planes(env_flat)
         interpret = self.fused_interpret
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
-        totals = fused_mlp_rollout(
-            weights,
-            biases,
-            planes0,
-            T=int(self.max_len),
-            sizes=sizes,
-            step_planes=self.fused_planes.step_planes,
-            obs_planes=self.fused_planes.obs_planes,
-            tile=self.fused_planes_tile,
-            episodes=ep,
-            early_stop=self.fused_planes.terminating,
-            interpret=interpret,
-            weight_dtype=self.fused_planes_dtype,
-            linear=self.fused_planes_linear,
-        )
+        with scope(ROLLOUT_KERNEL):
+            totals = fused_mlp_rollout(
+                weights,
+                biases,
+                planes0,
+                T=int(self.max_len),
+                sizes=sizes,
+                step_planes=self.fused_planes.step_planes,
+                obs_planes=self.fused_planes.obs_planes,
+                tile=self.fused_planes_tile,
+                episodes=ep,
+                early_stop=self.fused_planes.terminating,
+                interpret=interpret,
+                weight_dtype=self.fused_planes_dtype,
+                linear=self.fused_planes_linear,
+            )
         fitness = self.reduce_fn(totals.reshape(ep, pop_size).T, axis=-1)
         return fitness, RolloutState(key=key, cap=state.cap, norm=state.norm)
 

@@ -8,6 +8,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.instrument import (
+    CONSTRAIN,
+    FIT_TRANSFORMS,
+    MONITORS,
+    RUN_LOOP,
+    RUN_PEEL,
+    TELL,
+    scope,
+    span,
+)
 from ..core.monitor import HOOK_NAMES, Monitor
 from ..core.problem import Problem
 
@@ -27,9 +37,13 @@ def run_hooks(
     mstates: list,
     *args: Any,
 ) -> None:
-    """Dispatch one hook across monitors, updating ``mstates`` in place."""
-    for i in table[name]:
-        mstates[i] = getattr(monitors[i], name)(mstates[i], *args)
+    """Dispatch one hook across monitors, updating ``mstates`` in place.
+    What the hooks trace is named ``evox.monitors/<hook>``."""
+    if not table[name]:
+        return
+    with scope(MONITORS), scope(name):
+        for i in table[name]:
+            mstates[i] = getattr(monitors[i], name)(mstates[i], *args)
 
 
 def finish_step(
@@ -61,11 +75,15 @@ def make_run_loop(step_impl: Callable, donate: bool = False) -> Callable:
     states its driver produced itself. :func:`fused_run` (the driver
     behind ``StdWorkflow.run``/``IslandWorkflow.run``) honors it by
     advancing caller-owned states one non-donating ``wf.step`` first, so
-    checkpoints are always taken from states the loop never donates."""
-    return jax.jit(
-        lambda s, n: jax.lax.fori_loop(0, n, lambda _, x: step_impl(x), s),
-        donate_argnums=(0,) if donate else (),
-    )
+    checkpoints are always taken from states the loop never donates.
+
+    The program is named: a trace shows the module as ``jit_run_loop`` and
+    the host's dispatch as ``PjitFunction(run_loop)``."""
+
+    def run_loop(s, n):
+        return jax.lax.fori_loop(0, n, lambda _, x: step_impl(x), s)
+
+    return jax.jit(run_loop, donate_argnums=(0,) if donate else ())
 
 
 def fused_run(wf: Any, state: Any, n_steps: int) -> Any:
@@ -84,14 +102,19 @@ def fused_run(wf: Any, state: Any, n_steps: int) -> Any:
     # caller's arrays (bench re-timing loops, checkpointer snapshots,
     # test fixtures) would be invalidated under it
     if state.first_step or getattr(wf, "donate_carries", False):
-        state = wf.step(state)
+        with span(RUN_PEEL):
+            state = wf.step(state)
         n_steps -= 1
     if not wf.jit_step:
         for _ in range(n_steps):
             state = wf._step_impl(state)
         return state
     if n_steps > 0:
-        state = wf._run_loop(state, jnp.asarray(n_steps, dtype=jnp.int32))
+        # the span brackets the trip count's own little program and
+        # transfer as well as the loop's dispatch, so a trace says what
+        # each costs before the device starts on the chunk
+        with span(RUN_LOOP, n_steps=n_steps):
+            state = wf._run_loop(state, jnp.asarray(n_steps, dtype=jnp.int32))
     return state
 
 
@@ -111,29 +134,33 @@ def ingest_fitness(
     steps cannot silently drift between the copies."""
     from ..core.distributed import constrain_state
 
-    for t in wf.fit_transforms:
-        fitness = t(fitness)
+    with scope(TELL), scope(FIT_TRANSFORMS):
+        for t in wf.fit_transforms:
+            fitness = t(fitness)
     run_hooks(wf.monitors, wf._hook_table, "pre_tell", mstates, fitness)
-    if use_init:
-        astate = wf.algorithm.init_tell(astate, fitness)
-    else:
-        astate = wf.algorithm.tell(astate, fitness)
-    if wf.migrate_helper is not None:
-        do_migrate, foreign_pop, foreign_fit = wf.migrate_helper()
-        # foreign fitness arrives in the user's convention: sign-flip it
-        # to the internal minimization key, but never fit_transforms —
-        # population-relative shaping over a lone migrant batch is
-        # meaningless/NaN (see StdWorkflow.migrate_helper docs)
-        foreign_fit = wf._flip(foreign_fit)
-        astate = jax.lax.cond(
-            do_migrate,
-            lambda a: wf.algorithm.migrate(a, foreign_pop, foreign_fit),
-            lambda a: a,
-            astate,
-        )
+    with scope(TELL):
+        if use_init:
+            astate = wf.algorithm.init_tell(astate, fitness)
+        else:
+            astate = wf.algorithm.tell(astate, fitness)
+        if wf.migrate_helper is not None:
+            do_migrate, foreign_pop, foreign_fit = wf.migrate_helper()
+            # foreign fitness arrives in the user's convention: sign-flip
+            # it to the internal minimization key, but never
+            # fit_transforms — population-relative shaping over a lone
+            # migrant batch is meaningless/NaN (see
+            # StdWorkflow.migrate_helper docs)
+            foreign_fit = wf._flip(foreign_fit)
+            astate = jax.lax.cond(
+                do_migrate,
+                lambda a: wf.algorithm.migrate(a, foreign_pop, foreign_fit),
+                lambda a: a,
+                astate,
+            )
     # declared sharding + storage-dtype downcast in one fused walk: the
     # loop-carried algorithm state leaves the step at storage width
-    return constrain_state(astate, wf.mesh, wf.dtype_policy)
+    with scope(CONSTRAIN):
+        return constrain_state(astate, wf.mesh, wf.dtype_policy)
 
 
 def quarantine_nonfinite(fitness: jax.Array) -> jax.Array:

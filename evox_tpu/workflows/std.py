@@ -39,6 +39,18 @@ from ..core.distributed import (
     shard_pop,
 )
 from ..core.dtype_policy import DtypePolicy, apply_compute, apply_storage
+from ..core.instrument import (
+    ASK,
+    CONSTRAIN,
+    DECODE,
+    EVALUATE,
+    INIT,
+    RUN,
+    STEP,
+    TELL,
+    scope,
+    span,
+)
 from ..utils.common import parse_opt_direction
 from .checkpoint import (
     WorkflowCheckpointer,
@@ -313,6 +325,10 @@ class StdWorkflow:
 
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> StdWorkflowState:
+        with span(INIT):
+            return self._init(key)
+
+    def _init(self, key: jax.Array) -> StdWorkflowState:
         keys = jax.random.split(key, 2 + len(self.monitors))
         state = StdWorkflowState(
             generation=jnp.zeros((), dtype=jnp.int32),
@@ -335,7 +351,8 @@ class StdWorkflow:
 
     # ------------------------------------------------------------------ step
     def step(self, state: StdWorkflowState) -> StdWorkflowState:
-        return self._step(state)
+        with span(STEP):
+            return self._step(state)
 
     def run(
         self,
@@ -381,54 +398,58 @@ class StdWorkflow:
         recompile per doubling, best-so-far carried across; see
         workflows/ipop.py). Composes with ``checkpointer``/``resume_from``
         — a resumed run rebuilds the snapshot's population size first.
+
+        The call is one ``evox:run`` span on the profiler's host plane
+        (core/instrument.py); ``fused_run`` nests its parts in it.
         """
-        if restarts is not None:
+        with span(RUN, n_steps=int(n_steps)):
+            if restarts is not None:
+                if self.external:
+                    # host problems take the executor pipeline for IPOP too —
+                    # an ipop segment through fused_run would trace the
+                    # pure_callback step the executor routing exists to avoid
+                    from .pipelined import run_host_pipelined
+
+                    return run_host_pipelined(
+                        self, state, n_steps, checkpointer=checkpointer,
+                        resume_from=resume_from, restarts=restarts,
+                    )
+                from .ipop import ipop_run
+
+                return ipop_run(
+                    self,
+                    state,
+                    n_steps,
+                    restarts,
+                    segment=lambda w, s, c, ck: (
+                        checkpointed_run(w, s, c, ck)
+                        if ck is not None
+                        else fused_run(w, s, c)
+                    ),
+                    checkpointer=checkpointer,
+                    resume_from=resume_from,
+                )
+            # shared prologue (workflows/checkpoint.py enter_run): resolve a
+            # resume into (restored state, REMAINING steps) with the
+            # config-fingerprint guard armed on the caller's live state, and
+            # default the checkpointer to the resumed directory
+            state, n_steps, checkpointer = enter_run(
+                state, n_steps, checkpointer, resume_from, expect_like=state
+            )
             if self.external:
-                # host problems take the executor pipeline for IPOP too —
-                # an ipop segment through fused_run would trace the
-                # pure_callback step the executor routing exists to avoid
+                # host-problem path: since PR 8 the fused callback loop is
+                # replaced by the executor's double-buffered host pipeline
+                # (bit-identical to a step loop — the run==step law — with
+                # the host evaluate between dispatches instead of a callback
+                # inside the loop); checkpoint snapshots ride its background lane
                 from .pipelined import run_host_pipelined
 
                 return run_host_pipelined(
-                    self, state, n_steps, checkpointer=checkpointer,
-                    resume_from=resume_from, restarts=restarts,
+                    self, state, n_steps, checkpointer=checkpointer
                 )
-            from .ipop import ipop_run
-
-            return ipop_run(
-                self,
-                state,
-                n_steps,
-                restarts,
-                segment=lambda w, s, c, ck: (
-                    checkpointed_run(w, s, c, ck)
-                    if ck is not None
-                    else fused_run(w, s, c)
-                ),
-                checkpointer=checkpointer,
-                resume_from=resume_from,
-            )
-        # shared prologue (workflows/checkpoint.py enter_run): resolve a
-        # resume into (restored state, REMAINING steps) with the
-        # config-fingerprint guard armed on the caller's live state, and
-        # default the checkpointer to the resumed directory
-        state, n_steps, checkpointer = enter_run(
-            state, n_steps, checkpointer, resume_from, expect_like=state
-        )
-        if self.external:
-            # host-problem path: since PR 8 the fused callback loop is
-            # replaced by the executor's double-buffered host pipeline
-            # (bit-identical to a step loop — the run==step law — with
-            # the host evaluate between dispatches instead of a callback
-            # inside the loop); checkpoint snapshots ride its background lane
-            from .pipelined import run_host_pipelined
-
-            return run_host_pipelined(
-                self, state, n_steps, checkpointer=checkpointer
-            )
-        if checkpointer is not None:
-            return checkpointed_run(self, state, n_steps, checkpointer)
-        return fused_run(self, state, n_steps)
+            if checkpointer is not None:
+                return checkpointed_run(self, state, n_steps, checkpointer)
+            return fused_run(self, state, n_steps)
 
     def resume(
         self,
@@ -510,11 +531,35 @@ class StdWorkflow:
         use_init = state.first_step and (
             self.algorithm.has_init_ask or self.algorithm.has_init_tell
         )
-        if use_init:
-            pop, astate = self.algorithm.init_ask(state.algo)
-        else:
-            pop, astate = self.algorithm.ask(state.algo)
+        with scope(ASK):
+            if use_init:
+                pop, astate = self.algorithm.init_ask(state.algo)
+            else:
+                pop, astate = self.algorithm.ask(state.algo)
         return use_init, pop, astate
+
+    def _candidates(self, pop: Any) -> Any:
+        """What the problem is handed: ``pop_transforms`` (the genome
+        decoded, part of evaluating it) and the ``"pop"`` constraint."""
+        with scope(EVALUATE):
+            cand = pop
+            with scope(DECODE):
+                for t in self.pop_transforms:
+                    cand = t(cand)
+            return shard_pop(cand, self.mesh)
+
+    def _final_fitness(self, fitness: jax.Array) -> jax.Array:
+        """Sign-flipped (algorithms minimize) and, when asked, quarantined:
+        the fitness ``ingest_fitness`` takes."""
+        with scope(TELL):
+            fitness = self._flip(fitness)
+            if self.quarantine_nonfinite:
+                # poison (NaN/Inf) rows get the generation's worst-finite
+                # value AFTER monitors saw the raw fitness (telemetry still
+                # counts them) and BEFORE fit_transforms/tell (ranking
+                # stays sane)
+                fitness = quarantine_nonfinite(fitness)
+        return fitness
 
     def _ask_preview(self, state: StdWorkflowState) -> Any:
         # previews see the same compute-dtype view the step itself asks on
@@ -552,10 +597,7 @@ class StdWorkflow:
         training stream untouched.
         """
         problem = problem if problem is not None else self.problem
-        cand = self._ask_preview(state)
-        for t in self.pop_transforms:
-            cand = t(cand)
-        cand = shard_pop(cand, self.mesh)
+        cand = self._candidates(self._ask_preview(state))
         if problem_state is not None and problem is self.problem:
             raise ValueError(
                 "problem_state is only meaningful with an explicit "
@@ -581,11 +623,18 @@ class StdWorkflow:
         return fitness * self.opt_direction
 
     def _evaluate(self, pstate: Any, cand: Any) -> Tuple[jax.Array, Any]:
-        if not self.external:
-            if self.eval_shard_map:
-                return self._evaluate_shard_map(pstate, cand)
-            return self.problem.evaluate(pstate, cand)
-        return callback_evaluate(self.problem, pstate, cand, self.num_objectives)
+        with scope(EVALUATE):
+            if not self.external:
+                if self.eval_shard_map:
+                    return self._evaluate_shard_map(pstate, cand)
+                return self.problem.evaluate(pstate, cand)
+            return callback_evaluate(
+                self.problem, pstate, cand, self.num_objectives
+            )
+
+    def _shard_fitness(self, fitness: jax.Array) -> jax.Array:
+        with scope(EVALUATE):
+            return shard_pop(fitness, self.mesh)
 
     def _evaluate_shard_map(self, pstate: Any, cand: Any) -> Tuple[jax.Array, Any]:
         """Explicit-collective evaluation: each device scores its local
@@ -644,16 +693,14 @@ class StdWorkflow:
     def _pipeline_ask_impl(self, state: StdWorkflowState):
         # storage -> compute at the step boundary: ask's math (and the
         # ctx it hands to tell) runs full-precision
-        state = apply_compute(state, self.dtype_policy)
+        with scope(CONSTRAIN):
+            state = apply_compute(state, self.dtype_policy)
         mstates = list(state.monitors)
         self._run_hooks("pre_step", mstates)
         self._run_hooks("pre_ask", mstates)
         _, pop, astate = self._dispatch_ask(state)
         self._run_hooks("post_ask", mstates, pop)
-        cand = pop
-        for t in self.pop_transforms:
-            cand = t(cand)
-        cand = shard_pop(cand, self.mesh)
+        cand = self._candidates(pop)
         self._run_hooks("pre_eval", mstates, cand)
         return cand, (astate, tuple(mstates), cand)
 
@@ -662,11 +709,9 @@ class StdWorkflow:
     ) -> StdWorkflowState:
         astate, mstates_t, cand = ctx
         mstates = list(mstates_t)
-        fitness = shard_pop(fitness, self.mesh)
+        fitness = self._shard_fitness(fitness)
         self._run_hooks("post_eval", mstates, cand, fitness)
-        fitness = self._flip(fitness)
-        if self.quarantine_nonfinite:
-            fitness = quarantine_nonfinite(fitness)
+        fitness = self._final_fitness(fitness)
         use_init = state.first_step and (
             self.algorithm.has_init_ask or self.algorithm.has_init_tell
         )
@@ -687,7 +732,8 @@ class StdWorkflow:
         # storage -> compute upcast at step entry: every reduction, mean
         # and covariance update below runs in the compute dtype; only the
         # state carried OUT of the step (constrain_state below) is narrow
-        state = apply_compute(state, self.dtype_policy)
+        with scope(CONSTRAIN):
+            state = apply_compute(state, self.dtype_policy)
         mstates = list(state.monitors)
         self._run_hooks("pre_step", mstates)
         self._run_hooks("pre_ask", mstates)
@@ -695,22 +741,13 @@ class StdWorkflow:
         use_init, pop, astate = self._dispatch_ask(state)
         self._run_hooks("post_ask", mstates, pop)
 
-        cand = pop
-        for t in self.pop_transforms:
-            cand = t(cand)
-        cand = shard_pop(cand, self.mesh)
-
+        cand = self._candidates(pop)
         self._run_hooks("pre_eval", mstates, cand)
         fitness, pstate = self._evaluate(state.prob, cand)
-        fitness = shard_pop(fitness, self.mesh)
+        fitness = self._shard_fitness(fitness)
         self._run_hooks("post_eval", mstates, cand, fitness)
 
-        fitness = self._flip(fitness)
-        if self.quarantine_nonfinite:
-            # poison (NaN/Inf) rows get the generation's worst-finite value
-            # AFTER monitors saw the raw fitness (telemetry still counts
-            # them) and BEFORE fit_transforms/tell (ranking stays sane)
-            fitness = quarantine_nonfinite(fitness)
+        fitness = self._final_fitness(fitness)
         # shared tell half (workflows/common.py): fit_transforms ->
         # pre_tell -> tell dispatch -> migrate cond -> constrain_state
         astate = ingest_fitness(self, astate, mstates, fitness, use_init)

@@ -127,11 +127,24 @@ def test_compile_clock_unions_nested_spans():
 # ---------------------------------------------------------- compile cache
 
 
+_CACHE_OPTIONS = (
+    "jax_compilation_cache_dir",
+    "jax_compilation_cache_include_metadata_in_key",
+    "jax_hlo_source_file_canonicalization_regex",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+)
+
+
 @pytest.fixture
 def restore_cache_dir():
-    before = jax.config.jax_compilation_cache_dir
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = {k: getattr(jax.config, k) for k in _CACHE_OPTIONS}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for k, v in before.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()  # the rest of the session caches nowhere, as before
 
 
 def test_compile_cache_placed_from_outside_is_left_alone(
@@ -157,3 +170,47 @@ def test_compile_cache_defaults_to_one_fixed_directory_in_the_checkout(
     assert jax.config.jax_compilation_cache_dir == first
     ignored = (REPO / ".gitignore").read_text().split()
     assert ".jax_cache/" in ignored and "chiprun_out/" in ignored
+
+
+def test_compile_cache_keys_a_program_with_its_names(
+    tmp_path, monkeypatch, restore_cache_dir
+):
+    """A cached executable carries the ``op_name``s of the compile that
+    wrote it, and the profiler shows those. So two programs that differ in
+    a ``scope`` alone get an entry each, which jax's default key (metadata
+    stripped) does not give them; and the source paths in the key are
+    relative to the checkout, so a copy elsewhere finds the same entries."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from evox_tpu.core.instrument import scope
+    from evox_tpu.utils import compile_cache
+
+    def program(name):
+        def f(x):
+            with scope(name):
+                return jax.numpy.sin(x) * 2
+
+        return jax.jit(f)
+
+    def new_entries(names):
+        held = set(os.listdir(tmp_path))
+        for name in names:
+            program(name)(x)  # one call site, so the scope is all that differs
+        return len([p for p in set(os.listdir(tmp_path)) - held if p.endswith("-cache")])
+
+    x = jax.numpy.ones((8,))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "_REPO_CACHE", tmp_path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert new_entries("ab") == 2
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    assert new_entries("cd") == 1  # jax's default: "d" would load "c"'s names
+
+    assert jax.config.jax_hlo_source_file_canonicalization_regex == re.escape(str(REPO) + os.sep)
+    text = program("a").lower(x).as_text(debug_info=True)
+    assert '"tests/test_chip_smoke.py"' in text and str(REPO) not in text
