@@ -29,6 +29,16 @@ class Problem:
     def evaluate(self, state: ProblemState, pop: Any) -> Tuple[jax.Array, ProblemState]:
         raise NotImplementedError
 
+    #: Optional ``evaluate_genome(state, pop, genome, adapter)``: the same
+    #: result as ``evaluate(state, pop)`` for a problem that can read the
+    #: candidates out of the undecoded batch. ``StdWorkflow`` calls it in
+    #: ``evaluate``'s place when its ``pop_transforms`` is exactly one
+    #: ``TreeAndVector.batched_to_tree``: ``genome`` is the ``(n, dim)``
+    #: batch the algorithm proposed, ``adapter`` that ``TreeAndVector``
+    #: (its ``offsets`` are the genome's layout), ``pop`` the decoded tree
+    #: as ever (what of it nobody reads, XLA drops). ``None``: no such path.
+    evaluate_genome = None
+
     def fit_shape(self, pop_size: int) -> Tuple[int, ...]:
         """Fitness shape for a given pop size (used for callback problems)."""
         return (pop_size,)

@@ -23,6 +23,20 @@ better-pipelining fixed-T ``fori_loop``.
 Layouts:
 - weights per layer ``(fan_in, fan_out, n)`` — individual in the lane
   dimension, so ``w[k]`` is a ``(fan_out, tile)`` vreg block;
+- or the population whole, as the flat genome ``(dim, n)`` (``genome=``,
+  one ``(dim, tile)`` block a grid cell): a leaf whose first row ``rows``
+  names is read IN PLACE, ``w[k]`` being the static row range
+  ``off + k * fan_out`` to ``off + (k + 1) * fan_out`` of that block, so
+  nothing cuts the layer out of the genome and writes it a second time
+  before the kernel reads it. :func:`genome_rows` is the rule: a leaf is
+  read in place when its first row and its ``fan_out`` are multiples of
+  the resident dtype's sublane packing (8 rows of float32, 16 of
+  bfloat16) — then ``w[k]`` is the same whole aligned tiles, the same
+  vector loads, as in a block of its own; any other leaf is passed as
+  its own block as above. For ``mlp_policy`` 244-64-64-17
+  (``ravel_pytree`` order ``b0 w0 b1 w1 b2 w2``, rows 0, 64, 15,680,
+  15,744, 19,840, 19,857) that is ``b0 w0 b1 w1`` in place, 19,840 of
+  20,945 rows, and ``b2 w2`` (``fan_out`` 17) cut;
 - env state as a dict of ``(components, n)`` planes (:class:`PlaneEnv`);
 - observations assembled in-kernel as one ``(obs_dim, tile)`` block whose
   row order matches the AoS env's observation vector exactly — the same
@@ -36,6 +50,7 @@ exactly and to the scan engine's fitness within float tolerance.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -44,6 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+_NO_ROWS = ((None, None),)  # ``rows`` of a layer whose leaves both come as blocks
 
 PlaneState = Dict[str, jax.Array]
 
@@ -272,15 +288,29 @@ def _mlp_planes(w_refs, b_refs, obs: jax.Array, sizes, linear=()) -> jax.Array:
     ``linear``: layer indices whose output skips the tanh — consecutive
     linear layers express a low-rank factorization (a rank-r input layer
     is ``sizes=(obs, r, h, ...), linear=(0,)``), the PERF_NOTES §14
-    "fewer MACs" lever. Matches ``mlp_policy(linear_layers=...)``."""
+    "fewer MACs" lever. Matches ``mlp_policy(linear_layers=...)``.
+
+    A layer's entry in ``w_refs`` / ``b_refs`` is its own block,
+    ``(fan_in, fan_out, tile)`` / ``(fan_out, tile)``, or a pair ``(flat
+    genome block (dim, tile), first row)``: then ``w[k]`` and the bias
+    are static row ranges of that block, read where they lie."""
     h = obs
     n_layers = len(sizes) - 1
     for li in range(n_layers):
         fan_in, fan_out = sizes[li], sizes[li + 1]
-        acc = b_refs[li][...].astype(jnp.float32)  # (fan_out, tile)
-        w = w_refs[li]
+        b, w = b_refs[li], w_refs[li]
+        if isinstance(b, tuple):  # (flat genome block, the bias's first row)
+            b = b[0][b[1] : b[1] + fan_out]
+        else:
+            b = b[...]
+        acc = b.astype(jnp.float32)  # (fan_out, tile)
         for k in range(fan_in):
-            acc = acc + h[k : k + 1] * w[k].astype(jnp.float32)
+            if isinstance(w, tuple):  # (flat genome block, w[0]'s first row)
+                lo = w[1] + k * fan_out
+                wk = w[0][lo : lo + fan_out]
+            else:
+                wk = w[k]
+            acc = acc + h[k : k + 1] * wk.astype(jnp.float32)
         h = acc if (li == n_layers - 1 or li in linear) else jnp.tanh(acc)
     return h
 
@@ -296,11 +326,20 @@ def _rollout_mlp_kernel(
     state_keys: Tuple[str, ...],
     early_stop: bool,
     linear: Tuple[int, ...] = (),
+    rows: Any,
 ):
-    n_layers = len(sizes) - 1
-    w_refs = refs[:n_layers]
-    b_refs = refs[n_layers : 2 * n_layers]
-    state_refs = refs[2 * n_layers :]
+    # refs: the flat genome block where ``rows`` says some leaf is read in
+    # place, then the weight blocks, the bias blocks (of the leaves that
+    # have their own), then the state planes
+    refs = list(refs)
+    genome_ref = refs.pop(0) if rows != _NO_ROWS * len(rows) else None
+    w_refs = [
+        refs.pop(0) if r[0] is None else (genome_ref, r[0]) for r in rows
+    ]
+    b_refs = [
+        refs.pop(0) if r[1] is None else (genome_ref, r[1]) for r in rows
+    ]
+    state_refs = refs
     # state blocks arrive (1, C, tile): drop the episode block dim
     state = {k: r[0] for k, r in zip(state_keys, state_refs)}
     tile = state[state_keys[0]].shape[-1]
@@ -372,18 +411,46 @@ _VMEM_MARGIN = 8 * 1024 * 1024  # scratch/accumulator slack past residency
 _VMEM_CAP = 100 * 2**20  # stay under the chip's VMEM (v5e: 128 MiB)
 
 
-def _vmem_plan(weights, biases, tile: int) -> Tuple[int, int]:
+def genome_rows(offsets, sizes, dtype) -> Tuple[Tuple[Any, Any], ...]:
+    """Per layer ``(w's first row, b's first row)`` for the leaves of an
+    ``mlp_policy`` genome the kernel reads in place, ``None`` for a leaf
+    that is cut out and passed as its own block — the ``rows`` of
+    :func:`fused_mlp_rollout`. ``offsets``: the params tree with each
+    leaf's first row in the flat genome (``TreeAndVector.offsets``);
+    ``dtype``: what the planes are resident as.
+
+    A rule of shapes and offsets alone: in place when the leaf's first row
+    and its ``fan_out`` are multiples of the dtype's sublane packing (8
+    rows of a 4-byte type to a tile, 16 of a 2-byte one), so that every
+    ``w[k]`` is whole aligned tiles and loads as it does from its own
+    block. An unaligned row range compiles too (the walker's ``w2[k]``: 17
+    rows at 19,857 + 17 k) and gives the same bits; float32 on the chip,
+    the kernel alone ran no slower with every leaf in place (PERF.md, PR
+    27, which also says what widening the rule waits for)."""
+    pack = 32 // jnp.dtype(dtype).itemsize
+
+    def first_row(off, fan_out):
+        return off if off % pack == 0 and fan_out % pack == 0 else None
+
+    return tuple(
+        (first_row(l["w"], fan_out), first_row(l["b"], fan_out))
+        for l, fan_out in zip(offsets, sizes[1:])
+    )
+
+
+def _vmem_plan(weights, biases, tile: int, genome=None) -> Tuple[int, int]:
     """``(resident bytes per grid cell, vmem_limit_bytes)`` for the fused
-    kernel: one tile of every layer's weight/bias planes is VMEM-resident,
+    kernel: one tile of every block (each layer's weight/bias planes, or
+    the flat genome and the leaves cut out of it) is VMEM-resident,
     Pallas double-buffers the blocks across grid cells, and the Mosaic
     scoped-vmem budget is raised to twice the residency plus margin
     (capped below the chip's VMEM). The single source of truth for both
     the ``pallas_call`` compiler params and
     :func:`fused_rollout_analysis`'s headroom report."""
-    w_item = weights[0].dtype.itemsize
+    blocks = [x for x in (genome, *weights, *biases) if x is not None]
     per_cell = sum(
-        w.shape[0] * w.shape[1] * tile * w_item for w in weights
-    ) + sum(b.shape[0] * tile * w_item for b in biases)
+        math.prod(x.shape[:-1]) * tile * x.dtype.itemsize for x in blocks
+    )
     return per_cell, min(2 * per_cell + _VMEM_MARGIN, _VMEM_CAP)
 
 
@@ -392,6 +459,7 @@ def fused_rollout_analysis(
     biases: Tuple[jax.Array, ...],
     tile: int = _LANES,
     weight_dtype: Any = None,
+    params: Any = None,
 ) -> dict:
     """Static VMEM-residency report for :func:`fused_mlp_rollout` — the
     kernel half of the roofline analytics layer (core/xla_cost.py covers
@@ -405,26 +473,47 @@ def fused_rollout_analysis(
     will request, and the headroom between them. Negative headroom means
     the cap clipped the request — the compile will fail or thrash; shrink
     ``tile`` or narrow ``weight_dtype`` (bf16 halves residency, the
-    knob PERF_NOTES §9 documents)."""
-    if weight_dtype is not None:
-        itemsize = jnp.dtype(weight_dtype).itemsize
-        scale = itemsize / weights[0].dtype.itemsize
-    else:
-        scale = 1.0
-    per_cell, limit = _vmem_plan(weights, biases, tile)
-    per_cell = int(per_cell * scale)
-    limit = min(2 * per_cell + _VMEM_MARGIN, _VMEM_CAP)
+    knob PERF_NOTES §9 documents).
+
+    ``params``: one member's ``mlp_policy`` params tree (arrays or
+    shapes). With it the report is of the flat-genome call a workflow
+    makes for that tree (:func:`genome_rows` at the resident dtype):
+    ``rows_in_place``, the genome's rows the kernel reads where they lie,
+    ``rows_cut``, those cut out into blocks of their own first, and the
+    residency of the whole genome block plus the cut leaves."""
+    dtype = jnp.dtype(
+        weight_dtype if weight_dtype is not None else weights[0].dtype
+    )
+    genome, rows = None, {}
+    if params is not None:
+        from ..utils.common import leaf_offsets
+
+        offsets, dim = leaf_offsets(params)
+        sizes = (weights[0].shape[0],) + tuple(w.shape[1] for w in weights)
+        in_place = genome_rows(offsets, sizes, dtype)
+        # what the kernel is handed: the leaves cut out, and the genome
+        # whole where any leaf is read in place
+        weights = tuple(w for r, w in zip(in_place, weights) if r[0] is None)
+        biases = tuple(b for r, b in zip(in_place, biases) if r[1] is None)
+        cut = sum(math.prod(x.shape[:-1]) for x in (*weights, *biases))
+        rows = {"rows_in_place": dim - cut, "rows_cut": cut}
+        if cut < dim:
+            genome = jax.ShapeDtypeStruct((dim, tile), dtype)
+    per_cell, limit = _vmem_plan(
+        [jax.ShapeDtypeStruct(w.shape, dtype) for w in weights],
+        [jax.ShapeDtypeStruct(b.shape, dtype) for b in biases],
+        tile,
+        genome,
+    )
     return {
         "tile": tile,
-        "weight_dtype": str(
-            jnp.dtype(weight_dtype) if weight_dtype is not None
-            else weights[0].dtype
-        ),
+        "weight_dtype": str(dtype),
         "resident_bytes_per_cell": per_cell,
         "double_buffered_bytes": 2 * per_cell,
         "vmem_limit_bytes": limit,
         "vmem_cap_bytes": _VMEM_CAP,
         "headroom_bytes": limit - 2 * per_cell,
+        **rows,
     }
 
 
@@ -432,7 +521,7 @@ def fused_rollout_analysis(
     jax.jit,
     static_argnames=(
         "T", "sizes", "step_planes", "obs_planes", "tile", "episodes",
-        "early_stop", "interpret", "weight_dtype", "linear",
+        "early_stop", "interpret", "weight_dtype", "linear", "rows",
     ),
 )
 def fused_mlp_rollout(
@@ -449,12 +538,15 @@ def fused_mlp_rollout(
     interpret: bool = False,
     weight_dtype: Any = None,
     linear: Tuple[int, ...] = (),
+    genome: Any = None,
+    rows: Any = None,
 ) -> jax.Array:
     """Total episode reward per env, fully fused, weights VMEM-resident.
 
     Args:
-        weights: per layer ``(fan_in, fan_out, n)`` (individual = lane).
-        biases: per layer ``(fan_out, n)``.
+        weights: per layer ``(fan_in, fan_out, n)`` (individual = lane);
+            ``None`` for a layer read in place from ``genome``.
+        biases: per layer ``(fan_out, n)``; ``None`` likewise.
         init_state: dict of ``(episodes * n,)``-env plane arrays, each
             ``(C, episodes * n)``, EPISODE-MAJOR along the env axis. Must
             contain a ``"done"`` plane (float 0/1) consumed as the initial
@@ -472,6 +564,14 @@ def fused_mlp_rollout(
             roofline) and doubles the per-tile policy budget.
         linear: layer indices with no tanh after them (low-rank
             factorized layers — see :func:`_mlp_planes`).
+        genome: the population as the flat genome ``(dim, n)``, individual
+            = lane, with ``rows``; ``None`` when every leaf comes as its
+            own block.
+        rows: per layer ``(w's first row, b's first row)`` in ``genome``
+            for a leaf read in place, ``None`` for one passed in
+            ``weights`` / ``biases`` (:func:`genome_rows`, module
+            docstring). Same loads, same order of multiply and add:
+            bit-identical to the per-layer call.
 
     Returns:
         ``(episodes * n,)`` total rewards, episode-major (always f32).
@@ -488,17 +588,33 @@ def fused_mlp_rollout(
             f"linear {sorted(set(linear))} out of range for {n_layers} "
             "layers (negative indices not supported)"
         )
+    if rows is None:
+        rows = _NO_ROWS * n_layers
+    if rows == _NO_ROWS * n_layers:
+        genome = None  # nothing to read in place: no block of it
+    elif genome is None:
+        raise ValueError(f"rows {rows} name leaves of a genome, and none came")
+    if any(
+        (x is None) == (r is None)
+        for pair, leaves in zip(rows, zip(weights, biases))
+        for r, x in zip(pair, leaves)
+    ):
+        raise ValueError(
+            "a leaf comes in weights/biases or has its first row of genome "
+            f"in rows, one of the two (rows {rows})"
+        )
+    # every array the kernel is handed a block of, member last: the genome
+    # where some leaf is read in place, then the leaves that come as their own
+    planes = [x for x in (genome, *weights, *biases) if x is not None]
     if weight_dtype is not None:
-        weights = tuple(w.astype(weight_dtype) for w in weights)
-        biases = tuple(b.astype(weight_dtype) for b in biases)
-    n = weights[0].shape[-1]
+        planes = [x.astype(weight_dtype) for x in planes]
+    n = planes[0].shape[-1]
     pad = (-n) % tile
     n_pad = n + pad
     if pad:
-        weights = tuple(
-            jnp.pad(w, ((0, 0), (0, 0), (0, pad))) for w in weights
-        )
-        biases = tuple(jnp.pad(b, ((0, 0), (0, pad))) for b in biases)
+        planes = [
+            jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) for x in planes
+        ]
         init_state = {
             k: jnp.pad(
                 v.reshape(v.shape[0], episodes, n), ((0, 0), (0, 0), (0, pad))
@@ -524,19 +640,19 @@ def fused_mlp_rollout(
         state_keys=state_keys,
         early_stop=early_stop,
         linear=linear,
+        rows=rows,
     )
 
     def wrapped(*refs):
         kernel(refs[:-1], refs[-1])
 
-    w_specs = [
+    # whole in every dimension but the last, one tile of members a cell
+    p_specs = [
         pl.BlockSpec(
-            (w.shape[0], w.shape[1], tile), lambda b, e: (0, 0, b)
+            x.shape[:-1] + (tile,),
+            lambda b, e, lead=(0,) * (x.ndim - 1): lead + (b,),
         )
-        for w in weights
-    ]
-    b_specs = [
-        pl.BlockSpec((b.shape[0], tile), lambda b, e: (0, b)) for b in biases
+        for x in planes
     ]
     s_specs = [
         pl.BlockSpec(
@@ -549,7 +665,7 @@ def fused_mlp_rollout(
         # the weight blocks are double-buffered across grid cells; the
         # default 16 MB scoped-vmem budget is too small for the resident
         # weights — raise it (v5e VMEM is far larger than the default cap)
-        _, vmem_limit = _vmem_plan(weights, biases, tile)
+        _, vmem_limit = _vmem_plan(planes, (), tile)
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit
         )
@@ -561,7 +677,7 @@ def fused_mlp_rollout(
         # elides their re-fetch — the resident policy tile is DMA'd once
         # per block regardless of episode count
         grid=(blocks, episodes),
-        in_specs=w_specs + b_specs + s_specs,
+        in_specs=p_specs + s_specs,
         # 3-D output (episodes, 1, n_pad): Mosaic's lowering constrains
         # only the LAST TWO block dims (divisible by (8, 128) or equal to
         # the array dims); a 2-D (episodes, n_pad) array with block
@@ -574,5 +690,5 @@ def fused_mlp_rollout(
         # kernel_event_pattern matches it)
         name="fused_mlp_rollout",
         **kwargs,
-    )(*weights, *biases, *state_3d.values())
+    )(*planes, *state_3d.values())
     return total[:, 0, :n].reshape(episodes * n)

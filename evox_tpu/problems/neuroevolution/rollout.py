@@ -30,7 +30,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ...core.instrument import LAYOUT, RESET, ROLLOUT_KERNEL, scope
+from ...core.instrument import DECODE, LAYOUT, RESET, ROLLOUT_KERNEL, scope
 from ...core.problem import Problem
 from .control.envs import EnvSpec
 
@@ -189,7 +189,17 @@ class PolicyRolloutProblem(Problem):
             tree (pass the ``TreeAndVector`` adapter's ``batched_to_tree``
             as a workflow pop transform, as usual). For humanoid-scale
             policies where per-step weight re-reads dominate
-            (PERF_NOTES §9).
+            (PERF_NOTES §9). Where that transform is the workflow's only
+            one, the workflow hands over the undecoded batch as well
+            (``evaluate_genome``) and the kernel reads each leaf whose
+            first row in the genome and whose ``fan_out`` are multiples
+            of the resident dtype's sublane packing (8 rows of float32,
+            16 of bfloat16) in place, out of the flat ``(dim, n)``
+            genome; only the other leaves are cut out of it first
+            (``kernels.rollout_mlp.genome_rows``; for 244-64-64-17 the
+            17-wide output layer). Bit-identical fitness either way. Any
+            other chain of transforms, or ``evaluate`` called with a
+            tree: every layer cut and laid out as its own block.
         fused_planes_tile: individuals per grid cell (multiple of 128).
         fused_planes_dtype: VMEM residency dtype for the policy planes in
             the big-policy kernel (e.g. ``jnp.bfloat16`` — halves the
@@ -375,14 +385,22 @@ class PolicyRolloutProblem(Problem):
         fitness = self.reduce_fn(totals.reshape(ep, pop_size).T, axis=-1)
         return fitness, RolloutState(key=key, cap=state.cap, norm=state.norm)
 
+    @property
+    def evaluate_genome(self):
+        """``Problem.evaluate_genome``: the big-policy kernel's engine can
+        read the flat genome; the other engines have no such path."""
+        return self._evaluate_fused_planes if self.fused_planes is not None else None
+
     def _evaluate_fused_planes(
-        self, state: RolloutState, pop: Any
+        self, state: RolloutState, pop: Any, genome: Any = None, adapter: Any = None
     ) -> Tuple[jax.Array, RolloutState]:
         """Big-policy kernel engine (kernels/rollout_mlp.py): whole MLP
         resident in VMEM, per-tile early exit. ``pop`` must be an
         ``mlp_policy`` params tree (list of {"w", "b"} layers, batched on
-        the leading axis)."""
-        from ...kernels.rollout_mlp import fused_mlp_rollout
+        the leading axis). With ``genome`` (the ``(n, dim)`` batch ``pop``
+        was decoded from by ``adapter``) the kernel is handed the genome
+        whole and only the leaves it cannot read in place are cut out."""
+        from ...kernels.rollout_mlp import fused_mlp_rollout, genome_rows
 
         key = state.key
         if self.stochastic_reset:
@@ -397,10 +415,35 @@ class PolicyRolloutProblem(Problem):
                 "fused_planes expects an mlp_policy params tree "
                 "(list of {'w', 'b'} layers)"
             )
-        with scope(LAYOUT):
-            weights = tuple(l["w"].transpose(1, 2, 0) for l in pop)  # (in, out, n)
-            biases = tuple(l["b"].T for l in pop)  # (out, n)
-        sizes = (weights[0].shape[0],) + tuple(w.shape[1] for w in weights)
+        sizes = pop[0]["w"].shape[1:2] + tuple(l["w"].shape[2] for l in pop)
+        if genome is None:
+            flat = rows = None
+            with scope(LAYOUT):
+                weights = tuple(l["w"].transpose(1, 2, 0) for l in pop)  # (in, out, n)
+                biases = tuple(l["b"].T for l in pop)  # (out, n)
+        else:
+            offsets = adapter.offsets
+            resident = self.fused_planes_dtype
+            rows = genome_rows(
+                offsets, sizes, genome.dtype if resident is None else resident
+            )
+            with scope(LAYOUT):
+                # (dim, n), the member in the lane dimension: a bitcast of
+                # how XLA lays the batch out anyway. Behind a barrier, or
+                # the fusion that writes the batch out (ask's) takes the
+                # transpose as its root and, in a trace, evaluate's name
+                flat = jax.lax.optimization_barrier(genome).T
+            with scope(DECODE):
+                # rows off + k * fan_out + j of the genome are w[k, j]
+                weights = tuple(
+                    None if r[0] is not None else
+                    flat[o["w"] : o["w"] + i * j].reshape(i, j, -1)
+                    for r, o, i, j in zip(rows, offsets, sizes, sizes[1:])
+                )
+                biases = tuple(
+                    None if r[1] is not None else flat[o["b"] : o["b"] + j]
+                    for r, o, j in zip(rows, offsets, sizes[1:])
+                )
         if sizes[0] != self.env.obs_dim or sizes[-1] != self.env.act_dim:
             raise ValueError(
                 f"policy sizes {sizes} do not match env "
@@ -439,6 +482,8 @@ class PolicyRolloutProblem(Problem):
                 interpret=interpret,
                 weight_dtype=self.fused_planes_dtype,
                 linear=self.fused_planes_linear,
+                genome=flat,
+                rows=rows,
             )
         fitness = self.reduce_fn(totals.reshape(ep, pop_size).T, axis=-1)
         return fitness, RolloutState(key=key, cap=state.cap, norm=state.norm)

@@ -10,6 +10,8 @@
 
 from __future__ import annotations
 
+import itertools
+import math
 from typing import Any, Callable, Sequence, Union
 
 import jax
@@ -17,18 +19,33 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 
 
+def leaf_offsets(tree: Any) -> tuple:
+    """``(offsets, dim)``: ``tree`` with each leaf replaced by the index of
+    its first element in the flat genome ``ravel_pytree`` makes of it
+    (leaves in ``jax.tree.flatten`` order, each raveled row-major), and the
+    genome's length. Host arithmetic on shapes."""
+    leaves, treedef = jax.tree.flatten(tree)
+    sizes = [math.prod(jnp.shape(leaf)) for leaf in leaves]
+    starts = list(itertools.accumulate(sizes, initial=0))
+    return treedef.unflatten(starts[:-1]), starts[-1]
+
+
 class TreeAndVector:
     """Bidirectional adapter between a parameter pytree and a flat genome.
 
     ``to_vector``/``to_tree`` convert a single pytree; ``batched_to_tree``/
     ``batched_to_vector`` convert arrays with a leading population axis,
-    suitable as workflow candidate transforms.
+    suitable as workflow candidate transforms. ``offsets`` states where
+    each leaf lies in the genome (:func:`leaf_offsets`): a problem that can
+    read leaves out of the undecoded batch (``Problem.evaluate_genome``)
+    takes the layout from it, not from a guess at the tree's order.
     """
 
     def __init__(self, dummy_input: Any):
         flat, self._unravel = ravel_pytree(dummy_input)
         self.dim = flat.shape[0]
         self.dtype = flat.dtype
+        self.offsets, _ = leaf_offsets(dummy_input)
 
     def to_vector(self, tree: Any) -> jax.Array:
         flat, _ = ravel_pytree(tree)

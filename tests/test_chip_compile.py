@@ -13,6 +13,7 @@ inside a module-scoped fixture (never at import, never ``autouse``, never in
 lives in this one file.
 """
 
+import functools
 import os
 
 import jax
@@ -71,7 +72,7 @@ def _shapes_on(tree, sharding):
 # ------------------------------------------------------------------ kernels
 
 
-def _walker_kernel(n=16384, hidden=64, T=100):
+def _walker_kernel(n=16384, hidden=64, T=100, dtype=None):
     """``fused_mlp_rollout`` at 244-64-64-17 through the problem that calls it."""
     from evox_tpu.kernels.rollout_mlp import chain_walker_planes
     from evox_tpu.problems.neuroevolution import PolicyRolloutProblem, mlp_policy
@@ -81,12 +82,28 @@ def _walker_kernel(n=16384, hidden=64, T=100):
     init_params, apply = mlp_policy((env.obs_dim, hidden, hidden, env.act_dim))
     prob = PolicyRolloutProblem(
         apply, env, num_episodes=1, stochastic_reset=False,
-        fused_planes=penv, fused_interpret=False,
+        fused_planes=penv, fused_interpret=False, fused_planes_dtype=dtype,
     )
     pop = jax.eval_shape(
         lambda k: jax.vmap(init_params)(jax.random.split(k, n)), jax.random.PRNGKey(0)
     )
     return prob.evaluate, (jax.eval_shape(prob.init, jax.random.PRNGKey(0)), pop)
+
+
+def _walker_kernel_genome(n=16384, hidden=64, T=100, dtype=None):
+    """The same kernel as a workflow with the plain decode calls it: handed
+    the flat genome (``dim`` 20,945, one ``(20945, 128)`` block a cell),
+    ``b0 w0 b1 w1`` read in place, the 17-wide layer cut. ``dtype``
+    bfloat16: the genome converted whole and read under the 16-row rule,
+    the benchmark's low-precision control."""
+    from evox_tpu.utils import TreeAndVector
+
+    evaluate, (state, pop) = _walker_kernel(n, hidden, T, dtype)
+    adapter = TreeAndVector(jax.tree.map(lambda x: jnp.zeros(x.shape[1:], x.dtype), pop))
+    genome = jax.ShapeDtypeStruct((n, adapter.dim), jnp.float32)
+    problem = evaluate.__self__
+    fn = lambda s, p, g: problem.evaluate_genome(s, p, g, adapter)  # noqa: E731
+    return fn, (state, pop, genome)
 
 
 def _pendulum_kernel(n=65536, episodes=2, hidden=16, T=200):
@@ -122,6 +139,10 @@ def _dominance_kernel(n=20000, m=3):
 
 KERNELS = {
     "fused_mlp_rollout-244x64x64x17-n16384-T100": _walker_kernel,
+    "fused_mlp_rollout-genome20945-n16384-T100": _walker_kernel_genome,
+    "fused_mlp_rollout-genome20945-bf16-n16384-T100": functools.partial(
+        _walker_kernel_genome, dtype=jnp.bfloat16
+    ),
     "fused_rollout-h16-n65536x2-T200": _pendulum_kernel,
     "partial_topk-n4096-k128": _topk_kernel,
     "packed_dominance-n20000-m3": _dominance_kernel,
@@ -136,6 +157,22 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, args = KERNELS[name]()
     compiled = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_flat_genome_reaches_the_kernel_uncut(one_chip, no_persistent_cache):
+    """The chip's compiler hands the kernel the genome itself: the custom
+    call takes ``f32[20945,n]``, and no instruction of the program makes
+    ``w0`` or ``w1`` an array of its own (the per-layer call's does)."""
+    n = 16384
+    cut = ("f32[244,64,%d]" % n, "f32[64,64,%d]" % n, "f32[%d,15616]" % n, "f32[%d,4096]" % n)
+    fn, args = _walker_kernel_genome(n)
+    text = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile().as_text()
+    (call,) = [l for l in text.splitlines() if "tpu_custom_call" in l and " custom-call(" in l]
+    assert "f32[20945,%d]" % n in call.split("operand_layout_constraints=")[1]
+    assert not any(shape in text for shape in cut)
+    fn, args = _walker_kernel(n)
+    text = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile().as_text()
+    assert any(shape in text for shape in cut)
 
 
 def test_topk_outside_envelope_is_not_the_kernel(one_chip, no_persistent_cache):

@@ -325,3 +325,255 @@ def test_fused_mlp_rejects_out_of_range_linear():
         weights, biases, planes0, linear=(0,), **kw
     )
     assert got.shape == (n,)
+
+
+# ------------------------------------------------- the flat genome, in place
+
+# name: (layer sizes, chain_walker_planes kwargs). For float32 the rule
+# (genome_rows) reads b0 w0 b1 w1 of the first in place and cuts b2 w2, all
+# of the second in place (for bfloat16 too), nothing of the third.
+GENOME_SHAPES = {
+    "244x64x64x17": ((244, 64, 64, 17), {}),
+    "aligned-64x16x32x16": (
+        (64, 16, 32, 16), dict(n_masses=18, act_dim=16, obs_dim=64),
+    ),
+    "unaligned-244x12x10x17": ((244, 12, 10, 17), {}),
+}
+
+
+def _genome_case(shape, n, ep, T=3):
+    """A seeded flat genome ``(dim, n)`` in ``mlp_policy``'s own order, the
+    same numbers as per-layer planes, and the walker's initial planes."""
+    from evox_tpu.utils.common import leaf_offsets
+
+    sizes, env_kw = GENOME_SHAPES[shape]
+    penv = chain_walker_planes(max_steps=T, **env_kw)
+    keys = jax.random.split(jax.random.PRNGKey(0), ep)
+    env0 = jax.vmap(penv.base.reset)(keys)
+    planes0 = penv.to_planes(jax.tree.map(
+        lambda x: jnp.broadcast_to(x[:, None], (ep, n) + x.shape[1:]).reshape(
+            (ep * n,) + x.shape[1:]
+        ),
+        env0,
+    ))
+    init_params, _ = mlp_policy(sizes)
+    offsets, dim = leaf_offsets(init_params(jax.random.PRNGKey(0)))
+    genome = 0.2 * jax.random.normal(jax.random.PRNGKey(7), (dim, n))
+    weights = tuple(
+        genome[o["w"] : o["w"] + i * j].reshape(i, j, n)
+        for o, i, j in zip(offsets, sizes, sizes[1:])
+    )
+    biases = tuple(
+        genome[o["b"] : o["b"] + j] for o, j in zip(offsets, sizes[1:])
+    )
+    return penv, planes0, sizes, offsets, genome, weights, biases
+
+
+def _cut(leaves, rows, which):
+    return tuple(
+        None if r[which] is not None else x for x, r in zip(leaves, rows)
+    )
+
+
+def _genome_cross():
+    """Every combination. Tier-1 runs, beside each shape and each n, one
+    setting of the four switches and its opposite (so each switch is seen
+    both ways); the rest are marked slow."""
+    import itertools
+
+    ns, tier1 = (128, 130, 256), ((1, 0, 0, 0), (0, 0, 1, 0), (1, 1, 1, 0))
+    for shape, n, early_stop, ep, dtype, linear in itertools.product(
+        GENOME_SHAPES, ns, (True, False), (1, 2), (None, "bfloat16"), ((), (0,))
+    ):
+        switches = (int(early_stop), ep - 1, int(dtype is not None), len(linear))
+        base = tier1[(list(GENOME_SHAPES).index(shape) + ns.index(n)) % 3]
+        fast = switches in (base, tuple(1 - b for b in base))
+        yield pytest.param(
+            shape, n, early_stop, ep, dtype, linear,
+            marks=() if fast else pytest.mark.slow,
+            id=f"{shape}-n{n}-{'while' if early_stop else 'fori'}-ep{ep}"
+               f"-{dtype or 'f32'}-lin{len(linear)}",
+        )
+
+
+@pytest.mark.parametrize(
+    "shape,n,early_stop,ep,dtype,linear", list(_genome_cross())
+)
+def test_flat_genome_bit_identical_to_per_layer(
+    shape, n, early_stop, ep, dtype, linear
+):
+    """The kernel handed the flat genome, reading in place what
+    ``genome_rows`` says it may and taking the rest as blocks, returns the
+    per-layer call's totals bit for bit."""
+    from evox_tpu.kernels.rollout_mlp import genome_rows
+
+    penv, planes0, sizes, offsets, genome, weights, biases = _genome_case(
+        shape, n, ep
+    )
+    kw = dict(
+        T=3, sizes=sizes, step_planes=penv.step_planes,
+        obs_planes=penv.obs_planes, episodes=ep, early_stop=early_stop,
+        interpret=True, weight_dtype=dtype and jnp.dtype(dtype), linear=linear,
+    )
+    rows = genome_rows(offsets, sizes, dtype or genome.dtype)
+    want = fused_mlp_rollout(weights, biases, dict(planes0), **kw)
+    got = fused_mlp_rollout(
+        _cut(weights, rows, 0), _cut(biases, rows, 1), dict(planes0),
+        genome=genome, rows=rows, **kw,
+    )
+    assert np.isfinite(np.asarray(want)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_flat_genome_unaligned_rows_in_place():
+    """Row ranges off the sublane tiling are read in place too (the rule
+    leaves them cut because of what they cost on the chip, not because
+    they are wrong): every leaf of 244-64-64-17 in place, no block but the
+    genome, the same totals bit for bit."""
+    penv, planes0, sizes, offsets, genome, weights, biases = _genome_case(
+        "244x64x64x17", 130, 1
+    )
+    kw = dict(
+        T=3, sizes=sizes, step_planes=penv.step_planes,
+        obs_planes=penv.obs_planes, interpret=True,
+    )
+    rows = tuple((o["w"], o["b"]) for o in offsets)
+    assert rows[2] == (19857, 19840)
+    none = (None,) * 3
+    want = fused_mlp_rollout(weights, biases, dict(planes0), **kw)
+    got = fused_mlp_rollout(none, none, dict(planes0), genome=genome, rows=rows, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a leaf comes as a block or has its row, never both and never neither
+    with pytest.raises(ValueError, match="one of the two"):
+        fused_mlp_rollout(weights, biases, dict(planes0), genome=genome, rows=rows, **kw)
+    with pytest.raises(ValueError, match="one of the two"):
+        fused_mlp_rollout(none, none, dict(planes0), **kw)
+
+
+def test_genome_rows_rule_and_analysis():
+    """The rule on the cell's own shape: ``b0 w0 b1 w1`` in place, the
+    17-wide layer cut, for float32 and for bfloat16; and the analysis
+    states the same rows and the residency of what the kernel is handed."""
+    from evox_tpu.kernels import fused_rollout_analysis
+    from evox_tpu.kernels.rollout_mlp import genome_rows
+
+    sizes = (244, 64, 64, 17)
+    init_params, _ = mlp_policy(sizes)
+    params = init_params(jax.random.PRNGKey(0))
+    adapter = TreeAndVector(params)
+    assert adapter.offsets == [
+        {"b": 0, "w": 64}, {"b": 15680, "w": 15744}, {"b": 19840, "w": 19857}
+    ]
+    want = ((64, 0), (15744, 15680), (None, None))
+    assert genome_rows(adapter.offsets, sizes, jnp.float32) == want
+    assert genome_rows(adapter.offsets, sizes, jnp.bfloat16) == want
+    # a leaf whose first row is a multiple of 8 and not of 16
+    moved = [{"b": 8, "w": 72}] + adapter.offsets[1:]
+    assert genome_rows(moved, sizes, jnp.float32)[0] == (72, 8)
+    assert genome_rows(moved, sizes, jnp.bfloat16)[0] == (None, None)
+
+    ws = tuple(jnp.zeros((i, j, 128)) for i, j in zip(sizes, sizes[1:]))
+    bs = tuple(jnp.zeros((j, 128)) for j in sizes[1:])
+    report = fused_rollout_analysis(ws, bs, params=params)
+    assert (report["rows_in_place"], report["rows_cut"]) == (19840, 1105)
+    assert report["resident_bytes_per_cell"] == (20945 + 1105) * 128 * 4
+    assert report["headroom_bytes"] > 0
+    bf16 = fused_rollout_analysis(ws, bs, weight_dtype=jnp.bfloat16, params=params)
+    assert bf16["rows_in_place"] == 19840
+    assert bf16["resident_bytes_per_cell"] * 2 == report["resident_bytes_per_cell"]
+    assert "rows_in_place" not in fused_rollout_analysis(ws, bs)
+
+
+# ------------------------------------------- through the workflow, engaged
+
+
+def _walker_workflow(sizes, decode_of, mesh=None, island=False, pop=16, **env_kw):
+    """OpenES on the fused-planes walker as the benchmark's builder makes
+    it; ``decode_of(adapter)`` is the workflow's one pop transform."""
+    from evox_tpu import StdWorkflow
+    from evox_tpu.algorithms.so.es import OpenES
+    from evox_tpu.utils import rank_based_fitness
+
+    penv = chain_walker_planes(max_steps=4, **env_kw)
+    init_params, apply = mlp_policy(sizes)
+    adapter = TreeAndVector(init_params(jax.random.PRNGKey(0)))
+    prob = PolicyRolloutProblem(
+        apply, penv.base, num_episodes=1, stochastic_reset=False,
+        fused_planes=penv, fused_interpret=True,
+    )
+    center = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (adapter.dim,))
+    algo = OpenES(center, pop, learning_rate=0.05, noise_stdev=0.05)
+    return StdWorkflow(
+        algo, prob, opt_direction="max",
+        pop_transforms=(decode_of(adapter),),
+        fit_transforms=(rank_based_fitness,),
+        mesh=mesh, eval_shard_map=island,
+    )
+
+
+def _plain(adapter):
+    return adapter.batched_to_tree
+
+
+def _wrapped(adapter):
+    return lambda x: adapter.batched_to_tree(x)
+
+
+@pytest.mark.parametrize("island", [False, True], ids=["one-device", "shard-map"])
+def test_workflow_flat_genome_bit_identical_to_tree_path(island):
+    """Three generations of ``StdWorkflow.run`` end in the same state, bit
+    for bit, with the plain ``batched_to_tree`` (the problem is handed the
+    genome, ``w0 b0 w1 b1`` of 244-16-8-17 read in place) and with the same
+    transform wrapped in a lambda (every layer cut, as ever); also per
+    shard under the explicit evaluation island."""
+    from evox_tpu.core.distributed import create_mesh
+
+    mesh = create_mesh() if island else None
+    finals = []
+    for decode_of, engaged in ((_plain, True), (_wrapped, False)):
+        wf = _walker_workflow((244, 16, 8, 17), decode_of, mesh, island)
+        assert (wf._decode_adapter is not None) == engaged
+        state = wf.run(wf.init(jax.random.PRNGKey(1)), 3)
+        assert int(state.generation) == 3
+        finals.append(jax.tree.leaves(state))
+    for a, b in zip(*finals):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _produced_shapes(jaxpr, into):
+    """Shapes of everything any equation of ``jaxpr`` produces, sub-jaxprs
+    (jit, loops, the kernel's body) included."""
+    for eqn in jaxpr.eqns:
+        into.update(getattr(v.aval, "shape", None) for v in eqn.outvars)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _produced_shapes(sub, into)
+    return into
+
+
+def test_flat_genome_step_never_cuts_the_in_place_layers():
+    """The guard against the cut coming back: in the engaged step's jaxpr,
+    dead code dropped, no equation produces an in-place layer as an array
+    of its own, in any of the layouts it has had ((n, in * out), (n, in,
+    out), (in, out, n), (in * out, n)); the step whose decode is wrapped
+    (not engaged) does produce them, so the guard can see one."""
+    from jax._src.interpreters import partial_eval as pe
+
+    sizes, n = (64, 16, 32, 16), 16
+    leaves = set()
+    for i, j in zip(sizes, sizes[1:]):
+        leaves |= {(n, i * j), (n, i, j), (i, j, n), (i * j, n)}
+
+    def shapes(decode_of):
+        wf = _walker_workflow(
+            sizes, decode_of, pop=n, n_masses=18, act_dim=16, obs_dim=64
+        )
+        state = jax.eval_shape(wf.init, jax.random.PRNGKey(1)).replace(first_step=False)
+        closed = jax.make_jaxpr(wf._step_impl)(state)
+        jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+        return _produced_shapes(jaxpr, set())
+
+    assert not (shapes(_plain) & leaves)
+    assert shapes(_wrapped) & leaves
