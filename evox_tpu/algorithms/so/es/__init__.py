@@ -1,4 +1,5 @@
 from .open_es import OpenES
+from .lowrank_open_es import LowRankOpenES
 from .pgpe import PGPE, ClipUp
 from .cma_es import CMAES, SepCMAES, RestartCMAESDriver, IPOPCMAES, BIPOPCMAES
 from .nes import XNES, SeparableNES
@@ -21,6 +22,7 @@ except ImportError:  # pragma: no cover
 
 __all__ = [
     "OpenES",
+    "LowRankOpenES",
     "PGPE",
     "ClipUp",
     "CMAES",

@@ -36,6 +36,19 @@ class Algorithm:
     leading pop axis). ``tell`` receives fitness with shape ``(pop_size,)``
     for single-objective or ``(pop_size, n_objectives)`` for multi-objective.
 
+    The one exception is a **perturbation spec**: where a member is too large
+    to be a row of a population (a model of 1e9 parameters), ``ask`` returns a
+    pytree none of whose leaves has a population axis
+    (:class:`~evox_tpu.core.lowrank.LowRankPopulation`: the shared centre, the
+    noise key, sigma, rank, each member's sign, and the factors as a function
+    of key, leaf and pair) and says so with ``has_population_axis = False``.
+    The problem applies the perturbation inside its evaluation and still
+    returns ``(pop_size,)`` fitness, in the spec's order of members; ``tell``
+    contracts the fitness with the factors, which it draws again from the
+    state's noise key. ``StdWorkflow`` hands such candidates to ``evaluate``
+    as they are, and refuses them under a ``"pop"`` mesh, which has no axis of
+    theirs to shard.
+
     First-generation overrides: implement ``init_ask``/``init_tell`` when the
     initial evaluation differs (different pop size or bookkeeping). Workflows
     dispatch them on generation 0 when present.

@@ -108,9 +108,17 @@ def shard_pop(tree: Any, mesh: Optional[Mesh], axis_name: str = POP_AXIS) -> Any
     """Constrain every leaf's leading axis to be sharded over ``axis_name``.
 
     No-op when ``mesh`` is None (single-device path compiles identically).
+    Every leaf's leading axis is taken for the population's: a leaf without
+    one is the caller's to keep out (a scalar is refused here).
     """
     if mesh is None:
         return tree
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if jnp.ndim(leaf) == 0:
+            raise ValueError(
+                f"shard_pop: leaf {jax.tree_util.keystr(path) or '(root)'} is a scalar and "
+                f"has no population axis to shard over {axis_name!r}"
+            )
     return _constrain(tree, pop_sharding(mesh, axis_name))
 
 

@@ -110,6 +110,16 @@ NOISE = "noise"  # ask (ES): the normal draw and its mirrored concatenation
 PERTURB = "perturb"  # ask (ES): centre plus sigma times noise
 GRADIENT = "gradient"  # tell (ES): the noise drawn again and the contraction
 UPDATE = "update"  # tell (ES): the optimiser's step
+CAST = "cast"  # ask (low-rank ES): the centre's matrices cast for the forward pass
+# evaluate (the token language model, problems/lm): the parts of its forward pass
+LM_FORWARD = "lm/forward"  # the whole pass: what no part below names (the batch, the loop over chunks of pairs)
+LM_EMBED = "lm/embed"  # the rows gathered
+LM_ATTENTION = "lm/attention"  # norms, projections, RoPE, scores, softmax, output
+LM_MLP = "lm/mlp"  # the dense layer's MLP and the shared experts'
+LM_ROUTER = "lm/router"  # scores, top-k, the sort, tokens gathered and put back
+LM_EXPERTS = "lm/experts"  # the held experts' grouped products
+LM_LOWRANK = "lm/lowrank"  # every member's (x A) B^T, wherever it is added
+LM_HEAD_LOSS = "lm/head_loss"  # final norm, head, log-likelihood
 MATING = "mating"  # ask (GA): tournament selection
 CROSSOVER = "crossover"  # ask (GA)
 MUTATION = "mutation"  # ask (GA)

@@ -1,0 +1,448 @@
+"""The member model of :class:`~evox_tpu.problems.lm.TokenLMProblem`: a
+decoder-only language model with latent attention (MLA) and a mixture of
+experts (the ``deepseek_v3`` family), evaluated for every member of a
+low-rank population at once.
+
+The equations (each departure from the published modelling code is listed in
+the benchmark configuration's ``assumed``). Pre-norm residual blocks, ``h = x
++ attn(norm(x))``, ``y = h + mlp(norm(h))``, RMSNorm, a final norm, an untied
+head.
+
+- MLA, per token: ``q = x Wq`` as ``heads`` of ``qk_nope + qk_rope``. ``x
+  Wkva`` is ``kv_lora_rank + qk_rope`` wide: the first ``kv_lora_rank``
+  through RMSNorm give ``c``, the rest are ``k_rope``, one for all heads.
+  RoPE (``rope_theta``, no scaling, the two halves of the rope dimensions
+  paired, positions counted from the start of the token's document) on
+  ``q_rope`` and ``k_rope``. ``c Wkvb`` gives for each head ``k_nope`` and
+  ``v``. ``k = [k_nope, k_rope]``; scores ``q.k / sqrt(qk_nope + qk_rope)``,
+  causal and within a document only, softmax in float32; the heads' outputs
+  through ``Wo``.
+- The dense layers' MLP and the shared experts (one MLP of width
+  ``n_shared_experts * moe_intermediate_size``): ``down(silu(gate x) * up x)``.
+- Router: ``s = sigmoid(x Wr)`` in float32 over all ``n_routed_experts``; the
+  ``num_experts_per_tok`` largest of ``s + b`` (``b`` the correction bias);
+  weights ``routed_scaling_factor * s_e / sum of the chosen s``. The layer's
+  output is the shared MLP plus the weighted sum over the chosen experts
+  **that are held here** (``experts_held``, a range): the layer routes over
+  all the experts and computes its own experts' part of the result; what the
+  absent experts would add is left out and no token is dropped. On one chip
+  it runs without its exchange.
+- Fitness: mean next-token negative log-likelihood over the held rows of the
+  vocabulary, every position but the first of each document.
+- Precision: the operands of every matrix product in the dtype of the
+  centre's matrices as ``ask`` cast them (bfloat16 in the benchmark), float32
+  accumulation; norms, softmax, router scores and loss in float32.
+
+Members: the population's two halves are the two signs of ``pairs``
+perturbations (``core/lowrank.py``). Activations are laid out ``(pairs, 2,
+tokens, width)``; every product is the shared base product over all members'
+tokens plus each member's ``sign * scale * (x A_p) B_p^T``.
+
+Parameters: ``init_params`` gives the tree. The index of a leaf in
+``jax.tree.leaves`` of it is the leaf index of the perturbation law.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ...core.instrument import (
+    LM_ATTENTION,
+    LM_EMBED,
+    LM_EXPERTS,
+    LM_FORWARD,
+    LM_HEAD_LOSS,
+    LM_LOWRANK,
+    LM_MLP,
+    LM_ROUTER,
+    scope,
+)
+
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """The model's shapes. ``n_routed_experts`` is the router's width;
+    ``experts_held`` the range ``[lo, hi)`` of experts this chip holds;
+    ``vocab_size`` the rows of the vocabulary held here; ``layers`` the depth
+    held here, its first ``first_k_dense_replace`` layers dense."""
+
+    hidden_size: int
+    num_attention_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    first_k_dense_replace: int
+    layers: int
+    vocab_size: int
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 50000.0
+    init_std: float = 0.02
+
+    @classmethod
+    def from_dict(cls, config: dict) -> "LMConfig":
+        """From a configuration file's keys (the published ``config.json``'s
+        names; ``layers`` the depth held, ``n_routed_experts`` the experts
+        held beside ``n_routed_experts_published`` and ``experts_held``)."""
+        lo, hi = (int(v) for v in config["experts_held"])
+        if hi - lo != int(config["n_routed_experts"]):
+            raise ValueError(
+                f"experts_held {lo}..{hi} is not n_routed_experts={config['n_routed_experts']} experts"
+            )
+        names = {f.name for f in dataclasses.fields(cls)} - {"n_routed_experts", "experts_held"}
+        return cls(
+            n_routed_experts=int(config["n_routed_experts_published"]),
+            experts_held=(lo, hi),
+            **{k: config[k] for k in names if k in config},
+        )
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layers - self.first_k_dense_replace
+
+
+def param_shapes(cfg: LMConfig) -> dict:
+    """The parameter tree with a shape in each leaf's place."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    attn = {
+        "norm": (d,),
+        "q": (d, h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "kva": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "kv_norm": (cfg.kv_lora_rank,),
+        "kvb": (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "o": (h * cfg.v_head_dim, d),
+    }
+
+    def mlp(width, stack=()):
+        return {"gate": stack + (d, width), "up": stack + (d, width), "down": stack + (width, d)}
+
+    layers = []
+    for l in range(cfg.layers):
+        layer = {"attn": dict(attn), "mlp_norm": (d,)}
+        if l < cfg.first_k_dense_replace:
+            layer["mlp"] = mlp(cfg.intermediate_size)
+        else:
+            layer["router"] = (d, cfg.n_routed_experts)
+            layer["router_bias"] = (cfg.n_routed_experts,)
+            layer["shared"] = mlp(cfg.n_shared_experts * cfg.moe_intermediate_size)
+            layer["experts"] = mlp(cfg.moe_intermediate_size, (cfg.n_held,))
+        layers.append(layer)
+    return {
+        "embed": (cfg.vocab_size, d),
+        "layers": layers,
+        "final_norm": (d,),
+        "head": (d, cfg.vocab_size),
+    }
+
+
+def _is_shape(x: Any) -> bool:
+    return isinstance(x, tuple) and all(isinstance(n, int) for n in x)
+
+
+def init_params(cfg: LMConfig, key: jax.Array) -> dict:
+    """Seeded float32 parameters: matrix ``l`` (its index among the leaves)
+    ``init_std * normal(fold_in(key, l))``, norm gains one, the router's
+    correction bias zero."""
+    paths, treedef = jax.tree.flatten_with_path(param_shapes(cfg), is_leaf=_is_shape)
+    leaves = []
+    for l, (path, shape) in enumerate(paths):
+        if len(shape) >= 2:
+            leaves.append(cfg.init_std * jax.random.normal(jax.random.fold_in(key, l), shape, F32))
+        elif getattr(path[-1], "key", None) == "router_bias":
+            leaves.append(jnp.zeros(shape, F32))
+        else:
+            leaves.append(jnp.ones(shape, F32))
+    return jax.tree.unflatten(treedef, leaves)
+
+
+# ------------------------------------------------------------------ pieces
+
+_SIGNS = (1.0, -1.0)  # the two members of a pair, along the axis of size 2
+
+
+def _blocks(n: int, want: int) -> int:
+    """The largest divisor of ``n`` that is at most ``want``."""
+    return max(b for b in range(1, max(1, min(n, want)) + 1) if n % b == 0)
+
+
+def rmsnorm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    """Float32 in, float32 out."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def _add_lowrank(y: jax.Array, x: jax.Array, fac: Optional[tuple], scale: jax.Array) -> jax.Array:
+    """``y + sign * scale * (x A_p) B_p^T`` for ``x`` of ``(pairs, 2, T, d_in)``."""
+    if fac is None:
+        return y
+    a, b = fac
+    with scope(LM_LOWRANK):
+        xa = jnp.einsum("pstd,pdr->pstr", x, a.astype(x.dtype), preferred_element_type=F32)
+        xa = xa * (scale * jnp.asarray(_SIGNS, F32))[None, :, None, None]
+        return y + jnp.einsum(
+            "pstr,por->psto", xa.astype(x.dtype), b.astype(x.dtype), preferred_element_type=F32
+        )
+
+
+def linear(x, w, fac, scale, out_dtype) -> jax.Array:
+    """Every member's ``x @ W_i``: one product over all members' tokens and
+    the low-rank term."""
+    y = jnp.einsum("pstd,do->psto", x, w, preferred_element_type=F32)
+    return _add_lowrank(y, x, fac, scale).astype(out_dtype)
+
+
+def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """``x``: ``(..., T, heads, rope)``; ``cos``/``sin``: ``(T, rope / 2)``."""
+    x = x.astype(F32)
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def attention(cfg: LMConfig, p, f, scale, x, mask, cos, sin, block_pairs: int) -> jax.Array:
+    """``attn(norm(x))``, a block of pairs at a time: the scores of all
+    members at once would be ``pop * heads * T * T`` floats."""
+    pairs, _, t, _ = x.shape
+    dt = x.dtype
+    h, dn, dr, dv, dl = (cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim, cfg.kv_lora_rank)
+    bp = _blocks(pairs, block_pairs)
+    m = 2 * bp
+    split = lambda a: a.reshape((pairs // bp, bp) + a.shape[1:])
+
+    def block(args):
+        xb, fb = args
+        xn = rmsnorm(xb, p["norm"], cfg.rms_norm_eps).astype(dt)
+        q = linear(xn, p["q"], fb["q"], scale, dt).reshape(m, t, h, dn + dr)
+        kva = linear(xn, p["kva"], fb["kva"], scale, F32)
+        c = rmsnorm(kva[..., :dl], p["kv_norm"], cfg.rms_norm_eps).astype(dt)
+        kv = linear(c, p["kvb"], fb["kvb"], scale, dt).reshape(m, t, h, dn + dv)
+        q_rope = _rope(q[..., dn:], cos, sin).astype(dt)
+        k_rope = _rope(kva[..., dl:].reshape(m, t, 1, dr), cos, sin).astype(dt)[:, :, 0]
+        s = jnp.einsum("mqhd,mkhd->mhqk", q[..., :dn], kv[..., :dn], preferred_element_type=F32)
+        s = s + jnp.einsum("mqhd,mkd->mhqk", q_rope, k_rope, preferred_element_type=F32)
+        s = jnp.where(mask, s * (1.0 / math.sqrt(dn + dr)), jnp.finfo(F32).min)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("mhqk,mkhd->mqhd", w.astype(dt), kv[..., dn:], preferred_element_type=F32)
+        return linear(o.astype(dt).reshape(bp, 2, t, h * dv), p["o"], fb["o"], scale, dt)
+
+    return jax.lax.map(block, (split(x), jax.tree.map(split, f))).reshape(x.shape)
+
+
+def mlp(p, f, scale, xn, block_pairs: int) -> jax.Array:
+    """``down(silu(gate x) * up x)``, a block of pairs at a time."""
+    pairs, dt = xn.shape[0], xn.dtype
+    bp = _blocks(pairs, block_pairs)
+    split = lambda a: a.reshape((pairs // bp, bp) + a.shape[1:])
+
+    def block(args):
+        xb, fb = args
+        g = linear(xb, p["gate"], fb["gate"], scale, F32)
+        u = linear(xb, p["up"], fb["up"], scale, F32)
+        return linear((jax.nn.silu(g) * u).astype(dt), p["down"], fb["down"], scale, dt)
+
+    out = jax.lax.map(block, (split(xn), jax.tree.map(split, f)))
+    return out.reshape(xn.shape)
+
+
+def route(cfg: LMConfig, p, f, scale, xn) -> tuple:
+    """The router over all the experts: for each token its chosen experts
+    ``(…, k)`` and their weights."""
+    z = linear(xn, p["router"], f["router"], scale, F32)
+    s = jax.nn.sigmoid(z)
+    _, idx = jax.lax.top_k(s + p["router_bias"], cfg.num_experts_per_tok)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    w = cfg.routed_scaling_factor * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, w
+
+
+def held_experts(cfg: LMConfig, p, f, scale, xn, idx, w, block_rows: int) -> tuple:
+    """The weighted sum over each token's chosen experts that are held here,
+    and the held experts' loads ``(n_held,)``.
+
+    The assignments are sorted by expert (those of absent experts last), each
+    held expert's rows go through its MLP in blocks of ``block_rows`` (a loop
+    whose trip count is the blocks there are: nothing is padded to a capacity
+    and nothing is dropped), the results are written in sorted order and
+    gathered back by token."""
+    pairs, _, t, d = xn.shape
+    dt, k, eh = xn.dtype, cfg.num_experts_per_tok, cfg.n_held
+    lo, hi = cfg.experts_held
+    n = pairs * 2 * t
+    held = (idx >= lo) & (idx < hi)
+    key = jnp.where(held, idx - lo, eh).astype(jnp.int32).reshape(n * k)
+    experts, fe = p["experts"], f["experts"]
+    r = fe["gate"][0].shape[-1]
+    signs = jnp.asarray(_SIGNS, F32)
+    xc = xn.reshape(n, d)
+
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order)  # where each assignment lies in the sorted order
+    counts = jnp.sum(key[:, None] == jnp.arange(eh)[None, :], axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    blocks = (counts + block_rows - 1) // block_rows
+    ends = jnp.cumsum(blocks)
+    tok_sorted = jnp.pad(order // k, (0, block_rows))
+
+    def one_block(b, ybuf):
+        e = jnp.sum(b >= ends).astype(jnp.int32)  # the expert whose block this is
+        off = starts[e] + (b - (ends[e] - blocks[e])) * block_rows
+        tok = jax.lax.dynamic_slice_in_dim(tok_sorted, off, block_rows)
+        xb = xc[tok]
+        # each row's own pair, with its sign and the scale
+        own = jax.nn.one_hot(tok // (2 * t), pairs, dtype=F32) * (scale * signs[(tok // t) % 2])[:, None]
+
+        def product(xin, w_e, fac):
+            y = jnp.einsum("nd,do->no", xin, w_e, preferred_element_type=F32)
+            with scope(LM_LOWRANK):
+                a, bb = (jax.lax.dynamic_index_in_dim(v, e, axis=1, keepdims=False) for v in fac)
+                a = a.transpose(1, 0, 2).reshape(a.shape[1], pairs * r).astype(dt)
+                bb = bb.transpose(0, 2, 1).reshape(pairs * r, bb.shape[1]).astype(dt)
+                xa = jnp.einsum("nd,dq->nq", xin, a, preferred_element_type=F32)
+                xa = (xa.reshape(-1, pairs, r) * own[:, :, None]).reshape(-1, pairs * r)
+                return y + jnp.einsum("nq,qo->no", xa.astype(dt), bb, preferred_element_type=F32)
+
+        with scope(LM_EXPERTS):
+            w_e = {name: jax.lax.dynamic_index_in_dim(v, e, keepdims=False) for name, v in experts.items()}
+            g = product(xb, w_e["gate"], fe["gate"])
+            u = product(xb, w_e["up"], fe["up"])
+            y = product((jax.nn.silu(g) * u).astype(dt), w_e["down"], fe["down"]).astype(dt)
+        # rows past the expert's last belong to the next expert, whose own
+        # blocks come later and write over them
+        return jax.lax.dynamic_update_slice_in_dim(ybuf, y, off, axis=0)
+
+    ybuf = jax.lax.fori_loop(0, ends[-1], one_block, jnp.zeros((n * k + block_rows, d), dt))
+    y = ybuf[inv].reshape(n, k, d)
+    keep = (key < eh).reshape(n, k, 1)
+    out = jnp.sum(jnp.where(keep, y.astype(F32) * w.reshape(n, k, 1), 0.0), axis=1)
+    return out.astype(dt).reshape(xn.shape), counts
+
+
+def expert_layer(cfg: LMConfig, p, f, scale, xn, blocks: dict) -> tuple:
+    """``(shared MLP, held experts' part, loads)`` of an expert layer for the
+    normed ``xn``; the layer's output is the sum of the first two."""
+    with scope(LM_MLP):
+        shared = mlp(p["shared"], f["shared"], scale, xn, blocks["shared_block_pairs"])
+    with scope(LM_ROUTER):
+        idx, w = route(cfg, p, f, scale, xn)
+        routed, loads = held_experts(cfg, p, f, scale, xn, idx, w, blocks["expert_block_rows"])
+    return shared, routed, loads
+
+
+# How the forward pass is cut so that it fits. ``chunk_pairs``: the pairs that
+# go through the whole model together (their tokens are the rows of every base
+# product and of the experts' sort); within a chunk, the pairs a block of
+# attention, of the dense MLP and of the shared MLP takes; the rows of a block
+# of an expert's product.
+DEFAULT_BLOCKS = {
+    "chunk_pairs": 4,
+    "attn_block_pairs": 1,
+    "dense_block_pairs": 1,
+    "shared_block_pairs": 4,
+    "expert_block_rows": 512,
+}
+
+
+@scope(LM_FORWARD)
+def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
+            blocks: dict = DEFAULT_BLOCKS) -> dict:
+    """Every member's loss on one packed row of tokens.
+
+    ``center``/``factors``/``scale``: a ``LowRankPopulation``'s. ``ids``,
+    ``doc``, ``pos``: ``(T,)`` token ids, each token's document and its
+    position in it. Returns ``losses`` ``(pairs, 2)``, ``probe`` (pair 0's
+    float32 logits at the last ``n_probe`` positions, ``(2, n_probe, vocab)``),
+    and per expert layer ``held`` (assignments that landed on held experts)
+    and ``imbalance`` (largest held expert's load over the mean)."""
+    t = ids.shape[0]
+    dt = center["embed"].dtype
+    pairs = jax.tree.leaves(factors)[0].shape[0]
+    cp = _blocks(pairs, blocks["chunk_pairs"])
+    signs = jnp.asarray(_SIGNS, F32)
+
+    half = cfg.qk_rope_head_dim // 2
+    freq = cfg.rope_theta ** (-jnp.arange(half, dtype=F32) / half)
+    angle = pos.astype(F32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    at = jnp.arange(t)
+    mask = (at[:, None] >= at[None, :]) & (doc[:, None] == doc[None, :])
+    target = jnp.roll(ids, -1)
+    counted = (at + 1 < t) & (jnp.roll(doc, -1) == doc)  # the next token is of this document
+    weight = counted.astype(F32) / jnp.maximum(jnp.sum(counted), 1)
+
+    def chunk(fac):
+        with scope(LM_EMBED):
+            x = jnp.broadcast_to(center["embed"][ids].astype(F32), (cp, 2, t, cfg.hidden_size))
+            if fac["embed"] is not None:
+                a, b = fac["embed"]
+                with scope(LM_LOWRANK):
+                    xa = a[:, ids] * scale  # (pairs, T, r)
+                    delta = jnp.einsum("ptr,pdr->ptd", xa.astype(dt), b.astype(dt), preferred_element_type=F32)
+                    x = x + signs[None, :, None, None] * delta[:, None]
+            x = x.astype(dt)
+
+        loads = []
+        for p, f in zip(center["layers"], fac["layers"]):
+            with scope(LM_ATTENTION):
+                x = x + attention(cfg, p["attn"], f["attn"], scale, x, mask, cos, sin,
+                                  blocks["attn_block_pairs"])
+            with scope(LM_MLP):
+                xn = rmsnorm(x, p["mlp_norm"], cfg.rms_norm_eps).astype(dt)
+            if "mlp" in p:
+                with scope(LM_MLP):
+                    x = x + mlp(p["mlp"], f["mlp"], scale, xn, blocks["dense_block_pairs"])
+            else:
+                shared, routed, load = expert_layer(cfg, p, f, scale, xn, blocks)
+                with scope(LM_ROUTER):
+                    x = x + shared + routed
+                loads.append(load)
+
+        with scope(LM_HEAD_LOSS):
+            xn = rmsnorm(x, center["final_norm"], cfg.rms_norm_eps).astype(dt)
+
+            def pair(args):
+                xb, fb = args
+                logits = linear(xb[None], center["head"], jax.tree.map(lambda v: v[None], fb), scale, F32)[0]
+                nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                    logits, target[None, :, None], axis=-1
+                )[..., 0]
+                return jnp.sum(nll * weight, axis=-1)
+
+            losses = jax.lax.map(pair, (xn, fac["head"]))
+            probe = linear(
+                xn[:1, :, t - n_probe :], center["head"],
+                jax.tree.map(lambda v: v[:1], fac["head"]), scale, F32,
+            )[0]
+        loads = jnp.stack(loads) if loads else jnp.zeros((0, cfg.n_held), jnp.int32)
+        return losses, probe, loads
+
+    split = lambda a: a.reshape((pairs // cp, cp) + a.shape[1:])
+    losses, probe, loads = jax.lax.map(chunk, jax.tree.map(split, factors))
+    with scope(LM_ROUTER):
+        loads = jnp.sum(loads, axis=0)  # (expert layers, held experts)
+        imbalance = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads.astype(F32), axis=-1), 1.0)
+    return {
+        "losses": losses.reshape(pairs, 2),
+        "probe": probe[0],  # pair 0 is the first of the first chunk
+        "held": jnp.sum(loads, axis=-1).astype(jnp.int32),
+        "imbalance": imbalance.astype(F32),
+    }

@@ -2,6 +2,7 @@ from .common import (
     TreeAndVector,
     parse_opt_direction,
     rank_based_fitness,
+    standardise,
     min_by,
     compose,
     pairwise_euclidean_dist,
@@ -21,6 +22,7 @@ __all__ = [
     "TreeAndVector",
     "parse_opt_direction",
     "rank_based_fitness",
+    "standardise",
     "min_by",
     "compose",
     "pairwise_euclidean_dist",

@@ -94,6 +94,13 @@ def rank_based_fitness(fitness: jax.Array) -> jax.Array:
     return ranks / (n - 1) - 0.5
 
 
+def standardise(fitness: jax.Array) -> jax.Array:
+    """Fitness shaped to zero mean and unit variance over the population (the
+    z-score of low-rank ES; a population of equal fitness shapes to zeros)."""
+    centred = fitness - jnp.mean(fitness)
+    return centred / jnp.maximum(jnp.sqrt(jnp.mean(centred * centred)), 1e-12)
+
+
 def min_by(values: Sequence[jax.Array], keys: Sequence[jax.Array]):
     """Select the value whose key is minimal across several batches."""
     values = jnp.concatenate([jnp.atleast_1d(v) if v.ndim <= 1 else v for v in values])

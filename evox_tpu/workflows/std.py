@@ -557,12 +557,22 @@ class StdWorkflow:
 
     def _candidates(self, pop: Any) -> Any:
         """What the problem is handed: ``pop_transforms`` (the genome
-        decoded, part of evaluating it) and the ``"pop"`` constraint."""
+        decoded, part of evaluating it) and the ``"pop"`` constraint. Under a
+        mesh every leaf has to lead with the population axis; candidates that
+        say they have none (``has_population_axis = False``) are refused."""
         with scope(EVALUATE):
             cand = pop
             with scope(DECODE):
                 for t in self.pop_transforms:
                     cand = t(cand)
+            if self.mesh is not None and not getattr(cand, "has_population_axis", True):
+                # a perturbation spec (core/lowrank.py): shard_pop would lay
+                # the mesh over whatever axis comes first in each leaf
+                raise ValueError(
+                    f"the candidates ({type(cand).__name__}) have no population axis for "
+                    "the 'pop' mesh to shard: run the workflow without a mesh, or give "
+                    "pop_transforms that materialise the members"
+                )
             return shard_pop(cand, self.mesh)
 
     def _final_fitness(self, fitness: jax.Array) -> jax.Array:

@@ -80,6 +80,11 @@ CTOR_OVERRIDES = {
         "pop_size": POP,
         "memory_size": 3,
     },
+    # a centre with a matrix in it: vectors alone are never perturbed
+    "LowRankOpenES": {
+        "center_init": {"w": jnp.ones((DIM, 3)), "b": jnp.ones((DIM,))},
+        "pop_size": POP,
+    },
 }
 
 # fallback positional idioms for subclasses with (*args, **kwargs) ctors
@@ -284,6 +289,8 @@ def _fake_fitness(pop, n_objs):
     """Deterministic jittable fitness for an arbitrary candidate pytree:
     per-row sum of squares across every float leaf (shape (B,) or
     (B, n_objs))."""
+    if not getattr(pop, "has_population_axis", True):
+        pop = pop.materialise()  # a perturbation spec: its members, dense
     leaves = [
         jnp.asarray(x, jnp.float32)
         for x in jax.tree.leaves(pop)

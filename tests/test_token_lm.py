@@ -1,0 +1,253 @@
+"""The token language model under low-rank OpenES, at a tiny size on the CPU:
+the program against the plain reference (``benchmark/reference``) on seeded
+weights, the properties the member model has to have, and ``LowRankOpenES``
+against ``OpenES`` on materialised members."""
+
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import moonlight_16b_a3b_es as ref
+from evox_tpu import StdWorkflow
+from evox_tpu.algorithms.so.es import LowRankOpenES, OpenES
+from evox_tpu.core.lowrank import LowRankPopulation, tree_factors
+from evox_tpu.problems.lm import LMConfig, TokenLMProblem, init_params, packed_row
+from evox_tpu.problems.lm import model as lm
+from evox_tpu.utils import standardise
+
+ROOT = Path(__file__).resolve().parents[1]
+# hidden 64, 2 heads, 8 experts of which 2 held, top 2, 5 layers, vocabulary
+# 256 of which 32 held: the cut tests/benchmark_checks/conftest.py enters
+TINY = dict(
+    hidden_size=64, num_attention_heads=2, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, kv_lora_rank=24, intermediate_size=96, moe_intermediate_size=32,
+    n_shared_experts=2, n_routed_experts=2, n_routed_experts_published=8, experts_held=[0, 2],
+    num_experts_per_tok=2, routed_scaling_factor=2.446, first_k_dense_replace=1, layers=5,
+    vocab_size=32, rms_norm_eps=1e-5, rope_theta=50000, init_std=0.02, rank=1,
+    noise_stdev=0.001, learning_rate=0.0005, probe_positions=64,
+)
+TRAFFIC = dict(pop=8, seq_len=48, doc_len_median=12, doc_len_sigma=1.0, doc_len_min=4,
+               rows_per_member=1)
+BLOCKS = {"expert_block_rows": 8, "chunk_pairs": 2, "attn_block_pairs": 2}
+SEED = 2**33 + 5
+
+
+def _workflow(config=TINY, compute_dtype=None, rank=1):
+    cfg = LMConfig.from_dict(config)
+    key = ref._key(SEED)
+    algo = LowRankOpenES(
+        init_params(cfg, jax.random.fold_in(key, 1)), TRAFFIC["pop"], rank=rank,
+        learning_rate=config["learning_rate"], noise_stdev=config["noise_stdev"],
+        compute_dtype=compute_dtype,
+    )
+    problem = TokenLMProblem(cfg, TRAFFIC["pop"], TRAFFIC["seq_len"], doc_len_median=12,
+                             doc_len_min=4, blocks=BLOCKS)
+    wf = StdWorkflow(algo, problem, opt_direction="min", fit_transforms=(standardise,))
+    return wf, key
+
+
+def _snapshots(wf, key, steps=2):
+    # steady from the start: one compiled run loop serves every call
+    state, snaps = wf.init(key).replace(first_step=False), []
+    for _ in range(steps):
+        state = wf.run(state, 1)
+        snaps.append({
+            "generation": int(state.generation),
+            "center": [np.asarray(v) for v in jax.tree.leaves(state.algo.center)],
+            "fitness": np.asarray(state.algo.fitness),
+            "losses": np.asarray(state.prob.losses),
+            "probe": np.asarray(state.prob.probe),
+            "held": np.asarray(state.prob.held),
+        })
+    return snaps
+
+
+@pytest.mark.parametrize("rank,layers,steps", ((1, 5, 2), (2, 3, 1)))
+def test_program_agrees_with_the_reference_in_float32(rank, layers, steps):
+    """Logits, losses, routing and centre, through ``StdWorkflow.run``, a
+    second generation from the first's centre."""
+    config = dict(TINY, rank=rank, layers=layers)
+    wf, key = _workflow(config, rank=rank)
+    snaps = _snapshots(wf, key, steps)
+    generations = list(range(1, steps + 1))
+    want = ref.follow(config, TRAFFIC, SEED, generations, program=snaps)
+    for got, w in zip(snaps, want):
+        np.testing.assert_allclose(got["losses"], w["losses"], rtol=0, atol=2e-6)
+        np.testing.assert_allclose(got["probe"], w["probe"], rtol=0, atol=2e-5)
+        np.testing.assert_array_equal(got["held"], w["held"])
+        assert w["center_step"] > 0 and w["center_diff"] < 1e-5 * w["center_step"]
+    numbers = ref.numbers(config, snaps, want)
+    assert all(numbers[f"step{k}_generation_off"] == 0 for k in generations)
+    assert max(numbers[f"step{k}_logit_err"] for k in generations) < 1e-5
+
+
+def test_bfloat16_operands_stay_near_the_reference():
+    wf, key = _workflow(compute_dtype=jnp.bfloat16)
+    snaps = _snapshots(wf, key, steps=1)
+    numbers = ref.numbers(TINY, snaps, ref.follow(TINY, TRAFFIC, SEED, [1], program=snaps))
+    assert numbers["step1_logit_err"] < 0.02 and numbers["step1_center_err"] < 1e-5
+
+
+def _forward(cfg, center, ids, doc, pos, pairs=2):
+    factors = tree_factors(jax.random.PRNGKey(4), center, pairs, 1)
+    return lm.forward(cfg, center, factors, jnp.float32(1e-3), ids, doc, pos, 8, {**lm.DEFAULT_BLOCKS, **BLOCKS})
+
+
+def test_a_token_sees_only_its_document_and_its_past():
+    """The prefix property: the logits at a position do not change when a
+    later token, or a token of an earlier document, changes."""
+    cfg = LMConfig.from_dict(TINY)
+    center = init_params(cfg, jax.random.PRNGKey(1))
+    t = 24
+    ids = jax.random.randint(jax.random.PRNGKey(2), (t,), 0, cfg.vocab_size)
+    doc = jnp.asarray([0] * 10 + [1] * 14)
+    pos = jnp.concatenate([jnp.arange(10), jnp.arange(14)])
+    probe = jax.jit(lambda ids: _forward(cfg, center, ids, doc, pos)["probe"])
+    changed = lambda at: ids.at[at].set((ids[at] + 1) % cfg.vocab_size)
+    base = probe(ids)  # the last 8 positions, of document 1
+    later = probe(changed(t - 1))
+    np.testing.assert_array_equal(base[:, :-1], later[:, :-1])
+    assert not np.array_equal(base[:, -1], later[:, -1])
+    np.testing.assert_array_equal(base, probe(changed(3)))  # document 0 is not seen from document 1
+    assert not np.array_equal(base, probe(changed(12)))
+
+
+def test_the_shares_of_an_expert_layer_add_up():
+    """The outputs of the expert layer for ``experts_held`` 0-1, 2-3, 4-5 and
+    6-7, the shared MLP counted once, sum to the uncut reference's layer."""
+    whole = dict(TINY, n_routed_experts=8, experts_held=[0, 8])
+    full = ref._init(whole, jax.random.PRNGKey(7))["layers"][1]
+    pairs, t, d = 2, 16, TINY["hidden_size"]
+    xn = jax.random.normal(jax.random.PRNGKey(8), (pairs, 2, t, d))
+    no_noise = lambda tree: jax.tree.map(
+        lambda v: (jnp.zeros((pairs,) + v.shape[:-2] + (v.shape[-2], 1)),
+                   jnp.zeros((pairs,) + v.shape[:-2] + (v.shape[-1], 1))) if v.ndim >= 2 else None,
+        tree,
+    )
+    total = 0.0
+    for lo in (0, 2, 4, 6):
+        cfg = LMConfig.from_dict(dict(TINY, experts_held=[lo, lo + 2]))
+        p = dict(full, experts=jax.tree.map(lambda v: v[lo : lo + 2], full["experts"]))
+        shared, routed, loads = lm.expert_layer(
+            cfg, p, no_noise(p), jnp.float32(0.0), xn, {**lm.DEFAULT_BLOCKS, **BLOCKS}
+        )
+        total = total + routed
+        assert int(jnp.sum(loads)) > 0
+    total = total + shared
+
+    # the uncut layer, plainly: every chosen expert of all eight
+    x = xn.reshape(-1, d)
+    score = jax.nn.sigmoid(x @ full["router"])
+    _, idx = jax.lax.top_k(score + full["router_bias"], 2)
+    chosen = jnp.take_along_axis(score, idx, axis=-1)
+    weight = TINY["routed_scaling_factor"] * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    want = ref._swiglu(x, full["shared"])
+    for e in range(8):
+        mine = jnp.sum(jnp.where(idx == e, weight, 0), axis=-1)
+        want = want + mine[:, None] * ref._swiglu(x, jax.tree.map(lambda v: v[e], full["experts"]))
+    np.testing.assert_allclose(total.reshape(-1, d), want, rtol=0, atol=1e-5)
+
+
+def test_packed_row_packs_documents_until_the_row_is_full():
+    ids, doc, pos = packed_row(jax.random.PRNGKey(5), 256, 32, 12.0, 1.0, 4)
+    doc, pos = np.asarray(doc), np.asarray(pos)
+    assert ids.shape == (256,) and int(ids.min()) >= 0 and int(ids.max()) < 32
+    assert doc[0] == 0 and np.all(np.diff(doc) >= 0) and np.all(np.diff(doc) <= 1)
+    starts = np.flatnonzero(np.diff(doc, prepend=-1))
+    assert np.all(pos[starts] == 0) and np.all(np.diff(pos)[np.diff(doc) == 0] == 1)
+    assert np.all(np.diff(starts)[:-1] >= 4)  # no document under the least length
+
+
+def test_lowrank_openes_agrees_with_openes_on_materialised_members(monkeypatch):
+    """On a small dense problem where each member's dense genome is
+    materialised from the same factors: same fitness, same centre after tell
+    (``OpenES`` at ``learning_rate * sigma``: its 1 / sigma stays in the step)."""
+    pop, sigma, lr, rank = 8, 0.05, 0.3, 2
+    center = {"w": jax.random.normal(jax.random.PRNGKey(0), (5, 3)), "b": jnp.ones((3,))}
+    target = jax.random.normal(jax.random.PRNGKey(1), (pop, 18))
+    low = LowRankOpenES(center, pop, learning_rate=lr, noise_stdev=sigma, rank=rank)
+    state = low.init(jax.random.PRNGKey(2))
+    spec, state = low.ask(state)
+    assert isinstance(spec, LowRankPopulation)
+    members = spec.materialise()
+    flat = jnp.concatenate([members["b"], members["w"].reshape(pop, -1)], axis=1)  # leaves' order
+    fitness = standardise(jnp.sum((flat - target) ** 2, axis=1))
+    told = low.tell(state, fitness)
+
+    centre = jnp.concatenate([center["b"], center["w"].reshape(-1)])
+    half = (flat[: pop // 2] - centre) / sigma  # OpenES's unit noise: members are centre + sigma * noise
+    dense = OpenES(centre, pop, learning_rate=lr * sigma, noise_stdev=sigma)
+    dstate = dense.init(jax.random.PRNGKey(3))
+    monkeypatch.setattr(jax.random, "normal", lambda key, shape, *a, **k: half)
+    dpop, dstate = dense.ask(dstate)
+    dtold = dense.tell(dstate, fitness)
+    monkeypatch.undo()
+    np.testing.assert_allclose(dpop, flat, rtol=0, atol=1e-6)  # the same members, so the same fitness
+    got = jnp.concatenate([told.center["b"], told.center["w"].reshape(-1)])
+    np.testing.assert_allclose(got, dtold.center, rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(told.center["b"], center["b"])  # vectors stay at the centre
+    assert float(jnp.max(jnp.abs(told.center["w"] - center["w"]))) > 1e-3
+
+
+def test_the_spec_has_no_population_axis_and_a_mesh_says_so():
+    from evox_tpu import create_mesh
+
+    wf, key = _workflow()
+    spec, _ = wf.algorithm.ask(wf.algorithm.init(key))
+    pairs = TRAFFIC["pop"] // 2
+    assert all(v.shape[0] == pairs for v in jax.tree.leaves(spec.factors))
+    meshed = StdWorkflow(wf.algorithm, wf.problem, opt_direction="min",
+                         fit_transforms=(standardise,), mesh=create_mesh())
+    with pytest.raises(ValueError, match="no population axis"):
+        meshed.step(meshed.init(key))
+
+
+def test_problem_refuses_a_dense_population():
+    wf, key = _workflow()
+    with pytest.raises(TypeError, match="LowRankPopulation"):
+        wf.problem.evaluate(wf.problem.init(key), jnp.zeros((8, 4)))
+
+
+def test_configuration_keeps_the_published_widths():
+    """The configuration file holds every number of the catalog row's config
+    at its published value, but for the keys it lists under ``reduced``."""
+    config = json.loads((ROOT / "benchmark/configs/moonlight_16b_a3b_es.json").read_text())
+    published = {
+        "hidden_size": 2048, "intermediate_size": 11264, "moe_intermediate_size": 1408,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "num_attention_heads": 16, "num_key_value_heads": 16, "num_experts_per_tok": 6,
+        "n_shared_experts": 2, "num_hidden_layers": 27, "first_k_dense_replace": 1,
+        "routed_scaling_factor": 2.446, "rope_theta": 50000, "rms_norm_eps": 1e-05,
+        "q_lora_rank": None, "n_group": 1, "topk_group": 1, "max_position_embeddings": 8192,
+    }
+    assert {k: config[k] for k in published} == published
+    assert config["reduced"] == ["layers", "n_routed_experts", "vocab_size"]
+    assert (config["layers"], config["n_routed_experts"], config["vocab_size"]) == (5, 16, 20480)
+    assert config["published"] == {"num_hidden_layers": 27, "n_routed_experts": 64,
+                                   "vocab_size": 163840, "parameters": "16B, 3B active"}
+    cfg = LMConfig.from_dict(config)
+    shapes = jax.tree.leaves(lm.param_shapes(cfg), is_leaf=lm._is_shape)
+    assert sum(int(np.prod(s)) for s in shapes) == config["parameters_held"] == 845_308_672
+    assert set(config["limits"]) == set(config["limits_why"])
+
+
+def test_work_counts_of_the_cell():
+    from benchmark.lib import work_lm
+
+    config = json.loads((ROOT / "benchmark/configs/moonlight_16b_a3b_es.json").read_text())
+    traffic = json.loads((ROOT / "benchmark/traffic/closed_pop64_seq2048_g1.json").read_text())
+    parts = work_lm.lm_flops_per_token(config, traffic)
+    assert work_lm.held_choices_per_token(config) == 1.5
+    assert parts["dense_mlp"] == 2 * 3 * 2048 * 11264 and parts["head"] == 2 * 2048 * 20480
+    assert parts["experts"] == 4 * 1.5 * 2 * 3 * 2048 * 1408
+    assert 16 <= work_lm.expected_attended(traffic) <= 1024.5
+    # without documents a causal row of T tokens attends (T + 1) / 2 keys on average
+    whole = dict(traffic, doc_len_median=1e9, doc_len_min=2048)
+    assert work_lm.expected_attended(whole) == pytest.approx(1024.5)
+    assert parts["total"] == pytest.approx(sum(v for k, v in parts.items() if k != "total"))
