@@ -7,9 +7,19 @@ and is unit-tested against the fallback in interpret mode so the CPU mesh
 CI exercises the kernel body too. Where measurement shows the fallback
 already at the hardware roofline (see each kernel's docstring), the
 fallback stays the default.
+
+- ``dominance``: the bit-packed Pareto-dominance build.
+- ``topk``: blockwise partial top-k selection.
+- ``rollout``, ``rollout_mlp``: whole-episode policy rollouts (small and
+  VMEM-resident-weights policies).
+- ``flash_attention``: forward flash attention for the language model's
+  MLA heads (keys 192 wide, values 128) over a packed row of documents; its
+  fallback and reference is ``problems/lm/model.py`` ``attend_plain``, and
+  the caller chooses by platform and shape (``flash_block_sizes``).
 """
 
 from .dominance import packed_dominance, packed_dominance_reference
+from .flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
 from .topk import default_use_kernel, partial_topk, partial_topk_reference
 from .rollout import (
     SoAEnv,
@@ -29,6 +39,9 @@ from .rollout_mlp import (
 __all__ = [
     "packed_dominance",
     "packed_dominance_reference",
+    "flash_attention",
+    "flash_block_bounds",
+    "flash_block_sizes",
     "default_use_kernel",
     "partial_topk",
     "partial_topk_reference",

@@ -16,7 +16,14 @@ head.
   ``q_rope`` and ``k_rope``. ``c Wkvb`` gives for each head ``k_nope`` and
   ``v``. ``k = [k_nope, k_rope]``; scores ``q.k / sqrt(qk_nope + qk_rope)``,
   causal and within a document only, softmax in float32; the heads' outputs
-  through ``Wo``.
+  through ``Wo``. Two bodies, one result (``forward`` chooses by what it can
+  observe, no option): on the TPU backend, where the row divides into blocks
+  of 128 and ``qk_nope`` and ``v`` are 128 wide, ``kernels.flash_attention``
+  (a query block's scores, running maximum, running sum and output
+  accumulator stay in VMEM in float32; key blocks after the query block or
+  wholly of earlier documents are not visited; only the output is written);
+  elsewhere ``attend_plain``, which makes every member's and head's ``(T,
+  T)`` scores in HBM and is the kernel's reference in the tests.
 - The dense layers' MLP and the shared experts (one MLP of width
   ``n_shared_experts * moe_intermediate_size``): ``down(silu(gate x) * up x)``.
 - Router: ``s = sigmoid(x Wr)`` in float32 over all ``n_routed_experts``; the
@@ -31,7 +38,12 @@ head.
   vocabulary, every position but the first of each document.
 - Precision: the operands of every matrix product in the dtype of the
   centre's matrices as ``ask`` cast them (bfloat16 in the benchmark), float32
-  accumulation; norms, softmax, router scores and loss in float32.
+  accumulation; norms, softmax, router scores and loss in float32. In
+  attention the scores, their scale, the maximum, the sum and the output
+  accumulator are float32 in both bodies; the probabilities are cast to the
+  operands' dtype as the operand of ``p.v`` (normalised in the plain body,
+  unnormalised in the kernel, which divides the accumulator by the sum at the
+  end: the same relative rounding), and the output once more.
 
 Members: the population's two halves are the two signs of ``pairs``
 perturbations (``core/lowrank.py``). Activations are laid out ``(pairs, 2,
@@ -45,6 +57,7 @@ Parameters: ``init_params`` gives the tree. The index of a leaf in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -62,6 +75,7 @@ from ...core.instrument import (
     LM_ROUTER,
     scope,
 )
+from ...kernels.flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
 
 F32 = jnp.float32
 
@@ -218,9 +232,48 @@ def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def attention(cfg: LMConfig, p, f, scale, x, mask, cos, sin, block_pairs: int) -> jax.Array:
-    """``attn(norm(x))``, a block of pairs at a time: the scores of all
-    members at once would be ``pop * heads * T * T`` floats."""
+def attend_plain(cfg: LMConfig, mask: jax.Array, q, q_rope, kv, k_rope) -> jax.Array:
+    """The plain body: the scores of all the block's members and heads, ``(m,
+    heads, T, T)`` float32, made, masked and normalised in passes through
+    HBM. ``q`` ``(m, T, heads, nope + rope)`` of which the nope part is read;
+    ``q_rope`` ``(m, T, heads, rope)`` and ``k_rope`` ``(m, T, rope)`` after
+    RoPE; ``kv`` ``(m, T, heads, nope + v)``. Returns ``(m, T, heads * v)``."""
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    s = jnp.einsum("mqhd,mkhd->mhqk", q[..., :dn], kv[..., :dn], preferred_element_type=F32)
+    s = s + jnp.einsum("mqhd,mkd->mhqk", q_rope, k_rope, preferred_element_type=F32)
+    s = jnp.where(mask, s * (1.0 / math.sqrt(dn + dr)), jnp.finfo(F32).min)
+    w = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("mhqk,mkhd->mqhd", w.astype(q.dtype), kv[..., dn:], preferred_element_type=F32)
+    return o.astype(q.dtype).reshape(q.shape[:2] + (-1,))
+
+
+def attend_flash(cfg: LMConfig, doc: jax.Array, bounds: tuple, block_sizes: tuple,
+                 q, q_rope, kv, k_rope) -> jax.Array:
+    """The same, by ``kernels.flash_attention``: a query block's scores stay
+    on the chip, the key blocks outside ``bounds`` are not visited."""
+    m, t, h, _ = q.shape
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    return flash_attention(
+        q[..., :dn].reshape(m, t, h * dn), q_rope.transpose(0, 2, 1, 3), kv.reshape(m, t, -1), k_rope,
+        doc, bounds, heads=h, scale=1.0 / math.sqrt(dn + dr),
+        block_q=block_sizes[0], block_k=block_sizes[1], interpret=jax.default_backend() != "tpu",
+    )
+
+
+def _flash_blocks(cfg: LMConfig, t: int) -> Optional[tuple]:
+    """The kernel's ``(block_q, block_k)`` where it runs: on the TPU backend,
+    at head widths and a row length it takes. Elsewhere ``None``: the plain
+    body."""
+    if jax.default_backend() != "tpu":
+        return None
+    return flash_block_sizes(t, cfg.qk_nope_head_dim, cfg.v_head_dim)
+
+
+def attention(cfg: LMConfig, p, f, scale, x, attend, cos, sin, block_pairs: int) -> jax.Array:
+    """``attn(norm(x))``, a block of pairs at a time (the plain body's scores
+    of all members at once would be ``pop * heads * T * T`` floats).
+    ``attend``: ``attend_plain`` or ``attend_flash`` with the row's mask or
+    bounds bound."""
     pairs, _, t, _ = x.shape
     dt = x.dtype
     h, dn, dr, dv, dl = (cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -238,12 +291,8 @@ def attention(cfg: LMConfig, p, f, scale, x, mask, cos, sin, block_pairs: int) -
         kv = linear(c, p["kvb"], fb["kvb"], scale, dt).reshape(m, t, h, dn + dv)
         q_rope = _rope(q[..., dn:], cos, sin).astype(dt)
         k_rope = _rope(kva[..., dl:].reshape(m, t, 1, dr), cos, sin).astype(dt)[:, :, 0]
-        s = jnp.einsum("mqhd,mkhd->mhqk", q[..., :dn], kv[..., :dn], preferred_element_type=F32)
-        s = s + jnp.einsum("mqhd,mkd->mhqk", q_rope, k_rope, preferred_element_type=F32)
-        s = jnp.where(mask, s * (1.0 / math.sqrt(dn + dr)), jnp.finfo(F32).min)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("mhqk,mkhd->mqhd", w.astype(dt), kv[..., dn:], preferred_element_type=F32)
-        return linear(o.astype(dt).reshape(bp, 2, t, h * dv), p["o"], fb["o"], scale, dt)
+        o = attend(q, q_rope, kv, k_rope)
+        return linear(o.reshape(bp, 2, t, h * dv), p["o"], fb["o"], scale, dt)
 
     return jax.lax.map(block, (split(x), jax.tree.map(split, f))).reshape(x.shape)
 
@@ -371,8 +420,11 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
     ``doc``, ``pos``: ``(T,)`` token ids, each token's document and its
     position in it. Returns ``losses`` ``(pairs, 2)``, ``probe`` (pair 0's
     float32 logits at the last ``n_probe`` positions, ``(2, n_probe, vocab)``),
-    and per expert layer ``held`` (assignments that landed on held experts)
-    and ``imbalance`` (largest held expert's load over the mean)."""
+    per expert layer ``held`` (assignments that landed on held experts) and
+    ``imbalance`` (largest held expert's load over the mean), and
+    ``attn_blocks``: the key blocks attention's kernel visits for this row's
+    documents over those of a dense causal pass (1 where the plain body runs:
+    the whole row is its one block)."""
     t = ids.shape[0]
     dt = center["embed"].dtype
     pairs = jax.tree.leaves(factors)[0].shape[0]
@@ -384,7 +436,16 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
     angle = pos.astype(F32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     at = jnp.arange(t)
-    mask = (at[:, None] >= at[None, :]) & (doc[:, None] == doc[None, :])
+    flash = _flash_blocks(cfg, t)
+    if flash is None:
+        mask = (at[:, None] >= at[None, :]) & (doc[:, None] == doc[None, :])
+        attend = functools.partial(attend_plain, cfg, mask)
+        attn_blocks = jnp.ones((), F32)  # the whole row is one block
+    else:
+        first, last = flash_block_bounds(doc, *flash)
+        attend = functools.partial(attend_flash, cfg, doc, (first, last), flash)
+        # a query attends itself, so ``last`` is the diagonal's block: a dense causal pass visits 0 .. last
+        attn_blocks = jnp.sum(last - first + 1).astype(F32) / jnp.sum(last + 1)
     target = jnp.roll(ids, -1)
     counted = (at + 1 < t) & (jnp.roll(doc, -1) == doc)  # the next token is of this document
     weight = counted.astype(F32) / jnp.maximum(jnp.sum(counted), 1)
@@ -403,7 +464,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         loads = []
         for p, f in zip(center["layers"], fac["layers"]):
             with scope(LM_ATTENTION):
-                x = x + attention(cfg, p["attn"], f["attn"], scale, x, mask, cos, sin,
+                x = x + attention(cfg, p["attn"], f["attn"], scale, x, attend, cos, sin,
                                   blocks["attn_block_pairs"])
             with scope(LM_MLP):
                 xn = rmsnorm(x, p["mlp_norm"], cfg.rms_norm_eps).astype(dt)
@@ -445,4 +506,5 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         "probe": probe[0],  # pair 0 is the first of the first chunk
         "held": jnp.sum(loads, axis=-1).astype(jnp.int32),
         "imbalance": imbalance.astype(F32),
+        "attn_blocks": attn_blocks,
     }

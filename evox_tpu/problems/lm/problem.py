@@ -42,7 +42,10 @@ class TokenLMState(PyTreeNode):
     ``losses``; ``probe``, the float32 logits of members 0 and ``pop / 2``
     (the two signs of pair 0) at the row's last positions; for each expert
     layer ``held``, the routed assignments that landed on held experts, and
-    ``imbalance``, the largest held expert's load over the mean."""
+    ``imbalance``, the largest held expert's load over the mean;
+    ``attn_blocks``, the key blocks the attention kernel's loop bounds visited
+    over the key blocks of a dense causal pass (how much of the row's
+    attention the documents let it skip; 1 where the plain body ran)."""
 
     key: jax.Array = field(sharding=P())
     generation: jax.Array = field(sharding=P())
@@ -50,6 +53,7 @@ class TokenLMState(PyTreeNode):
     probe: jax.Array = field(sharding=P())
     held: jax.Array = field(sharding=P())
     imbalance: jax.Array = field(sharding=P())
+    attn_blocks: jax.Array = field(sharding=P())
 
 
 class TokenLMProblem(Problem):
@@ -100,6 +104,7 @@ class TokenLMProblem(Problem):
             probe=jnp.zeros((2, self.n_probe, self.cfg.vocab_size), jnp.float32),
             held=jnp.zeros((n,), jnp.int32),
             imbalance=jnp.zeros((n,), jnp.float32),
+            attn_blocks=jnp.zeros((), jnp.float32),
         )
 
     def evaluate(self, state: TokenLMState, pop: Any) -> Tuple[jax.Array, TokenLMState]:
@@ -115,5 +120,5 @@ class TokenLMProblem(Problem):
         losses = out["losses"].T.reshape(-1)  # the + half, then the - half
         return losses, state.replace(
             generation=state.generation + 1, losses=losses, probe=out["probe"],
-            held=out["held"], imbalance=out["imbalance"],
+            held=out["held"], imbalance=out["imbalance"], attn_blocks=out["attn_blocks"],
         )

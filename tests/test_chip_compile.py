@@ -137,6 +137,23 @@ def _dominance_kernel(n=20000, m=3):
     return fn, (jax.ShapeDtypeStruct((n, m), jnp.float32),)
 
 
+def _flash_kernel(members=2, t=2048, heads=16, nope=128, rope=64, v=128):
+    """``flash_attention`` at the language-model cell's shapes: two members a
+    call, MLA's 192-wide queries and keys, 128-wide values, bfloat16."""
+    from evox_tpu.kernels.flash_attention import flash_attention, flash_block_sizes
+
+    bq, bk = flash_block_sizes(t, nope, v)
+    fn = lambda qn, qr, kv, kr, doc, first, last: flash_attention(  # noqa: E731
+        qn, qr, kv, kr, doc, (first, last), heads=heads, scale=(nope + rope) ** -0.5,
+        block_q=bq, block_k=bk, interpret=False,
+    )
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return fn, (bf16(members, t, heads * nope), bf16(members, heads, t, rope),
+                bf16(members, t, heads * (nope + v)), bf16(members, t, rope),
+                i32(t), i32(t // bq), i32(t // bq))
+
+
 KERNELS = {
     "fused_mlp_rollout-244x64x64x17-n16384-T100": _walker_kernel,
     "fused_mlp_rollout-genome20945-n16384-T100": _walker_kernel_genome,
@@ -146,6 +163,7 @@ KERNELS = {
     "fused_rollout-h16-n65536x2-T200": _pendulum_kernel,
     "partial_topk-n4096-k128": _topk_kernel,
     "packed_dominance-n20000-m3": _dominance_kernel,
+    "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
 }
 
 

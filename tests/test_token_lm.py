@@ -38,6 +38,16 @@ BLOCKS = {"expert_block_rows": 8, "chunk_pairs": 2, "attn_block_pairs": 2}
 SEED = 2**33 + 5
 
 
+@pytest.fixture(params=("plain", "kernel"))
+def body(request, monkeypatch):
+    """Attention's two bodies: the plain one, which the CPU backend takes, and
+    the flash kernel (interpreted here), which the TPU backend takes at
+    shapes it accepts; the choice is steered here, not by an option."""
+    if request.param == "kernel":
+        monkeypatch.setattr(lm, "_flash_blocks", lambda cfg, t: (8, 8))
+    return request.param
+
+
 def _workflow(config=TINY, compute_dtype=None, rank=1):
     cfg = LMConfig.from_dict(config)
     key = ref._key(SEED)
@@ -69,7 +79,7 @@ def _snapshots(wf, key, steps=2):
 
 
 @pytest.mark.parametrize("rank,layers,steps", ((1, 5, 2), (2, 3, 1)))
-def test_program_agrees_with_the_reference_in_float32(rank, layers, steps):
+def test_program_agrees_with_the_reference_in_float32(rank, layers, steps, body):
     """Logits, losses, routing and centre, through ``StdWorkflow.run``, a
     second generation from the first's centre."""
     config = dict(TINY, rank=rank, layers=layers)
@@ -87,7 +97,7 @@ def test_program_agrees_with_the_reference_in_float32(rank, layers, steps):
     assert max(numbers[f"step{k}_logit_err"] for k in generations) < 1e-5
 
 
-def test_bfloat16_operands_stay_near_the_reference():
+def test_bfloat16_operands_stay_near_the_reference(body):
     wf, key = _workflow(compute_dtype=jnp.bfloat16)
     snaps = _snapshots(wf, key, steps=1)
     numbers = ref.numbers(TINY, snaps, ref.follow(TINY, TRAFFIC, SEED, [1], program=snaps))
@@ -99,7 +109,7 @@ def _forward(cfg, center, ids, doc, pos, pairs=2):
     return lm.forward(cfg, center, factors, jnp.float32(1e-3), ids, doc, pos, 8, {**lm.DEFAULT_BLOCKS, **BLOCKS})
 
 
-def test_a_token_sees_only_its_document_and_its_past():
+def test_a_token_sees_only_its_document_and_its_past(body):
     """The prefix property: the logits at a position do not change when a
     later token, or a token of an earlier document, changes."""
     cfg = LMConfig.from_dict(TINY)
@@ -116,6 +126,25 @@ def test_a_token_sees_only_its_document_and_its_past():
     assert not np.array_equal(base[:, -1], later[:, -1])
     np.testing.assert_array_equal(base, probe(changed(3)))  # document 0 is not seen from document 1
     assert not np.array_equal(base, probe(changed(12)))
+
+
+def test_attn_blocks_is_the_visited_share_of_a_dense_causal_pass(body):
+    """The counter against a count from the dense mask: key blocks that hold
+    a key some query of the block attends, over the blocks at or under the
+    diagonal. The plain body makes the whole row as one block."""
+    cfg = LMConfig.from_dict(TINY)
+    center = init_params(cfg, jax.random.PRNGKey(1))
+    t, b = 48, 8
+    ids, doc, pos = packed_row(jax.random.PRNGKey(3), t, cfg.vocab_size, 6.0, 1.0, 2)
+    got = float(_forward(cfg, center, ids, doc, pos)["attn_blocks"])
+    at, d = np.arange(t), np.asarray(doc)
+    mask = (at[:, None] >= at[None, :]) & (d[:, None] == d[None, :])
+    seen = mask.reshape(t // b, b, t // b, b).any(axis=(1, 3)).sum()
+    assert seen < 21  # some block under the diagonal is skipped in this row
+    assert got == pytest.approx(seen / 21 if body == "kernel" else 1.0)
+    wf, key = _workflow()
+    state = wf.run(wf.init(key).replace(first_step=False), 1)
+    assert 0.0 < float(state.prob.attn_blocks) <= 1.0
 
 
 def test_the_shares_of_an_expert_layer_add_up():
