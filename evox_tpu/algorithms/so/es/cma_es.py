@@ -16,9 +16,9 @@ Capability parity with reference src/evox/algorithms/so/es_variants/cma_es.py
   noted buggy there, SURVEY.md §2.4).
 
 The reference warns its eigh is numerically hardware-sensitive (cma_es.py
-:40-44); validated here on a real v5e chip: f32 ``jnp.linalg.eigh``
-converges CMAES to f(mean)=1.3e-5 and SepCMAES to 5.2e-12 on Sphere-10D
-within 60/80 generations — no host offload or f64 needed.
+:40-44); here f32 ``jnp.linalg.eigh`` is used as it is — no host offload
+or f64 (the convergence tests of tests/test_so_es.py run on it; dense
+CMA-ES has no benchmark cell yet: PERF.md section 7, row 7).
 """
 
 from __future__ import annotations

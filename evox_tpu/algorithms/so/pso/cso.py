@@ -6,9 +6,8 @@ from its winner and from the swarm mean, and only the updated losers are
 re-evaluated (half the population per generation) — the ``init_ask`` /
 ``init_tell`` first-generation pattern of the reference.
 
-TPU-first data movement: the reference formulation (and this module's
-round-3 version) indexes winners/losers through ``students``/``teachers``
-index vectors — five random row-gathers in ``ask`` plus three scatters in
+TPU-first data movement: the reference formulation indexes
+winners/losers through ``students``/``teachers`` index vectors — five random row-gathers in ``ask`` plus three scatters in
 ``tell`` per generation. A population is a *set*: CSO never needs stable
 row identity, so this version permutes the population ONCE into
 pair-major layout (`pop[perm]` — the single gather), selects winners and
@@ -19,20 +18,18 @@ permuted population IS the population), so the separate full-population
 mean pass disappears too. Distributionally identical to the reference
 update
 (same pairing law, same learning rule, same tie-breaking: on equal
-fitness the second row of the pair wins). The algorithm is
-HBM-streaming-bound; see PERF_NOTES §12 for the measured traffic budget
-and the shared-chip streaming roofline that caps this leg.
+fitness the second row of the pair wins). The algorithm streams its
+whole state through HBM every generation; no benchmark cell times it yet
+(PERF.md section 7, row 2).
 
 State carries NO ask→tell intermediates: ``tell`` replays the pairing
 pass from the carried generation key (JAX's PRNG is counter-based, so
-the replay is bit-identical — the OpenES/PGPE trick of PERF_NOTES §10).
+the replay is bit-identical — the trick OpenES and PGPE use too).
 Inside the fused jitted step XLA CSEs the replay against ``ask``'s pass
-(zero extra compute); what it buys is the loop carry — ~40 MB/gen of
-dead winners/candidates writes at the bench shape (pop=4096, d=1024)
-that a ``fori_loop`` of generations otherwise round-trips through HBM
-(PERF_NOTES §12, measured 1.1–1.25x on the streaming-bound leg). Under
-separately-jitted ask/tell (external problems) the replay costs one
-extra streaming pass — still cheaper than carrying it in HBM state.
+(zero extra compute); what it buys is the loop carry — the dead
+winners/candidates writes that a ``fori_loop`` of generations otherwise
+round-trips through HBM. Under separately-jitted ask/tell (external
+problems) the replay costs one extra streaming pass — still cheaper than carrying it in HBM state.
 The state structure is branch-invariant, so ``lax.cond`` container
 dispatch (containers/clustered.py) needs no special-casing.
 """

@@ -8,7 +8,7 @@ answer to "is the state still the state we computed?":
   state pytree. Built from **bitwise-stable reductions only** (wrapping
   u32 sum / XOR / min / max over position-mixed bit-cast uint32 views,
   plus exact nan/inf counts). Float sums are reassociation-dependent
-  across GSPMD layouts (PERF_NOTES §15), so a digest built on them would
+  across GSPMD layouts, so a digest built on them would
   false-alarm on every mesh change; modular-integer reductions are
   associative *and* commutative exactly, so the digest is a function of
   the logical value alone — layout-invariant by construction (law tested

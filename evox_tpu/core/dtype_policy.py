@@ -1,11 +1,11 @@
 """Mixed-precision storage policy — bf16 at rest, f32 in flight.
 
-PR 4's roofline analytics showed the streaming legs (CSO at 331 GB/s,
-55% of the measured 607 GB/s HBM ceiling) are memory-bound: every
-generation round-trips the whole population/velocity/fitness state
-through HBM. evosax (PAPERS.md) made the same observation for batched
-JAX strategies — memory traffic per generation is the budget. The
-cheapest lever is to halve the bytes: store the per-individual state in
+The streaming algorithms (CSO, PSO) are memory-bound by their
+arithmetic: every generation round-trips the whole
+population/velocity/fitness state through HBM (no benchmark cell times
+them yet: PERF.md section 7, row 2). evosax (PAPERS.md) made the same
+observation for batched JAX strategies — memory traffic per generation
+is the budget. The cheapest lever is to halve the bytes: store the per-individual state in
 ``bfloat16`` and compute in ``float32``.
 
 Design (mirrors the ``field(sharding=...)`` layout convention):
@@ -88,7 +88,7 @@ class DtypePolicy:
         return self.storage == self.compute
 
     def report(self) -> dict:
-        """JSON-serializable description (lands in run_report/bench)."""
+        """JSON-serializable description (lands in run_report)."""
         return {
             "storage": str(self.storage.name),
             "compute": str(self.compute.name),
@@ -96,7 +96,7 @@ class DtypePolicy:
         }
 
 
-# the one policy the bench / docs talk about: bf16 at rest, f32 in flight
+# the one policy the docs talk about: bf16 at rest, f32 in flight
 BF16_STORAGE = DtypePolicy(storage=jnp.bfloat16, compute=jnp.float32)
 
 
@@ -173,7 +173,7 @@ def storage_eligible_fields(state: Any) -> dict:
 
 
 def policy_report(workflow: Any) -> dict:
-    """The ``dtype_policy`` section for run_report / bench JSON, duck-
+    """The ``dtype_policy`` section for run_report, duck-
     typed off ``workflow.dtype_policy`` (absent → explicit f32 default,
     so reports always state the precision they ran at)."""
     policy = getattr(workflow, "dtype_policy", None)

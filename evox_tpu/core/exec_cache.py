@@ -8,8 +8,8 @@ event: entry points are AOT-compiled once per cache key
 (``jax.jit(fn).lower(*args).compile()``), held in memory, and persisted
 through :func:`jax.experimental.serialize_executable.serialize` to a
 content-addressed on-disk store, so a COLD PROCESS warm-starts its fleet
-by deserializing executables in milliseconds instead of recompiling
-(ROADMAP item 4; measured in bench.py's ``serving_elastic`` leg).
+by deserializing executables instead of recompiling (no benchmark cell
+drives it yet: ROADMAP D6).
 
 Cache key anatomy (what must match for an entry to be reusable):
 
@@ -97,8 +97,8 @@ __all__ = [
 _SCHEMA = "evox_tpu.exec_cache/v1"
 
 # every live cache, so the atexit guard can drop deserialized-executable
-# references before jax's clear_backends runs (PERF_NOTES §23: such a
-# reference surviving to interpreter teardown can segfault). WeakSet: the
+# references before jax's clear_backends runs (such a reference
+# surviving to interpreter teardown can segfault). WeakSet: the
 # guard must never be what keeps a cache alive.
 _LIVE_CACHES: "weakref.WeakSet" = weakref.WeakSet()
 _GUARD_ARMED = False
@@ -257,9 +257,9 @@ class ExecutableCache:
         _arm_teardown_guard()
 
     def close(self) -> None:
-        """Drop every in-memory executable reference (PERF_NOTES §23:
-        a DESERIALIZED executable alive at interpreter exit can
-        segfault jax's atexit teardown). Durable state — the on-disk
+        """Drop every in-memory executable reference (a DESERIALIZED
+        executable alive at interpreter exit can segfault jax's atexit
+        teardown). Durable state — the on-disk
         store, counters, provenance — is untouched, and the cache stays
         usable: a later request simply pays a disk hit (or a recompile)
         again. Idempotent; also run by the module's atexit guard."""

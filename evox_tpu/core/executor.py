@@ -932,7 +932,7 @@ class GenerationExecutor:
         failed fsync still surfaces), then shut their worker threads
         down and forget them. A lane thread alive at interpreter exit
         races the jax atexit backend teardown the same way a live
-        deserialized executable does (PERF_NOTES §23) — pod drains and
+        deserialized executable does — pod drains and
         the multi-pod gateway call this before letting the process exit.
         Idempotent; a closed executor lazily re-creates lanes if used
         again."""

@@ -15,8 +15,7 @@ reports that first call separately (``first_call_s``) plus an estimated
 ``compile_s`` (first call minus the steady-state median) alongside the
 steady-state dispatch statistics. Host fetches go through
 :meth:`DispatchRecorder.fetch`, which accounts bytes and seconds per
-fetch site (a big-array fetch costs transfer time — the reason bench.py
-fetches a small leaf).
+fetch site (a big-array fetch costs transfer time).
 
 Beyond timing, the recorder is the host half of the roofline analytics
 layer (core/xla_cost.py):
@@ -24,8 +23,8 @@ layer (core/xla_cost.py):
 - **Work-normalized timing**: each call carries a work count (``run``'s
   ``n_steps``; 1 elsewhere). When an entry was called at two distinct
   trip counts, the per-generation time is the *differenced slope*
-  ``(t(n2) - t(n1)) / (n2 - n1)`` — bench.py's latency-cancelling
-  discipline — otherwise the steady median is used and flagged
+  ``(t(n2) - t(n1)) / (n2 - n1)``, in which the per-dispatch latency
+  cancels — otherwise the steady median is used and flagged
   ``latency_confounded`` (a single-trip-count timing still contains the
   whole per-dispatch round-trip).
 - **Retrace detection**: every call's abstract argument signature is
@@ -261,7 +260,7 @@ class _EntryStats:
     def _per_work(self) -> Optional[dict]:
         """Seconds per work unit. Differenced slope over the two extreme
         distinct work counts when available (per-dispatch latency cancels
-        exactly, bench.py's protocol); else the steady median divided by
+        exactly); else the steady median divided by
         its median work, flagged latency-confounded. The cold call (index
         0, trace+compile) is excluded whenever warmer data exists."""
         if not self.times:
@@ -289,7 +288,7 @@ class _EntryStats:
                 }
                 if cold_included:
                     # one end of the slope still contains trace+compile —
-                    # warm both trip counts (bench.py discipline) to clear
+                    # warm both trip counts to clear
                     out["cold_call_included"] = True
                 return out
         times, works = (self.times, self.works) if steady is None else steady
@@ -780,7 +779,7 @@ def run_report(
     # incl. the counter-sum and event-ordering coherence rules. v11 adds
     # the top-level `schema_version` int (PR 16 satellite: the version
     # is grep-able without parsing the schema string; check_report
-    # --schema prints the validated range) and the optional `metrics` +
+    # --schema prints the one version it takes) and the optional `metrics` +
     # `slo` sections (workflows/flightrec.py FlightRecorder: the
     # serving-plane registry snapshot, stream accounting, and the SLO
     # ledger) — validated when present, incl. slo↔tenancy.queue

@@ -106,7 +106,7 @@ __all__ = [
 
 #: jaxlib's coordination client aborts the PROCESS (C++ LOG(FATAL) →
 #: SIGABRT) roughly this many seconds after it stops reaching the
-#: coordinator (PERF_NOTES §25) — the hard ceiling a supervisor deadline
+#: coordinator — the hard ceiling a supervisor deadline
 #: must undercut in a real multi-process pod to classify the failure
 #: before the runtime kills the classifier
 JAXLIB_COORD_ABORT_S = 10.0
@@ -260,7 +260,7 @@ class PodSupervisor:
         heartbeat_interval_s: KV heartbeat period. The census probe
             waits ``2 × interval + 0.2 s`` between its two reads, so
             detection latency after a deadline hit is roughly
-            ``deadline_s + 2 × interval`` (PERF_NOTES §25 budgets it).
+            ``deadline_s + 2 × interval``.
         journal: a :class:`~evox_tpu.workflows.journal.RunJournal`, a
             directory path for one, or ``None``. Membership transitions
             are appended as ``pod_*`` records by PROCESS 0 only (the
@@ -331,7 +331,7 @@ class PodSupervisor:
             self.process_id, self.process_count = _dist_process_info()
         except Exception:  # pragma: no cover - backend not initializable
             self.process_id, self.process_count = 0, 1
-        # PERF_NOTES §25: in a REAL multi-process pod, jaxlib's own
+        # in a REAL multi-process pod, jaxlib's own
         # coordination client LOG(FATAL)s the process ~10 s after it
         # stops reaching the coordinator — a supervisor deadline whose
         # worst-case detection latency (deadline + census probe slack)
@@ -351,7 +351,7 @@ class PodSupervisor:
                 warnings.warn(
                     f"PodSupervisor deadline_s={self.deadline_s} cannot "
                     f"win the race against jaxlib's ~{JAXLIB_COORD_ABORT_S:g} s "
-                    "coordination heartbeat abort (PERF_NOTES §25): "
+                    "coordination heartbeat abort: "
                     f"detection needs deadline + {slack:.1f} s census "
                     f"slack; clamping to {clamped:.2f} s so pod faults "
                     "are classified instead of dying by SIGABRT",

@@ -14,7 +14,7 @@ deliberately callback-free and trace-free:
   identically on the 8-device CPU mesh and on the TPU.
 - **Roofline merge**: static FLOPs/bytes divided by the *differenced*
   measured seconds (``DispatchRecorder``'s slope over distinct trip
-  counts — bench.py's latency-cancelling discipline) give achieved TF/s
+  counts, in which the per-dispatch latency cancels) give achieved TF/s
   and GB/s, compared against the chip's published peaks below.
 - **Dynamic trip counts**: XLA's HLO cost analysis counts a
   dynamic-trip-count ``fori_loop`` body ONCE (verified empirically: a

@@ -17,17 +17,13 @@ uint32 word in VMEM, and writes only the packed words — n^2/8 bytes of
 HBM traffic instead of ~9 n^2. The domination count comes from one
 popcount pass over the packed words.
 
-Measured on the v5e bench chip at n=20000, m=3 (fused-loop timing,
-interleaved rounds): naive broadcast build 11.3 ms; this kernel 6.3 ms;
-the lane-oriented XLA fallback 6.2 ms. The op is VPU-compute-bound
-(~2 n^2 m compares + pack logic ≈ 7 G vector ops), NOT HBM-bound, so
-once the lane layout is fixed XLA's own fusion already sits at the
-roofline and the kernel matches rather than beats it (tile size 256..2048
-changes nothing). The fallback is therefore the default everywhere; the
-kernel remains as the explicit `use_pallas=True` option, a tested
-template for ops where XLA's lowering is NOT already optimal. End-to-end
-the lane-layout fix alone took NSGA-II/LSMOP1 (pop=10000) from 57.6 to
-70.5 gens/sec.
+The op is VPU-compute-bound (~2 n^2 m compares + pack logic), NOT
+HBM-bound, so once the lane layout is fixed XLA's own fusion does the
+same vector work as the kernel. The lane-oriented XLA fallback is the
+default everywhere and is what the NSGA-II cell runs (PERF.md section 5:
+the build is the cell's largest share of device time); the kernel stays
+behind `use_pallas=True`, which no package code passes, and has not been
+timed at the cell's n=100000 (ROADMAP S3(ii), D4).
 """
 
 from __future__ import annotations
@@ -41,10 +37,9 @@ from jax.experimental import pallas as pl
 
 from ..utils.common import dominate_relation
 
-# Default tiles: 512 rows (16 words) x 2048 lanes — best of the sweep at
-# n=20000 (6.32 ms vs 6.90 for 256x512; every config within ~8%, the op is
-# compute-bound). VMEM per cell ~6 MB (dom + masks + words); 1024x4096
-# exceeds the 16 MB scoped-vmem limit.
+# Default tiles: 512 rows (16 words) x 2048 lanes (the op is
+# compute-bound, so the tile matters little). VMEM per cell ~6 MB (dom +
+# masks + words); 1024x4096 exceeds the 16 MB scoped-vmem limit.
 _TILE_I = 512
 _TILE_J = 2048
 
@@ -174,9 +169,8 @@ def packed_dominance(
     Args:
         fitness: ``(n, m)`` objective matrix.
         use_pallas: run the Pallas kernel instead of the XLA fallback.
-            Default False: measured on v5e the two are within noise (the
-            op is VPU-roofline-bound either way) and the fallback runs on
-            every backend.
+            Default False: the op is VPU-bound either way and the
+            fallback runs on every backend.
         interpret: run the kernel in interpreter mode (CPU testing).
     """
     if use_pallas:  # the fallback ignores tiling entirely

@@ -11,16 +11,16 @@ fitness write per environment, total.
 
 Scope: the MLP policy from ``flat_mlp_policy`` flat genomes and envs
 expressed in SoA form over component arrays. Built-ins: ``pendulum_soa``
-(the bench workload), ``cartpole_soa``, ``mountain_car_soa`` and
+(chip_smoke.py's pendulum phase), ``cartpole_soa``, ``mountain_car_soa`` and
 ``acrobot_soa`` — terminating envs run under a sticky in-kernel done
 mask with the standard engine's frozen-episode reward accounting, so
 fitness matches both ``early_exit`` modes of the generic engine (which
 remains the default; this kernel is the opt-in fast path, strongest on
-never-terminating or long-surviving episodes — PERF_NOTES §8).
+never-terminating or long-surviving episodes).
 
 CPU interpret-mode tests (tests/test_kernels.py) pin the kernel to the
-scan rollout's numerics; measured v5e numbers live in docs/PERF_NOTES.md
-§8. The wiring into :class:`PolicyRolloutProblem` (the ``fused_env=``
+scan rollout's numerics; no benchmark cell times it yet (PERF.md section
+7, row 6). The wiring into :class:`PolicyRolloutProblem` (the ``fused_env=``
 constructor parameter) lives in problems/neuroevolution/rollout.py.
 """
 
@@ -95,7 +95,7 @@ def pendulum_step_soa(s: SoAState, a: Tuple[jax.Array, ...]):
 
 
 def pendulum_soa(max_steps: int = 200) -> SoAEnv:
-    """The built-in :class:`SoAEnv` instance (bench workload 2's env)."""
+    """The built-in pendulum :class:`SoAEnv` instance."""
     from ..problems.neuroevolution.control.envs import pendulum
 
     return SoAEnv(
@@ -378,8 +378,7 @@ def fused_rollout(
         step_soa / obs_soa: the env's SoA step/observation functions (any
             jax-traceable elementwise math over the component arrays).
         tile: environments per Pallas grid cell; theta tile must fit VMEM
-            (tile x dim x 4 bytes, default 2048 x 81 ≈ 660 KB — the
-            measured v5e optimum, PERF_NOTES §8).
+            (tile x dim x 4 bytes, default 2048 x 81 ≈ 660 KB).
         episodes: episodes per individual. The grid is 2-D
             ``(n/tile, episodes)`` with episodes innermost: every episode
             row maps to the same genome block, and because consecutive
@@ -429,7 +428,7 @@ def fused_rollout(
     # tile: genome components are (rows, LANES) planes of a 3-D theta
     # block, env state components are matching 2-D tiles — all kernel ops
     # are full-width VPU instructions (1-D (tile,) values waste 7/8
-    # sublanes and measured ~5x slower)
+    # sublanes)
     rows_pop = n_pad // _LANES
     rows_tile = tile // _LANES
     blocks = rows_pop // rows_tile

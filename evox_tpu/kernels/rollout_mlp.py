@@ -5,7 +5,7 @@ The humanoid-scale workload (chain_walker: obs=244, act=17, 2-hidden MLP,
 dim≈21k) is HBM-bound on the standard scan engine: every env step re-reads
 every individual's ~84 KB of policy weights from HBM — ~4 bytes of weight
 traffic per 2 flops. The reference's engine shape (brax.py:62-97) has the
-same roofline; bench workload 2b measured ≈1.08x it.
+same roofline.
 
 This kernel flips the roofline: a tile of 128 individuals' FULL weight
 matrices (~10.8 MB f32) is loaded into VMEM once per episode and reused
@@ -280,15 +280,15 @@ def _mlp_planes(w_refs, b_refs, obs: jax.Array, sizes, linear=()) -> jax.Array:
 
     Weight planes may be bf16 (``fused_mlp_rollout(weight_dtype=...)``):
     each slice is widened to f32 at load and the accumulator stays f32.
-    Measured at walker scale this is throughput-NEUTRAL (the load-byte
-    saving is offset by the widening converts — PERF_NOTES §11); what
-    bf16 buys is a 2x per-tile policy budget and half the per-episode
-    HBM weight traffic.
+    The load-byte saving is paid for in widening converts; what bf16
+    buys is a 2x per-tile policy budget and half the per-episode HBM
+    weight traffic, and it fails the walker cell's ``correct`` (PERF.md
+    section 6, the table of limits).
 
     ``linear``: layer indices whose output skips the tanh — consecutive
     linear layers express a low-rank factorization (a rank-r input layer
-    is ``sizes=(obs, r, h, ...), linear=(0,)``), the PERF_NOTES §14
-    "fewer MACs" lever. Matches ``mlp_policy(linear_layers=...)``.
+    is ``sizes=(obs, r, h, ...), linear=(0,)``), the "fewer MACs"
+    lever. Matches ``mlp_policy(linear_layers=...)``.
 
     A layer's entry in ``w_refs`` / ``b_refs`` is its own block,
     ``(fan_in, fan_out, tile)`` / ``(fan_out, tile)``, or a pair ``(flat
@@ -472,8 +472,7 @@ def fused_rollout_analysis(
     double-buffered requirement, the ``vmem_limit_bytes`` the kernel
     will request, and the headroom between them. Negative headroom means
     the cap clipped the request — the compile will fail or thrash; shrink
-    ``tile`` or narrow ``weight_dtype`` (bf16 halves residency, the
-    knob PERF_NOTES §9 documents).
+    ``tile`` or narrow ``weight_dtype`` (bf16 halves residency).
 
     ``params``: one member's ``mlp_policy`` params tree (arrays or
     shapes). With it the report is of the flat-genome call a workflow

@@ -113,17 +113,15 @@ def non_dominated_sort(
     cumulative front sizes first reach ``until`` — the "worst admitted
     rank" of environmental selection. The peel loop knows it for free,
     which saves the O(n log n) ``jnp.sort(rank)`` pass selection would
-    otherwise spend deriving it (~5 ms at n=20000 on v5e).
+    otherwise spend deriving it.
 
     The dominance matrix is BIT-PACKED along the dominator axis: 32 rows
     per uint32 word, so each peel iteration is a fused
     ``popcount(front_word & dom_word)`` reduction reading n^2/8 bytes —
-    8x less HBM traffic than an int8 matvec. The peel loop is HBM-bound at
-    large n; measured on NSGA-II/LSMOP1 (merged n=20000, v5e chip, with
-    the old broadcast-compare build): packed 57.2 gens/sec vs int8 48.9
-    vs bf16 45.3. The build itself is VPU-bound and lane-layout-sensitive
-    — see kernels/dominance.py (the lane-oriented build lifted the same
-    workload to 70.5 gens/sec).
+    8x less HBM traffic than an int8 matvec. The build itself is
+    VPU-bound and lane-layout-sensitive — see kernels/dominance.py. What
+    each costs on the chip at merged n=100000: PERF.md section 5
+    (``tell_dominance_ms``, ``tell_peel_ms``).
 
     With ``mesh`` (holding a >1-sized ``axis_name`` axis) the O(n²)
     dominance build AND every peel pass are row-sharded across the mesh
@@ -359,8 +357,8 @@ def rank_crowding_truncate(
     ``mesh``: shard the O(n²) sort across its ``"pop"`` axis.
 
     The worst admitted rank comes from the peel loop's free cut-rank
-    by-product (PERF_NOTES §4) — a ``jnp.sort(rank)[k-1]`` here would
-    re-pay the ~5 ms O(n log n) pass that optimization removed.
+    by-product — a ``jnp.sort(rank)[k-1]`` here would re-pay the
+    O(n log n) pass that by-product removed.
 
     ``use_kernel`` (``None`` = backend default, currently off —
     kernels/topk.py): replace the O(n log n) full ``lexsort`` with the

@@ -8,8 +8,7 @@ modules; these helpers give the same ergonomics with zero dependencies: an
 TPU note: small layers deliberately avoid ``obs @ w`` — under the rollout's
 per-individual vmap that becomes a huge batch of tiny matmuls, which XLA:TPU
 pads onto the MXU at enormous cost. The broadcast-multiply-reduce form
-lowers to plain VPU elementwise work and measured 6.3x faster end-to-end
-(OpenES + pendulum, pop=65536, 2 episodes: 428k -> 2712k evals/sec on v5e).
+lowers to plain VPU elementwise work.
 Wide layers (where the matmul genuinely fills MXU tiles) keep ``@``; the
 per-layer choice is automatic (see ``mlp_policy``'s ``use_matmul``).
 Custom policies used with :class:`PolicyRolloutProblem` should follow suit.

@@ -157,7 +157,7 @@ class PolicyRolloutProblem(Problem):
             ``fused_env`` engine, which picks its own loop form: per-tile
             early-exit while_loop for terminating envs, fixed-horizon
             fori for never-terminating ones (``SoAEnv.terminating``;
-            same fitness either way — PERF_NOTES §8).
+            same fitness either way).
         unroll: scan unroll factor for the ``early_exit=False`` path.
         fused_env: an :class:`~evox_tpu.kernels.rollout.SoAEnv` — switches
             ``evaluate`` to the fused Pallas rollout kernel
@@ -176,8 +176,7 @@ class PolicyRolloutProblem(Problem):
             (pinned by tests/test_kernels.py). Built-ins:
             ``pendulum_soa``, ``cartpole_soa``, ``mountain_car_soa``,
             ``acrobot_soa`` (kernels/rollout.py).
-        fused_tile: environments per Pallas grid cell (multiple of 1024;
-            2048 measured best on v5e — PERF_NOTES §8).
+        fused_tile: environments per Pallas grid cell (multiple of 1024).
         fused_interpret: run the kernel in interpreter mode (None = auto:
             interpret on the CPU backend, compiled elsewhere).
         fused_planes: a :class:`~evox_tpu.kernels.rollout_mlp.PlaneEnv` —
@@ -189,9 +188,9 @@ class PolicyRolloutProblem(Problem):
             tree (pass the ``TreeAndVector`` adapter's ``batched_to_tree``
             as a workflow pop transform, as usual). For humanoid-scale
             policies where per-step weight re-reads dominate
-            (PERF_NOTES §9). Where that transform is the workflow's only
-            one, the workflow hands over the undecoded batch as well
-            (``evaluate_genome``) and the kernel reads each leaf whose
+            (the walker cell: PERF.md sections 4 and 5). Where that
+            transform is the workflow's only one, the workflow hands
+            over the undecoded batch as well (``evaluate_genome``) and the kernel reads each leaf whose
             first row in the genome and whose ``fan_out`` are multiples
             of the resident dtype's sublane packing (8 rows of float32,
             16 of bfloat16) in place, out of the flat ``(dim, n)``
@@ -209,7 +208,7 @@ class PolicyRolloutProblem(Problem):
         fused_planes_linear: layer indices with no tanh after them, matching
             the policy's ``mlp_policy(linear_layers=...)`` — expresses
             low-rank factorized layers in the big-policy kernel (the
-            PERF_NOTES §18 fewer-MACs lever).
+            fewer-MACs lever).
     """
 
     def __init__(

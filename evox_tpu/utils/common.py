@@ -151,8 +151,7 @@ def dominate_relation(x: jax.Array, y: jax.Array) -> jax.Array:
     Minimization convention (reference: utils/common.py:94-97). Formulated
     as a static loop over the (small) objective axis so every compare is an
     (n, n) pass with the population in the TPU lane dimension — the
-    broadcast-compare form puts m in the lanes and measures ~2x slower at
-    n=20000 on v5e.
+    broadcast-compare form puts m in the lanes and wastes most of them.
     """
     le = jnp.ones((x.shape[0], y.shape[0]), dtype=jnp.bool_)
     lt = jnp.zeros((x.shape[0], y.shape[0]), dtype=jnp.bool_)

@@ -6,7 +6,7 @@ executables on disk, but only under a path that stays put: the directory
 is part of what a later process must find again, so it is never built
 from ``tempfile``, a pid or the clock.
 
-Launchers (``chip_smoke.py``, ``bench.py``, the examples) call
+Launchers (``chip_smoke.py``, ``benchmark/run.py``, the examples) call
 :func:`enable_compile_cache` first thing.
 """
 

@@ -99,8 +99,8 @@ def fused_run(wf: Any, state: Any, n_steps: int) -> Any:
         return state
     # the peel is mandatory when the loop donates: without it a warm
     # caller state would be handed straight to the donated loop and the
-    # caller's arrays (bench re-timing loops, checkpointer snapshots,
-    # test fixtures) would be invalidated under it
+    # caller's arrays (re-timing loops, checkpointer snapshots, test
+    # fixtures) would be invalidated under it
     if state.first_step or getattr(wf, "donate_carries", False):
         with span(RUN_PEEL):
             state = wf.step(state)

@@ -367,7 +367,7 @@ class ControlPlane:
         metrics: one :class:`~evox_tpu.workflows.flightrec.
             FlightRecorder` (or a directory to build one) spanning the
             whole plane — its SLO ledger is the autoscaler's pressure
-            input and the bench leg's referee.
+            input.
         supervisor / executor: threaded into every pod's queues.
         max_ledger_segment_bytes: rotate the ledger into size-bounded
             segments (hash chain carried across; see journal.py).
@@ -1199,8 +1199,8 @@ class ControlPlane:
 
     def close(self) -> None:
         """Release the gateway's process-lifetime resources: the shared
-        executable cache's in-memory executables (PERF_NOTES §23 — the
-        durable cache state stays) and the executor's background lanes
+        executable cache's in-memory executables (the durable cache
+        state stays) and the executor's background lanes
         when one is threaded through."""
         self.cache.close()
         if self.executor is not None and hasattr(self.executor, "close"):

@@ -43,8 +43,8 @@ Record kinds (the stream's closed whitelist, :data:`STREAM_KINDS`):
 The SLO ledger is the ``slo.*`` counter namespace rendered as a
 first-class view (:meth:`FlightRecorder.slo_ledger`): tenant
 generations served (and their rate), EDF admissions, preemptions, and
-SLA deadline hits/misses — exactly the quantities ROADMAP item 4's
-"sustained tenant-gens/sec SLO bench" needs.
+SLA deadline hits/misses — the quantities a sustained tenant-gens/sec
+SLO is judged by.
 
 Everything here is host-side file I/O between dispatches — no host
 callbacks (pinned by tests/test_no_host_callbacks.py).

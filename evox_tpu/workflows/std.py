@@ -170,7 +170,7 @@ class StdWorkflow:
             loser rows differ by 1 ulp on the CPU backend) — so the
             default stays off to keep the fused run bit-identical to a
             ``step`` loop (the repo's equivalence laws), and donation is
-            the explicit perf knob the bench legs turn on.
+            an explicit knob no benchmark cell turns on (ROADMAP D4, D8).
     """
 
     def __init__(

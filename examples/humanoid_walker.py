@@ -5,8 +5,9 @@ The workload shape of the north-star benchmark (BASELINE.md; reference
 brax.py:45-97 is the engine it replaces): obs=244, act=17, a 2-hidden
 MLP of ~21k parameters per individual, contact physics, termination on
 falling. The fused kernel (kernels/rollout_mlp.py) keeps each tile of
-individuals' full weight matrices resident in VMEM for the whole episode
-— measured ~6x the standard scan engine on a v5e chip (PERF_NOTES §9).
+individuals' full weight matrices resident in VMEM for the whole episode.
+This is the benchmark's `walker_openes_pop65k` cell at another population;
+what the chip says of it is in PERF.md sections 4 and 5.
 
 Run (on the TPU, from the root of the checkout):
     PYTHONPATH=. python examples/humanoid_walker.py
@@ -36,9 +37,8 @@ def main() -> None:
     ap.add_argument("--episode-len", type=int, default=200)
     ap.add_argument(
         "--rank", type=int, default=0,
-        help="low-rank factorize the input layer (0 = dense): rank 16 "
-        "measured 1.51x throughput at matched equal-wall-clock reward, "
-        "and halves the genome (PERF_NOTES §18)",
+        help="low-rank factorize the input layer (0 = dense): fewer "
+        "MACs a step and a smaller genome",
     )
     args = ap.parse_args()
 

@@ -4,7 +4,7 @@ The real packages are not part of this build's baked environment; these
 fakes reproduce exactly the API surface our adapters consume so the
 adapter code paths (`control/brax_adapter.py::brax_env`,
 `hostenv.py::envpool_make`/`EnvPoolAdapter`) execute in CI instead of
-living behind import guards (VERDICT r3 task 4). The fake dynamics are
+living behind import guards. The fake dynamics are
 simple but real (a damped torque pendulum for brax, Gym CartPole-v1
 physics for envpool), so golden tests can pin adapter output against an
 EnvSpec/HostVectorEnv built directly on the same math.

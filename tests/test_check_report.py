@@ -1,7 +1,8 @@
-"""tools/check_report.py — the report-shape gate: run_report() and
-BENCH_*.json must stay valid against the schema validator, and the
-validator must actually catch the regressions it exists for (missing
-keys, non-strict JSON numbers)."""
+"""tools/check_report.py — the report-shape gate: run_report(), the
+metrics stream and the Chrome trace must stay valid against the schema
+validator, and the validator must actually catch the regressions it
+exists for (missing keys, non-strict JSON numbers, a report of another
+version than the one the program emits)."""
 
 import importlib.util
 import json
@@ -22,6 +23,13 @@ _spec = importlib.util.spec_from_file_location(
 )
 check_report = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(check_report)
+
+#: the stamp every hand-built report below carries: the one version the
+#: program emits and the validator takes
+_STAMP = {
+    "schema": check_report.RUN_REPORT_SCHEMA,
+    "schema_version": check_report.RUN_REPORT_SCHEMA_VERSION,
+}
 
 
 def _fresh_report(analyze):
@@ -93,92 +101,12 @@ def test_validator_sharding_subsection_rules():
     )
 
 
-def test_validator_large_pop_leg_rules():
-    """A 'large-pop' bench leg without its measured replicated-baseline
-    ratio (or ratio_rounds) is an asserted win — rejected; and a
-    large_pop summary whose instrumented report lacks the sharding
-    subsection is an unmeasured gather-free claim — rejected."""
-    summary = {
-        "metric": "geomean",
-        "value": 1.0,
-        "unit": "x",
-        "sub_metrics": [
-            {
-                "metric": "Sharded large-pop SepCMAES evals/sec",
-                "value": 1.0e6,
-                "unit": "evals/sec",
-                "vs_baseline": None,
-                "ratio_rounds": None,
-            }
-        ],
-    }
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "large-pop" in errors and "replicated-baseline" in errors
-    summary["sub_metrics"][0]["vs_baseline"] = 1.01
-    summary["sub_metrics"][0]["ratio_rounds"] = [1.0, 1.01]
-    assert check_report.validate_bench(summary) == []
-    summary["large_pop"] = {"run_report": _fresh_report(True)}
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "roofline.sharding missing" in errors
-
-
-def test_bench_jsons_validate():
-    """Every BENCH_*.json the driver has captured must either validate as
-    a bench summary or be a truncated capture (some historical envelopes
-    keep only a cut stdout tail — r01/r05 — which the validator reports
-    as 'no bench summary line', never as a shape violation)."""
-    paths = sorted(REPO.glob("BENCH_r*.json"))
-    assert paths, "no BENCH_*.json captures found"
-    validated = 0
-    for path in paths:
-        errors = check_report.validate_file(str(path))
-        if errors == []:
-            validated += 1
-        else:
-            assert len(errors) == 1 and "no bench summary line" in errors[0], (
-                path.name, errors,
-            )
-    assert validated > 0, "no capture had an intact summary to validate"
-
-
-def test_validate_bench_on_fresh_summary_shape():
-    """The exact dict bench.py main() prints (with the PR-4 roofline
-    fields) passes; a leg with a non-numeric ratio round fails."""
-    leg = {
-        "metric": "CSO/Ackley evals/sec",
-        "value": 1.0e6,
-        "unit": "evals/sec",
-        "vs_baseline": 1.2,
-        "ratio_rounds": [1.1, 1.2, 1.3],
-        "flops_per_eval": 19456,
-        "bytes_per_eval": 24576,
-        "achieved_gflops": 19.4,
-        "achieved_gbps": 24.5,
-        "frac_peak_compute": 9.4e-5,
-        "frac_peak_bandwidth": 4.0e-5,
-    }
-    summary = {
-        "metric": "geomean speedup over reference (CSO/Ackley)",
-        "value": 1.2,
-        "unit": "x",
-        "vs_baseline": 1.2,
-        "sub_metrics": [leg],
-        "run_report": _fresh_report(True),
-    }
-    assert check_report.validate_bench(summary) == []
-    bad = json.loads(json.dumps(summary))
-    bad["sub_metrics"][0]["ratio_rounds"] = ["high"]
-    assert any(
-        "ratio_rounds" in e for e in check_report.validate_bench(bad)
-    )
-
-
 def test_validator_cli_detects_jsonl(tmp_path):
     good = _fresh_report(False)
     p = tmp_path / "runs.jsonl"
     with open(p, "w") as f:
         f.write(json.dumps(good) + "\n")
-        f.write('{"schema": "evox_tpu.run_report/v1", "x": NaN}\n')
+        f.write('{"schema": "evox_tpu.run_report/v14", "x": NaN}\n')
     errors = check_report.validate_file(str(p))
     assert len(errors) == 1 and "runs.jsonl:2" in errors[0]
     assert check_report.main([str(p)]) == 1
@@ -344,56 +272,6 @@ def test_validator_multihost_subsection_rules():
         "multihost.process_count" in e
         for e in check_report.validate_run_report(bad3)
     )
-
-
-def test_validator_multihost_bench_rules():
-    """v8 bench rules: a multihost leg must carry its measured
-    vs_baseline + ratio_rounds; a multihost summary key needs the AOT
-    static-bytes referee, and a missing pod-side number needs the
-    provenance note (the large_pop note discipline); a pod peak at or
-    above the solo peak is a scaling claim that bought nothing."""
-    summary = {
-        "metric": "geomean",
-        "value": 1.0,
-        "unit": "x",
-        "sub_metrics": [
-            {
-                "metric": "Multihost sharded SepCMAES evals/sec (2x4 pod)",
-                "value": 1.0e5,
-                "unit": "evals/sec",
-                "vs_baseline": None,
-                "ratio_rounds": None,
-            }
-        ],
-    }
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "multihost" in errors and "solo-baseline" in errors
-    summary["sub_metrics"][0]["vs_baseline"] = 0.9
-    summary["sub_metrics"][0]["ratio_rounds"] = [0.89, 0.9]
-    assert check_report.validate_bench(summary) == []
-    # summary key: missing table rejected
-    summary["multihost"] = {"collectives_ran": False}
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "static_bytes missing" in errors
-    # measured pod side must beat the solo side
-    summary["multihost"] = {
-        "static_bytes": {
-            "solo_per_process_peak_bytes": 42_000_000,
-            "pod_per_process_peak_bytes": 43_000_000,
-        }
-    }
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "bought no per-process memory" in errors
-    # absent pod side needs the note/skip_reason
-    summary["multihost"] = {
-        "static_bytes": {"solo_per_process_peak_bytes": 42_000_000}
-    }
-    errors = "\n".join(check_report.validate_bench(summary))
-    assert "unmeasured" in errors
-    summary["multihost"]["skip_reason"] = (
-        "CPU backend cannot run multiprocess collectives on jaxlib 0.4.36"
-    )
-    assert check_report.validate_bench(summary) == []
 
 
 def _pod_section():
@@ -572,15 +450,12 @@ def test_validator_v10_surrogate_section_rules():
     """The v10 surrogate section: a coherent ledger passes; a broken
     counter sum, an over-full archive, out-of-order events, and unknown
     reason bits all fail loudly."""
-    good = {
-        "schema": "evox_tpu.run_report/v10",
-        "surrogate": _surrogate_section(),
-    }
+    good = {**_STAMP, "surrogate": _surrogate_section()}
     assert check_report.validate_run_report(good) == []
     # disabled sections stay minimal and valid
     assert check_report.validate_run_report(
         {
-            "schema": "evox_tpu.run_report/v10",
+            **_STAMP,
             "surrogate": {"enabled": False, "model": None, "screen_frac": 1.0},
         }
     ) == []
@@ -623,83 +498,13 @@ def test_validator_v10_surrogate_section_rules():
     assert "fallback" in errors
 
 
-def test_validator_v10_surrogate_bench_rules():
-    """Bench rules: the surrogate leg must carry vs_baseline +
-    ratio_rounds; the `surrogate` summary key needs a coherent eval
-    ledger hitting the 5x bar (note escape honored), anchored to an
-    instrumented run_report whose counters agree."""
-    leg = {
-        "leg": "surrogate",
-        "metric": "Surrogate-screened candidate throughput (...)",
-        "value": 3000.0,
-        "unit": "cand-evals/sec",
-        "vs_baseline": 6.5,
-        "ratio_rounds": [6.4, 6.5, 6.6],
-    }
-    rr = {
-        "schema": "evox_tpu.run_report/v10",
-        "surrogate": _surrogate_section(),
-    }
-    summary = {
-        "metric": "m",
-        "value": 1.0,
-        "unit": "x",
-        "sub_metrics": [leg],
-        "surrogate": {
-            "eval_ledger": {
-                "threshold": 1e-2,
-                "screened": {"true_evals": 128, "generations": 8, "best": 5e-3},
-                "full": {"true_evals": 768, "generations": 6, "best": 6e-3},
-                "ratio": 6.0,
-            },
-            "run_report": rr,
-        },
-    }
-    assert check_report.validate_bench(summary) == []
-
-    bad = json.loads(json.dumps(summary))
-    bad["sub_metrics"][0]["vs_baseline"] = None
-    bad["sub_metrics"][0]["ratio_rounds"] = None
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "full-evaluation baseline ratio" in errors
-    assert "ratio_rounds" in errors
-
-    bad = json.loads(json.dumps(summary))
-    bad["surrogate"]["eval_ledger"]["ratio"] = 3.0
-    bad["surrogate"]["eval_ledger"]["full"]["true_evals"] = 384
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "5x" in errors
-    bad["surrogate"]["note"] = "containerized capture: see protocol"
-    assert check_report.validate_bench(bad) == []
-
-    bad = json.loads(json.dumps(summary))
-    bad["surrogate"]["eval_ledger"]["ratio"] = 9.0
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "incoherent" in errors
-
-    bad = json.loads(json.dumps(summary))
-    bad["surrogate"]["eval_ledger"]["screened"]["best"] = 0.5
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "did not reach the threshold" in errors
-
-    bad = json.loads(json.dumps(summary))
-    bad["surrogate"]["run_report"]["surrogate"]["counters"]["true_evals"] = 99
-    bad["surrogate"]["run_report"]["surrogate"]["counters"]["screened_out"] = 413
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "disagree" in errors
-
-    bad = json.loads(json.dumps(summary))
-    del bad["surrogate"]["run_report"]
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "machine-validated" in errors
-
-
 # ------------------------------------------------ v11 metrics plane (PR 16)
 
 
 def test_validator_v11_schema_version_rules():
-    """v11 reports must carry a schema_version int that agrees with the
-    schema tag suffix; v10-and-earlier reports stay exempt."""
+    """A report carries the schema tag and the schema_version int of the
+    one version the program emits; any other version, in either place,
+    is refused."""
     report = _fresh_report(False)
     assert report["schema"] == "evox_tpu.run_report/v14"
     assert report["schema_version"] == 14
@@ -711,11 +516,26 @@ def test_validator_v11_schema_version_rules():
     bad["schema_version"] = 10
     errors = "\n".join(check_report.validate_run_report(bad))
     assert "disagrees" in errors
-    # pre-v11 shapes carry no schema_version and are not asked for one
-    old = {"schema": "evox_tpu.run_report/v10"}
-    assert not any(
-        "schema_version" in e for e in check_report.validate_run_report(old)
-    )
+    # a report of a version other than the one the program emits is
+    # refused, whether it is older, newer or agrees with itself
+    assert check_report.RUN_REPORT_SCHEMA == report["schema"]
+    for version in (1, 10, 13, 15):
+        other = json.loads(json.dumps(report))
+        other["schema"] = f"evox_tpu.run_report/v{version}"
+        other["schema_version"] = version
+        errors = "\n".join(check_report.validate_run_report(other))
+        assert "the version the program emits" in errors, version
+        assert "disagrees" in errors, version
+    # an old capture's shape (no int, no roofline provenance) gets no
+    # exemption either
+    old = {"schema": "evox_tpu.run_report/v1", "roofline": {
+        "ceilings": {"mxu_bf16_tflops": 1.0, "hbm_gbps": 1.0},
+        "entries": {"step": {"static": {}, "classification": None}},
+    }}
+    errors = "\n".join(check_report.validate_run_report(old))
+    assert "schema_version missing" in errors
+    assert "roofline.dtype_policy missing" in errors
+    assert "roofline.donation missing" in errors
 
 
 def _metrics_report():
@@ -861,8 +681,11 @@ def test_validate_file_sniffs_metrics_stream(tmp_path):
 def test_schema_flag_lists_and_detects(tmp_path, capsys):
     assert check_report.main(["--schema"]) == 0
     out = capsys.readouterr().out
-    assert "evox_tpu.run_report/v14" in out
-    assert "evox_tpu.metrics_stream/v1" in out
+    assert out.splitlines() == [
+        "evox_tpu.run_report/v14",
+        "evox_tpu.metrics_stream/v1",
+        "chrome trace (traceEvents)",
+    ]
     from evox_tpu import FlightRecorder
 
     fr = FlightRecorder(directory=str(tmp_path))
@@ -914,11 +737,7 @@ def _control_plane_section():
 
 
 def test_validator_v12_control_plane_rules():
-    report = {
-        "schema": "evox_tpu.run_report/v12",
-        "schema_version": 12,
-        "control_plane": _control_plane_section(),
-    }
+    report = {**_STAMP, "control_plane": _control_plane_section()}
     assert check_report.validate_run_report(report) == []
 
     # ANY duplicate admission is a violated law, not a warning
@@ -965,64 +784,6 @@ def test_validator_v12_control_plane_rules():
     bad["control_plane"]["steals"] = []
     errors = "\n".join(check_report.validate_run_report(bad))
     assert "tenants.stolen" in errors
-
-
-def test_validator_v12_control_plane_bench_rules():
-    leg = {
-        "metric": "control-plane churn sustained rate",
-        "value": 2.0,
-        "unit": "tenant-gens/s",
-        "vs_baseline": 1.7,
-        "ratio_rounds": [1.6, 1.8],
-    }
-    summary = {
-        "metric": "geomean",
-        "value": 1.0,
-        "unit": "x",
-        "sub_metrics": [leg],
-        "control_plane": {
-            "report": {
-                "schema": "ignored",
-                **_control_plane_section(),
-                "slo": {
-                    "tenant_gens": 18,
-                    "elapsed_s": 2.0,
-                    "tenant_gens_per_s": 9.0,
-                    "admissions": 3,
-                    "preemptions": 0,
-                    "deadline_hits": 0,
-                    "deadline_misses": 0,
-                },
-            },
-            "tenant_gens_per_s": 2.0,
-        },
-    }
-    assert check_report.validate_bench(summary) == []
-
-    # the timed win must be measured, not asserted
-    bad = json.loads(json.dumps(summary))
-    bad["sub_metrics"][0]["vs_baseline"] = None
-    bad["sub_metrics"][0]["ratio_rounds"] = None
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "control-plane leg is missing" in errors
-    assert "no ratio_rounds" in errors
-
-    # the static referee must exist and must show the fault path ran
-    bad = json.loads(json.dumps(summary))
-    del bad["control_plane"]["report"]
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "static referee" in errors
-    bad = json.loads(json.dumps(summary))
-    bad["control_plane"]["report"]["pods"]["dead"] = []
-    bad["control_plane"]["report"]["pods"]["opened"] = 1
-    bad["control_plane"]["report"]["events"]["pod_dead"] = 0
-    bad["control_plane"]["report"]["events"]["pod_open"] = 1
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "no dead pod" in errors
-    bad = json.loads(json.dumps(summary))
-    del bad["control_plane"]["report"]["slo"]
-    errors = "\n".join(check_report.validate_bench(bad))
-    assert "SLO ledger is the leg's referee" in errors
 
 
 # ---------------------------------------------------------------- v13
@@ -1129,48 +890,3 @@ def test_validator_v13_search_section_rules():
     assert "front_size out of" in errors
 
 
-def test_validator_bench_trajectory_rules(tmp_path):
-    """The cross-PR BENCH_TRAJECTORY.json (ISSUE 19 satellite): the repo
-    artifact validates, the file dispatch recognises the schema, and the
-    rules catch unknown rounds / bad flags / schema drift."""
-    repo_file = REPO / "BENCH_TRAJECTORY.json"
-    assert repo_file.exists(), (
-        "BENCH_TRAJECTORY.json missing — regenerate with "
-        "python tools/bench_trajectory.py"
-    )
-    traj = json.loads(repo_file.read_text())
-    assert check_report.validate_bench_trajectory(traj) == []
-    assert check_report.validate_file(str(repo_file)) == []
-    assert (
-        check_report.detect_schema(str(repo_file))
-        == "evox_tpu.bench_trajectory/v1"
-    )
-    assert any(
-        "bench_trajectory" in s for s in check_report.SUPPORTED_SCHEMAS
-    )
-
-    bad = json.loads(json.dumps(traj))
-    bad["schema"] = "evox_tpu.bench_trajectory/v99"
-    assert any(
-        "schema" in e for e in check_report.validate_bench_trajectory(bad)
-    )
-    bad = json.loads(json.dumps(traj))
-    key = next(iter(bad["legs"]))
-    bad["legs"][key]["history"][0]["round"] = 99999
-    assert any(
-        "not among rounds" in e
-        for e in check_report.validate_bench_trajectory(bad)
-    )
-    bad = json.loads(json.dumps(traj))
-    bad["legs"][key]["flags"] = {"ratio_regression": "yes"}
-    assert any(
-        "flags" in e for e in check_report.validate_bench_trajectory(bad)
-    )
-    # a tail-recovered round must explain itself
-    bad = json.loads(json.dumps(traj))
-    for rnd in bad["rounds"]:
-        rnd["notes"] = []
-    assert any(
-        "provenance note" in e
-        for e in check_report.validate_bench_trajectory(bad)
-    )

@@ -376,7 +376,7 @@ def test_serialized_executable_fresh_process_bitwise(tmp_path):
 def _cache_clean_exit_child(cache_dir):
     """Spawned child: deserialize the fleet's executables from disk,
     run, then exit NORMALLY — no ``os._exit`` escape hatch. The cache's
-    atexit guard (core/exec_cache.py, PERF_NOTES §23) must drop the
+    atexit guard (core/exec_cache.py) must drop the
     deserialized references before jax's ``clear_backends`` runs, or
     this child segfaults instead of returning 0."""
     import sys
@@ -392,7 +392,7 @@ def _cache_clean_exit_child(cache_dir):
 
 
 def test_deserialized_executables_clean_interpreter_exit(tmp_path):
-    """PERF_NOTES §23 regression (PR 18): a fresh process whose
+    """PR 18 regression: a fresh process whose
     executables all came from the disk store exits 0 through normal
     interpreter teardown — the atexit teardown guard, not ``os._exit``,
     keeps the deserialized refs from outliving the backend."""

@@ -198,8 +198,8 @@ def test_fused_planes_multichip_shard_map():
 @pytest.mark.slow
 def test_fused_planes_low_rank_linear_matches_scan():
     """A rank-r factorized input layer (linear_layers=(0,)) runs through
-    the fused kernel bit-compatibly with the scan engine — the PERF_NOTES
-    §18 fewer-MACs structured policy."""
+    the fused kernel bit-compatibly with the scan engine — the
+    fewer-MACs structured policy."""
     penv = chain_walker_planes(max_steps=20)
     init_params, apply = mlp_policy((244, 8, 16, 17), linear_layers=(0,))
     adapter = TreeAndVector(init_params(jax.random.PRNGKey(0)))
@@ -265,8 +265,8 @@ def test_fused_mlp_bf16_residency_close_to_f32():
 @pytest.mark.slow
 def test_bf16_rollouts_train_walker():
     """Convergence with bf16-resident policies: OpenES on a small walker
-    still improves the center policy's episode return (VERDICT r4 task 2
-    done-criterion — reduced precision must not break training)."""
+    still improves the center policy's episode return (reduced
+    precision must not break training)."""
     from evox_tpu import StdWorkflow
     from evox_tpu.algorithms.so.es import OpenES
     from evox_tpu.utils import rank_based_fitness
@@ -305,7 +305,7 @@ def test_bf16_rollouts_train_walker():
 
 
 def test_fused_mlp_rejects_out_of_range_linear():
-    """ADVICE round-5 regression: an out-of-range `linear` index used to
+    """Regression: an out-of-range `linear` index used to
     be silently ignored (the user would train a different architecture
     than requested); fused_mlp_rollout now mirrors
     mlp_policy(linear_layers=...)'s range check."""

@@ -319,7 +319,7 @@ def test_sharded_rmes_rosenbrock_pop1e6():
     the large-pop win here is STABLE progress, not a 10^6-fold speedup —
     RMES (rank-based PSR step sizes, bounded by construction) with the
     `mu` parent cap (strong truncation keeps mueff = O(10^3), the regime
-    the CSA-family constants were derived for; PERF_NOTES §22).
+    the CSA-family constants were derived for).
     Calibrated in-container: THIS config (key 1) measures f=0.436 at
     gen 40 (~11 s/gen on the 1-core 8-device mesh — hence 40 gens, not
     more); the same config at pop=1e5 reaches 0.039 by gen 80 and 2e-10

@@ -1,4 +1,4 @@
-"""LES standing on unseen non-quadratic tasks (VERDICT r4 task 8).
+"""LES standing on unseen non-quadratic tasks.
 
 The published evosax LES params are unobtainable offline (the reference
 loads `2023_03_les_v1.pkl` via pkgutil.get_data — reference
@@ -8,7 +8,7 @@ them. This test pins where that artifact stands OUTSIDE its training
 distribution: official CEC2022 members at d=10 (shifted/rotated Zakharov
 and Levy, and the F6 hybrid — none of these families appear in
 les_meta.py's training draw), against OpenES and CMA-ES at an equal
-evaluation budget. The measured table lives in docs/PERF_NOTES.md §16.
+evaluation budget. The test prints each member's scores.
 
 Standing provenance (PR-5 triage of the since-seed failure): this test
 failed from seed in this container for the same ROOT CAUSE class PR 4
@@ -85,8 +85,8 @@ def test_les_cec2022_standing():
     member (strictly on the multimodal F5/F6; within the plateau noise
     margin on F1 — see module docstring), and (b) beat the random-params
     LES the same way per member and strictly in aggregate. CMA-ES is
-    reported, not asserted: it wins the multimodal members at this budget
-    (measured standings in PERF_NOTES §17) — a standing the published
+    not asserted: it wins the multimodal members at this budget
+    — a standing the published
     evosax params share on small-budget multimodal suites, per the LES
     paper's own ablations."""
     params = load_params()
@@ -117,8 +117,8 @@ def test_les_cec2022_standing():
                     True,
                 )
             ),
-            # CMA-ES is reported in PERF_NOTES §17, never asserted —
-            # re-running it here spent ~25% of the test for zero checks
+            # CMA-ES is never asserted — running it here spent ~25% of
+            # the test for zero checks
         }
         print(
             f"{fcls.__name__}: "

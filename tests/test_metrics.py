@@ -91,7 +91,7 @@ def test_hv_class_dispatches_exact_for_2d():
 
 
 def test_hypervolume_3d_golden_values():
-    """Exact 3-D HV against analytic cases (VERDICT r3 task 10)."""
+    """Exact 3-D HV against analytic cases."""
     from evox_tpu.metrics import hypervolume_3d
 
     ref = jnp.array([1.0, 1.0, 1.0])

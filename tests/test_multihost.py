@@ -232,14 +232,20 @@ def test_process_barrier_is_noop_single_process():
     dist.process_barrier()  # must not raise and not block
 
 
-def test_multihost_roofline_subsection_attaches(monkeypatch):
+def test_multihost_roofline_subsection_attaches(monkeypatch, ceilings):
     """core/instrument.py v8: on a multi-process run (monkeypatched —
     the CPU backend here is single-process) an analyzed workflow's
     report carries roofline.multihost with coherent per-process bytes
     and a positive collective estimate, and the section validates."""
     import importlib
 
-    from evox_tpu import ShardedES, StdWorkflow, instrument, run_report
+    from evox_tpu import (
+        CostAnalyzer,
+        ShardedES,
+        StdWorkflow,
+        instrument,
+        run_report,
+    )
     from evox_tpu.algorithms.so.es import SepCMAES
     from evox_tpu.problems.numerical import Sphere
 
@@ -247,24 +253,32 @@ def test_multihost_roofline_subsection_attaches(monkeypatch):
     instr = importlib.import_module("evox_tpu.core.instrument")
 
     mesh = dist.create_pod_mesh()
+    # pop 1,024: the validator's gather-free law (per-device peak under
+    # the whole population's bytes) needs a population that outweighs the
+    # program's fixed few kilobytes; at pop 64 (4,096 B) it did not
+    pop = 1024
     algo = ShardedES(
-        SepCMAES(center_init=jnp.zeros(16), init_stdev=1.0, pop_size=64),
+        SepCMAES(center_init=jnp.zeros(16), init_stdev=1.0, pop_size=pop),
         mesh=mesh,
     )
     wf = StdWorkflow(algo, Sphere(), mesh=mesh)
-    rec = instrument(wf, analyze=True)
+    # the CPU has no entry in CHIP_CEILINGS (no default peak exists):
+    # the analyzer is handed stand-in peaks (conftest's fixture)
+    rec = instrument(wf)
     st = wf.init(jax.random.PRNGKey(0))
     st = wf.run(st, 2)
     monkeypatch.setattr(instr.jax, "process_count", lambda: 2)
     monkeypatch.setattr(instr.jax, "local_device_count", lambda: 4)
-    report = run_report(wf, st, recorder=rec)
+    report = run_report(
+        wf, st, recorder=rec, analyzer=CostAnalyzer(ceilings=ceilings)
+    )
     mh = report["roofline"]["multihost"]
     assert mh["process_count"] == 2 and mh["n_local_devices"] == 4
     assert (
         mh["per_process_peak_bytes"] == mh["per_device_peak_bytes"] * 4
     )
     # base model 2*pop*4 plus the psum'd moment tree (zw+zzw: 2*dim)
-    assert mh["collective_bytes_estimate"] >= 2 * 64 * 4
+    assert mh["collective_bytes_estimate"] >= 2 * pop * 4
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(

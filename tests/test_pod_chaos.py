@@ -44,7 +44,7 @@ pytestmark = pytest.mark.pod_chaos
 
 # deadline 5 s: must undercut the coordination client's own ~10 s
 # missed-heartbeat SIGABRT so the classified path wins the race
-# (PodManager.run_scenario docstring + PERF_NOTES §25)
+# (PodManager.run_scenario docstring)
 _OPTS = {"deadline_s": 5.0, "chunk": 2, "total": 8, "kill_gen": 4}
 
 

@@ -563,7 +563,7 @@ def test_journalled_pod_events_verify(tmp_path):
 
 
 def test_deadline_clamped_against_coord_abort(monkeypatch):
-    """PERF_NOTES §25 (PR 18): in a REAL multi-process pod a supervisor
+    """PR 18: in a REAL multi-process pod a supervisor
     deadline that cannot beat jaxlib's ~10 s coordination-heartbeat
     abort is clamped at construction with a warning — pod faults must be
     classified, not die by SIGABRT."""

@@ -1,4 +1,4 @@
-"""Multi-process rollout farm (VERDICT r3 task 6): a 2-worker-PROCESS
+"""Multi-process rollout farm: a 2-worker-PROCESS
 farm must reproduce the single-process farm's fitness exactly, and drive
 through the workflow + run_host_pipelined like any host problem."""
 

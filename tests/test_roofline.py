@@ -437,6 +437,6 @@ def test_fused_rollout_vmem_headroom():
     assert report["vmem_limit_bytes"] == limit
     assert report["headroom_bytes"] > 0
     assert report["vmem_limit_bytes"] <= report["vmem_cap_bytes"]
-    # bf16 residency halves (PERF_NOTES §9's bandwidth/budget knob)
+    # bf16 residency halves (the per-tile budget knob)
     bf16 = fused_rollout_analysis(ws, bs, weight_dtype=jnp.bfloat16)
     assert bf16["resident_bytes_per_cell"] * 2 == per_cell

@@ -264,7 +264,7 @@ def test_les_meta_trained_beats_random_and_openes():
 
 
 def test_les_meta_transfers_to_unseen_families():
-    """VERDICT r3 task 8: the bundled meta-trained LES must beat OpenES at
+    """The bundled meta-trained LES must beat OpenES at
     an equal budget on >=2 families NEVER seen in meta-training (training
     draws sphere/ellipsoid/rastrigin/rosenbrock/MLP-loss; held-out here:
     Ackley and Griewank), at a transfer dimension (12 vs training 8)."""

@@ -22,12 +22,9 @@ The laws, in the repo's acceptance order:
 - checkpoint/resume mid-refit equivalence, quarantine composition, the
   supervisor retry ladder, and the host-rows == ledger law;
 - run_report v10 ``surrogate`` section validated by tools/check_report,
-  telemetry mirror counters, executor ``bg_refit`` accounting;
-- bench.py ``--legs`` rejects unknown leg names loudly (regression for
-  the ISSUE 15 satellite) and advertises the new ``surrogate`` leg.
+  telemetry mirror counters, executor ``bg_refit`` accounting.
 """
 
-import importlib.util
 import pathlib
 import sys
 import tempfile
@@ -703,34 +700,3 @@ def test_surrogate_state_is_checkpoint_stable():
     s0 = wf.init(jax.random.PRNGKey(0))
     s5 = wf.run(s0, 5)
     assert state_config_fingerprint(s0) == state_config_fingerprint(s5)
-
-
-# -------------------------------------------------------- bench.py driver
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location("bench", REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_legs_unknown_name_fails_fast(capsys):
-    """ISSUE 15 satellite regression: a typo'd --legs name must fail
-    LOUDLY listing every known leg, never silently skip (a skipped leg
-    would carry last round's stale ratio forward)."""
-    bench = _load_bench()
-    with pytest.raises(SystemExit) as exc:
-        bench._parse_legs(["--legs", "no_such_leg"])
-    assert exc.value.code == 2
-    err = capsys.readouterr().err
-    assert "no_such_leg" in err
-    for name in bench.LEG_NAMES:
-        assert name in err  # the known names are listed for the operator
-
-
-def test_bench_advertises_surrogate_leg():
-    bench = _load_bench()
-    assert "surrogate" in bench.LEG_NAMES
-    # self-baselined: excluded from the reference geomean
-    assert any("surrogate" in m.lower() for m in bench.NON_REFERENCE_LEGS)

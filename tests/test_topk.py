@@ -1,7 +1,7 @@
 """Pallas partial-top-k kernel (kernels/topk.py) — interpret-mode parity
 on the CPU CI mesh (per CLAUDE.md, interpret-mode passing is NOT
-real-chip compile evidence; the mandatory TPU compile check is tracked
-in docs/PERF_NOTES.md §"round 6") plus the wired selection sites:
+real-chip compile evidence; the compile for a described v5e is a case
+of tests/test_chip_compile.py) plus the wired selection sites:
 truncation selection, pbest sampling, island migration elites, and the
 NSGA-II last-front truncation."""
 
@@ -148,8 +148,14 @@ def test_rank_crowding_truncate_kernel_set_identical():
 
 def test_nsga2_kernel_mode_converges_zdt1():
     """Convergence-threshold gate (CLAUDE.md) for the selection-law-
-    equivalent kernel truncation: NSGA-II with use_kernel on matches the
-    f32 suite's ZDT1 IGD bar."""
+    equivalent kernel truncation: NSGA-II with use_kernel on reaches the
+    suite's ZDT1 IGD bar at 150 generations (tests/test_mo_algorithms.py).
+
+    At generation 100 the search is still on the steep part of its
+    descent, where the IGD swings with the seed on EITHER truncation
+    path (0.03 to 0.11 over three seeds, the lexsort path 0.092 where
+    this one read 0.108 on seed 3), so a bar of 0.1 there tested the
+    build's PRNG. By generation 150 both paths read 0.008 to 0.015."""
     from evox_tpu import StdWorkflow
     from evox_tpu.algorithms.mo import NSGA2
     from evox_tpu.metrics import igd
@@ -166,11 +172,11 @@ def test_nsga2_kernel_mode_converges_zdt1():
     )
     wf = StdWorkflow(algo, ZDT1(n_dim=d))
     state = wf.init(jax.random.PRNGKey(3))
-    state = wf.run(state, 100)
+    state = wf.run(state, 150)
     fit = state.algo.fitness
     finite = jnp.isfinite(fit).all(axis=1)
     fit = jnp.where(finite[:, None], fit, 1e6)
-    assert float(igd(fit, ZDT1(n_dim=d).pf())) < 0.1
+    assert float(igd(fit, ZDT1(n_dim=d).pf())) < 0.05
 
 
 def test_islands_topk_kernel_migration_matches_argsort():

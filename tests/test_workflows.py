@@ -155,7 +155,7 @@ def test_device_history_variable_batch_width():
 
 def test_shard_map_eval_island_matches_gspmd():
     """Explicit shard_map + all_gather evaluation == GSPMD-constraint path
-    == single device (VERDICT: exercise the all_gather collective)."""
+    == single device (exercises the all_gather collective)."""
     assert jax.device_count() >= 8
     mesh = create_mesh()
     key = jax.random.PRNGKey(11)
@@ -236,7 +236,7 @@ def test_sharded_mo_selection_matches_single_device():
     """NSGA-II/LSMOP1 with BOTH evaluation and the O(n²) environmental
     selection sharded over the 8-device mesh (algorithms/mo/common.py mesh
     arg -> operators/selection/non_dominate.py sharded sort) must match the
-    single-device run to <=1e-5 (VERDICT r3 task 1 done-criterion; exact
+    single-device run to <=1e-5 (exact
     equality expected since ranks are integer-identical)."""
     from evox_tpu.algorithms.mo import NSGA2
     from evox_tpu.problems.numerical import LSMOP1
@@ -261,8 +261,8 @@ def test_sharded_mo_selection_matches_single_device():
 
 @pytest.mark.slow
 def test_sharded_selection_at_chunked_build_size():
-    """Chunked-build x row-sharded interaction at engagement size
-    (VERDICT r4 task 4): above merged n=20000 the REPLICATED path switches
+    """Chunked-build x row-sharded interaction at engagement size:
+    above merged n=20000 the REPLICATED path switches
     to the lax.map slab build (kernels/dominance.py::_DENSE_BUILD_MAX_N)
     while the SHARDED path builds per-device dominator slabs — the two
     formulations must still produce bit-identical truncations. n=20032
@@ -350,7 +350,7 @@ def test_shard_map_rejects_half_pop_algorithms():
 
 
 def test_eval_monitor_mo_archive_workflow_level():
-    """VERDICT weak #6: the MO Pareto-archive path exercised through the
+    """The MO Pareto-archive path exercised through the
     full workflow (run() fusion), with jit-safe padded getters."""
     from evox_tpu.algorithms.mo import NSGA2
     from evox_tpu.problems.numerical import ZDT1

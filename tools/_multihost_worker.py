@@ -256,6 +256,18 @@ def _pso(jnp):
     return PSO(lb=-5.0 * jnp.ones(4), ub=5.0 * jnp.ones(4), pop_size=8)
 
 
+def _init_shapes(wf, key):
+    """What ``jax.eval_shape(wf.init, key)`` gives, for a mesh that may
+    span processes: there ``init`` assembles global arrays eagerly
+    (``ensure_global_state``), which a trace cannot do, so ``init`` runs
+    and its result is described."""
+    import jax
+
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), wf.init(key)
+    )
+
+
 def _law_workflow(mesh, n_shards, pop=32, dim=16):
     """The law workload: POP-sharded ShardedES(SepCMAES) on Sphere —
     per-shard fold_in sampling + psum-of-moments recombination, the PR-10
@@ -328,7 +340,7 @@ def _collective_laws(spec, result, dist, mesh, nprocs, n_local, workdir):
         # pod leg: resume the 1-process gen-K snapshot on THIS process
         # layout and reproduce the solo trajectory's remaining stretch
         ckpt = WorkflowCheckpointer(ckpt_dir, every=gens_snapshot, keep=10)
-        expect = jax.eval_shape(wf.init, jax.random.PRNGKey(0))
+        expect = _init_shapes(wf, jax.random.PRNGKey(0))
         snap = ckpt.load(gens_snapshot, expect_like=expect)
         assert snap is not None, "1-process snapshot missing"
         st = restore_layouts(snap, mesh=mesh)
@@ -385,7 +397,7 @@ def _collective_laws(spec, result, dist, mesh, nprocs, n_local, workdir):
         from evox_tpu.core.xla_cost import analyze_callable
 
         mwf = _law_workflow(mesh, n_total, pop=mem_pop, dim=mem_dim)
-        sds = jax.eval_shape(mwf.init, jax.random.PRNGKey(0))
+        sds = _init_shapes(mwf, jax.random.PRNGKey(0))
         sds = sds.replace(first_step=False)
         mem = analyze_callable(mwf._step, sds).get("memory") or {}
         peak = mem.get("peak_bytes_estimate")
@@ -403,31 +415,6 @@ def _collective_laws(spec, result, dist, mesh, nprocs, n_local, workdir):
             }
     except Exception as e:  # the table must never sink the laws
         result["memory"] = {"error": f"{type(e).__name__}: {e}"}
-
-    # optional bench leg: differenced fused-run slope at the bench shape
-    pair = spec.get("bench_pair")
-    if pair:
-        import time
-
-        bpop, bdim = spec.get("bench_shape", (4096, 32))
-        bwf = _law_workflow(mesh, n_total, pop=bpop, dim=bdim)
-        bst = bwf.init(jax.random.PRNGKey(21))
-        bst = bwf.run(bst, pair[0])  # compile + warm
-
-        def timed(n):
-            nonlocal bst
-            t0 = time.perf_counter()
-            bst = bwf.run(bst, n)
-            float(dist.host_value(bst.algo.sigma))  # small-leaf fetch
-            return time.perf_counter() - t0
-
-        t1, t2 = timed(pair[0]), timed(pair[1])
-        result["bench"] = {
-            "pair": list(pair),
-            "slope_s_per_gen": (t2 - t1) / (pair[1] - pair[0]),
-            "pop": bpop,
-            "dim": bdim,
-        }
 
 
 def _metrics_tier(spec, result, dist, nprocs, n_local, workdir):
@@ -983,7 +970,7 @@ class PodManager:
         classified deadline → census → post-mortem path has to win that
         race, or a coordinator-loss scenario dies silently with rc -6
         instead of exiting 23 with a diagnosis (observed at 8 s;
-        PERF_NOTES §25 records the budget)."""
+        ``PodSupervisor`` clamps a deadline that cannot win it)."""
         if scenario not in self.SCENARIOS:
             raise ValueError(
                 f"unknown scenario {scenario!r}; expected one of "
@@ -1127,8 +1114,7 @@ class PodManager:
             # moment its coordinator connection dies) can win the race
             # with the classified path — a prompt, logged termination,
             # observed nondeterministically on the same box; the pod
-            # layer's job is the re-formation either way
-            # (PERF_NOTES §25 records the race budget).
+            # layer's job is the re-formation either way.
             coordinator_dead = victim == 0
             expected = self.EXPECTED_CLASS[scenario]
             detections, jaxlib_fatals = [], []

@@ -1,8 +1,8 @@
-"""Schema validator for evox_tpu run reports and BENCH summary JSON.
+"""Schema validator for evox_tpu run reports, metrics streams and traces.
 
-``run_report()`` (core/instrument.py) and bench.py's summary line are the
-two structured-JSON surfaces downstream tooling consumes (dashboards,
-the driver's BENCH_*.json diffs, jq pipelines). This validator pins their
+``run_report()`` (core/instrument.py), the serving metrics stream
+(workflows/flightrec.py) and ``write_chrome_trace()`` are the
+structured-JSON surfaces the program writes. This validator pins their
 shape so a refactor that silently drops a key or leaks a bare
 ``NaN``/``Infinity`` token (rejected by strict JSON parsers) fails a fast
 tier-1 test (tests/test_check_report.py) instead of a downstream
@@ -10,12 +10,17 @@ pipeline.
 
 Usage::
 
-    python tools/check_report.py BENCH_r05.json runs.jsonl ...
+    python tools/check_report.py runs.jsonl trace.json ...
 
-``.jsonl`` files are validated line by line as run reports; ``.json``
-files are sniffed: a top-level ``sub_metrics`` key means a bench summary,
-a ``schema`` key a run report, a ``traceEvents`` key a Chrome trace.
-Exit status 0 = every file valid, 1 = violations (printed one per line).
+``.jsonl`` files are validated line by line as run reports, or as one
+metrics stream when the first record carries that schema tag; ``.json``
+files are sniffed: a ``traceEvents`` key means a Chrome trace, anything
+else a run report. Exit status 0 = every file valid, 1 = violations
+(printed one per line).
+
+One version of the run report is valid: the one the program emits
+(``RUN_REPORT_SCHEMA_VERSION``; ``core/instrument.py`` stamps the same
+integer). A report stamped with another version is a violation.
 
 The finiteness rule is exactly ``core.instrument.sanitize_json``'s: a
 value the sanitizer would rewrite (non-finite float) is a violation —
@@ -30,6 +35,10 @@ import sys
 from typing import Any, Iterator, List, Tuple
 
 RUN_REPORT_SCHEMA_PREFIX = "evox_tpu.run_report/"
+#: the one version ``run_report`` emits (core/instrument.py) and this
+#: validator takes
+RUN_REPORT_SCHEMA_VERSION = 14
+RUN_REPORT_SCHEMA = f"{RUN_REPORT_SCHEMA_PREFIX}v{RUN_REPORT_SCHEMA_VERSION}"
 # v11 (PR 16, workflows/flightrec.py): the serving metrics stream is a
 # third .jsonl surface — sniffed by its per-record schema tag
 METRICS_STREAM_SCHEMA_PREFIX = "evox_tpu.metrics_stream/"
@@ -127,31 +136,23 @@ def validate_run_report(report: Any, where: str = "run_report") -> List[str]:
     if not isinstance(report, dict):
         return [f"{where}: not a JSON object"]
     schema = report.get("schema")
-    schema_version = 1
-    if not isinstance(schema, str) or not schema.startswith(
-        RUN_REPORT_SCHEMA_PREFIX
-    ):
+    if schema != RUN_REPORT_SCHEMA:
         errors.append(
             f"{where}: missing/unknown schema key (want "
-            f"'{RUN_REPORT_SCHEMA_PREFIX}*', got {schema!r})"
+            f"{RUN_REPORT_SCHEMA!r}, the version the program emits; got "
+            f"{schema!r})"
         )
-    else:
-        try:
-            schema_version = int(schema.rsplit("/v", 1)[1])
-        except (IndexError, ValueError):
-            schema_version = 1
-    # v11+: the version also rides as a grep-able top-level int, and the
-    # two must agree — a report that says v12 in one place and v11 in the
-    # other is lying to somebody
-    if schema_version >= 11:
-        sv = report.get("schema_version")
-        if not isinstance(sv, int):
-            errors.append(f"{where}: schema_version missing or not an int")
-        elif sv != schema_version:
-            errors.append(
-                f"{where}: schema_version {sv} disagrees with schema "
-                f"{schema!r}"
-            )
+    # the version also rides as a grep-able top-level int, and the two
+    # must agree — a report that says one version in the tag and another
+    # in the int is lying to somebody
+    sv = report.get("schema_version")
+    if not isinstance(sv, int) or isinstance(sv, bool):
+        errors.append(f"{where}: schema_version missing or not an int")
+    elif sv != RUN_REPORT_SCHEMA_VERSION:
+        errors.append(
+            f"{where}: schema_version {sv} disagrees with schema "
+            f"{RUN_REPORT_SCHEMA!r}"
+        )
     errors += [f"{where}: non-finite number at {p}" for p in find_nonfinite(report)]
     for i, mon in enumerate(report.get("telemetry", []) or []):
         if not isinstance(mon, dict) or "monitor" not in mon:
@@ -338,15 +339,11 @@ def validate_run_report(report: Any, where: str = "run_report") -> List[str]:
                             f"{entry.get('classification')!r} not in "
                             f"{sorted(c for c in CLASSIFICATIONS if c)}"
                         )
-                # PR-6 provenance (schema v2+): rates are only
-                # interpretable next to the dtype the state was stored at
-                # and whether the run carry was donated — a v2 roofline
-                # section without them is stale. v1 captures predate the
-                # fields and stay valid as recorded.
+                # provenance: rates are only interpretable next to the
+                # dtype the state was stored at and whether the run carry
+                # was donated — a roofline section without them is stale
                 dp = roofline.get("dtype_policy")
-                if schema_version < 2:
-                    pass
-                elif not isinstance(dp, dict):
+                if not isinstance(dp, dict):
                     errors.append(f"{where}: roofline.dtype_policy missing")
                 else:
                     for key in ("storage", "compute"):
@@ -377,9 +374,7 @@ def validate_run_report(report: Any, where: str = "run_report") -> List[str]:
                 if mh is not None:
                     errors += _validate_multihost(mh, where)
                 don = roofline.get("donation")
-                if schema_version < 2:
-                    pass
-                elif not isinstance(don, dict):
+                if not isinstance(don, dict):
                     errors.append(f"{where}: roofline.donation missing")
                 else:
                     if not isinstance(don.get("donate_carries"), bool):
@@ -2090,408 +2085,6 @@ def validate_metrics_stream(
     return errors
 
 
-def validate_bench(summary: Any, where: str = "bench") -> List[str]:
-    errors: List[str] = []
-    if not isinstance(summary, dict):
-        return [f"{where}: not a JSON object"]
-    for key in ("metric", "value", "unit", "sub_metrics"):
-        if key not in summary:
-            errors.append(f"{where}: missing top-level key {key!r}")
-    errors += [f"{where}: non-finite number at {p}" for p in find_nonfinite(summary)]
-    for i, leg in enumerate(summary.get("sub_metrics", []) or []):
-        loc = f"{where}: sub_metrics[{i}]"
-        if not isinstance(leg, dict):
-            errors.append(f"{loc} is not an object")
-            continue
-        for key in ("metric", "value", "unit"):
-            if key not in leg:
-                errors.append(f"{loc} missing {key!r}")
-        if "value" in leg and not _num(leg["value"]):
-            errors.append(f"{loc}.value non-numeric")
-        vs = leg.get("vs_baseline")
-        if vs is not None and not _num(vs):
-            errors.append(f"{loc}.vs_baseline neither null nor numeric")
-        rounds = leg.get("ratio_rounds")
-        if rounds is not None and (
-            not isinstance(rounds, list) or not all(_num(r) for r in rounds)
-        ):
-            errors.append(f"{loc}.ratio_rounds neither null nor numeric list")
-        metric_l = str(leg.get("metric", "")).lower()
-        # self-baselined A/B legs must carry a MEASURED ratio: a leg
-        # without vs_baseline is an asserted win, and without
-        # ratio_rounds it lacks the spread self-check the differenced
-        # protocol requires
-        for keyword, ratio_name in (
-            ("bf16", "its f32 reference ratio"),
-            ("tenant", "its sequential-baseline ratio"),
-            ("overlap", "its sequential-loop ratio"),
-            ("large-pop", "its replicated-baseline ratio"),
-            # v7: the serving_elastic leg's vs_baseline is the measured
-            # warm-vs-recompile cold-start speedup — the PR-12 claim
-            ("elastic serving", "its cold-start (warm vs recompile) ratio"),
-            # v8: the multihost leg's vs_baseline is the measured
-            # 2-process-vs-1-process ratio (the ISSUE-13 claim); a leg
-            # present without it is an asserted win
-            ("multihost", "its 1-process solo-baseline ratio"),
-            # v10: the surrogate leg's vs_baseline is the measured
-            # screened-vs-full-evaluation wall ratio on the expensive
-            # host problem (the ISSUE-15 claim); the true-eval-count
-            # ledger in the `surrogate` summary key is its static
-            # referee
-            ("surrogate", "its full-evaluation baseline ratio"),
-            # v11: the metrics_overhead leg's vs_baseline is the
-            # measured bare-vs-instrumented wall ratio — the PR-16
-            # <= 2% overhead law must be measured, not asserted
-            ("metrics-plane", "its uninstrumented-baseline ratio"),
-            # v12: the control_plane leg's vs_baseline is the measured
-            # multi-pod-churn vs single-pod-sequential sustained
-            # tenant-gens/sec ratio (ISSUE 18); the gateway report's
-            # exactly-once audit is its static referee
-            ("control-plane", "its single-pod sequential-baseline ratio"),
-        ):
-            if keyword not in metric_l:
-                continue
-            if vs is None or not _num(vs):
-                errors.append(
-                    f"{loc}: {keyword} leg is missing {ratio_name} "
-                    "(vs_baseline null) — the win must be measured, "
-                    "not asserted"
-                )
-            if rounds is None:
-                errors.append(
-                    f"{loc}: {keyword} leg has no ratio_rounds — the "
-                    "A/B spread is the self-check the differenced "
-                    "protocol requires"
-                )
-    rr = summary.get("run_report")
-    if rr is not None:
-        errors += validate_run_report(rr, where=f"{where}: run_report")
-    ten = summary.get("tenancy")
-    if isinstance(ten, dict) and ten.get("run_report") is not None:
-        errors += validate_run_report(
-            ten["run_report"], where=f"{where}: tenancy.run_report"
-        )
-    if isinstance(ten, dict) and ten.get("serving_run_report") is not None:
-        errors += validate_run_report(
-            ten["serving_run_report"],
-            where=f"{where}: tenancy.serving_run_report",
-        )
-    lp = summary.get("large_pop")
-    if isinstance(lp, dict):
-        if lp.get("run_report") is not None:
-            rr_lp = lp["run_report"]
-            errors += validate_run_report(
-                rr_lp, where=f"{where}: large_pop.run_report"
-            )
-            # the instrumented sharded sample must actually carry the
-            # gather-free evidence, not just the timing ratio — UNLESS
-            # the capture says why it legitimately cannot (the producer
-            # omits the subsection where its inequality does not
-            # discriminate: < 4 devices or a fixed-footprint-dominated
-            # shape; see core/instrument.py::_sharding_subsection)
-            if not isinstance(
-                (rr_lp.get("roofline") or {}).get("sharding"), dict
-            ) and not isinstance(lp.get("note"), str):
-                errors.append(
-                    f"{where}: large_pop.run_report.roofline.sharding "
-                    "missing without an explanatory note — the leg's "
-                    "gather-free claim is unmeasured"
-                )
-        table = lp.get("static_bytes")
-        if table is not None:
-            if not isinstance(table, dict):
-                errors.append(f"{where}: large_pop.static_bytes not an object")
-            else:
-                sh = table.get("sharded_per_device_peak_bytes")
-                rp = table.get("replicated_peak_bytes")
-                if not isinstance(sh, int) or not isinstance(rp, int):
-                    errors.append(
-                        f"{where}: large_pop.static_bytes needs int "
-                        "sharded_per_device_peak_bytes and "
-                        "replicated_peak_bytes"
-                    )
-                elif sh >= rp:
-                    errors.append(
-                        f"{where}: large_pop.static_bytes sharded per-device "
-                        f"peak {sh} >= replicated peak {rp} — sharding "
-                        "bought no memory"
-                    )
-    mh = summary.get("multihost")
-    if isinstance(mh, dict) and "error" not in mh:
-        table = mh.get("static_bytes")
-        if not isinstance(table, dict):
-            errors.append(
-                f"{where}: multihost.static_bytes missing — the AOT "
-                "per-process table is the leg's referee"
-            )
-        else:
-            solo = table.get("solo_per_process_peak_bytes")
-            if not isinstance(solo, int) or solo < 1:
-                errors.append(
-                    f"{where}: multihost.static_bytes."
-                    "solo_per_process_peak_bytes missing or not a "
-                    "positive int"
-                )
-            pod = table.get("pod_per_process_peak_bytes")
-            if pod is not None:
-                if not isinstance(pod, int) or pod < 1:
-                    errors.append(
-                        f"{where}: multihost.static_bytes."
-                        "pod_per_process_peak_bytes neither null nor a "
-                        "positive int"
-                    )
-                elif isinstance(solo, int) and pod >= solo:
-                    errors.append(
-                        f"{where}: multihost.static_bytes pod per-process "
-                        f"peak {pod} >= solo peak {solo} — scaling out "
-                        "bought no per-process memory"
-                    )
-            elif not isinstance(table.get("note"), str) and not isinstance(
-                mh.get("skip_reason"), str
-            ):
-                # the measured pod-side number is legitimately absent
-                # only where the backend cannot compile a multiprocess
-                # program — the capture must SAY so (the large_pop
-                # note discipline)
-                errors.append(
-                    f"{where}: multihost.static_bytes has no pod "
-                    "per-process peak and no explanatory note/"
-                    "skip_reason — the scale-out claim is unmeasured"
-                )
-        if mh.get("run_report") is not None:
-            errors += validate_run_report(
-                mh["run_report"], where=f"{where}: multihost.run_report"
-            )
-    sv = summary.get("serving")
-    if isinstance(sv, dict) and "error" not in sv:
-        cs = sv.get("cold_start")
-        if not isinstance(cs, dict):
-            errors.append(
-                f"{where}: serving.cold_start missing — the cold-start "
-                "claim is unmeasured"
-            )
-        else:
-            for key in ("warm_s", "retrace_s", "cold_compile_s"):
-                v = cs.get(key)
-                if not _num(v) or v <= 0:
-                    errors.append(
-                        f"{where}: serving.cold_start.{key} missing or "
-                        "non-positive"
-                    )
-            ref = cs.get("compile_referee")
-            if not isinstance(ref, dict) or not all(
-                _num(ref.get(k)) and ref[k] >= 0
-                for k in (
-                    "compile_s_recorded",
-                    "warm_load_s",
-                    "warm_compile_s_saved",
-                )
-            ):
-                errors.append(
-                    f"{where}: serving.cold_start.compile_referee missing "
-                    "its compile/load seconds — the static compile-ms "
-                    "table is the honesty referee"
-                )
-        rr_sv = sv.get("run_report")
-        if rr_sv is None:
-            errors.append(
-                f"{where}: serving.run_report missing — the warm sample's "
-                "serving.cache section is the zero-recompile evidence"
-            )
-        else:
-            errors += validate_run_report(
-                rr_sv, where=f"{where}: serving.run_report"
-            )
-            if not isinstance(
-                (rr_sv.get("serving") or {}).get("cache"), dict
-            ):
-                errors.append(
-                    f"{where}: serving.run_report carries no "
-                    "serving.cache section — the warm sample was not "
-                    "driven through the executable cache"
-                )
-    ex = summary.get("executor")
-    if isinstance(ex, dict):
-        if ex.get("run_report") is not None:
-            errors += validate_run_report(
-                ex["run_report"], where=f"{where}: executor.run_report"
-            )
-        eff = ex.get("overlap_efficiency")
-        if eff is not None and (not _num(eff) or eff <= 0):
-            errors.append(
-                f"{where}: executor.overlap_efficiency neither null nor "
-                "positive"
-            )
-    sg = summary.get("surrogate")
-    if isinstance(sg, dict) and "error" not in sg:
-        errors += _validate_surrogate_summary(sg, where)
-    cps = summary.get("control_plane")
-    if isinstance(cps, dict) and "error" not in cps:
-        errors += _validate_control_plane_summary(cps, where)
-    return errors
-
-
-def _validate_control_plane_summary(cps: dict, where: str) -> List[str]:
-    """The bench summary's ``control_plane`` key (schema v12, ISSUE 18):
-    the timed leg (sustained tenant-gens/sec under churn, multi-pod vs a
-    single-pod sequential baseline) must carry the gateway's own report
-    as its STATIC REFEREE — the exactly-once admission audit and the SLO
-    ledger — and the churn must actually have exercised the fault path:
-    a pod died mid-sweep and its work was re-placed (stolen), or the
-    speedup was measured on the happy path only."""
-    errors: List[str] = []
-    rep = cps.get("report")
-    if not isinstance(rep, dict):
-        errors.append(
-            f"{where}: control_plane.report missing — the gateway report "
-            "(exactly-once audit + SLO ledger) is the leg's static referee"
-        )
-        return errors
-    errors += _validate_control_plane(rep, f"{where}: control_plane")
-    if not isinstance(rep.get("slo"), dict):
-        errors.append(
-            f"{where}: control_plane.report.slo missing — the SLO ledger "
-            "is the leg's referee"
-        )
-    if not (rep.get("pods") or {}).get("dead"):
-        errors.append(
-            f"{where}: control_plane.report shows no dead pod — the "
-            "churn leg must inject a pod death"
-        )
-    tenants = rep.get("tenants") or {}
-    if not isinstance(tenants.get("stolen"), int) or tenants["stolen"] < 1:
-        errors.append(
-            f"{where}: control_plane.report.tenants.stolen < 1 — the "
-            "dead pod's outstanding work was never re-placed"
-        )
-    return errors
-
-
-def _validate_surrogate_summary(sg: dict, where: str) -> List[str]:
-    """The bench summary's ``surrogate`` key (schema v10, ISSUE 15): the
-    true-eval-count ledger is the STATIC REFEREE behind the timed leg —
-    both runs must have reached the same threshold, the ratio must be
-    coherent with the raw counts, and the ROADMAP item 5 bar
-    (>= 5x fewer TRUE evaluations) must hold unless an explanatory
-    ``note`` says why this capture legitimately cannot show it (the
-    large_pop/multihost note discipline). The instrumented screened
-    run's run_report must carry the v10 surrogate section — the ledger
-    must come from the machine-validated counters, not a hand count."""
-    errors: List[str] = []
-    ledger = sg.get("eval_ledger")
-    if not isinstance(ledger, dict):
-        return [
-            f"{where}: surrogate.eval_ledger missing — the true-eval "
-            "count ledger is the leg's whole evidence"
-        ]
-    if not _num(ledger.get("threshold")):
-        errors.append(f"{where}: surrogate.eval_ledger.threshold missing")
-    for side in ("screened", "full"):
-        entry = ledger.get(side)
-        if not isinstance(entry, dict):
-            errors.append(f"{where}: surrogate.eval_ledger.{side} missing")
-            continue
-        for key in ("true_evals", "generations"):
-            v = entry.get(key)
-            if not isinstance(v, int) or v < 1:
-                errors.append(
-                    f"{where}: surrogate.eval_ledger.{side}.{key} missing "
-                    "or < 1"
-                )
-        best = entry.get("best")
-        thr = ledger.get("threshold")
-        if _num(best) and _num(thr) and best >= thr:
-            errors.append(
-                f"{where}: surrogate.eval_ledger.{side}.best {best} did "
-                f"not reach the threshold {thr} — an unconverged run "
-                "cannot anchor the ledger"
-            )
-    ratio = ledger.get("ratio")
-    scr = (ledger.get("screened") or {}).get("true_evals")
-    full = (ledger.get("full") or {}).get("true_evals")
-    if not _num(ratio):
-        errors.append(f"{where}: surrogate.eval_ledger.ratio missing")
-    elif isinstance(scr, int) and isinstance(full, int) and scr > 0:
-        if abs(ratio - full / scr) > max(0.05 * ratio, 0.01):
-            errors.append(
-                f"{where}: surrogate.eval_ledger.ratio {ratio} incoherent "
-                f"with full/screened = {full}/{scr}"
-            )
-        if ratio < 5.0 and not isinstance(sg.get("note"), str):
-            errors.append(
-                f"{where}: surrogate.eval_ledger.ratio {ratio} is below "
-                "the 5x ROADMAP bar with no explanatory note"
-            )
-    rr = sg.get("run_report")
-    if rr is None:
-        errors.append(
-            f"{where}: surrogate.run_report missing — the ledger must "
-            "come from the machine-validated v10 surrogate section"
-        )
-    else:
-        errors += validate_run_report(rr, where=f"{where}: surrogate.run_report")
-        sec = rr.get("surrogate") if isinstance(rr, dict) else None
-        if not isinstance(sec, dict) or not sec.get("enabled"):
-            errors.append(
-                f"{where}: surrogate.run_report carries no enabled "
-                "surrogate section — the screened sample was not driven "
-                "through the screening workflow"
-            )
-        elif isinstance(scr, int):
-            counted = (sec.get("counters") or {}).get("true_evals")
-            if isinstance(counted, int) and counted != scr:
-                errors.append(
-                    f"{where}: surrogate ledger screened.true_evals {scr} "
-                    f"!= the instrumented run_report counter {counted} — "
-                    "the ledger and the device counters disagree"
-                )
-    return errors
-
-
-def validate_bench_envelope(env: dict, where: str = "bench-envelope") -> List[str]:
-    """BENCH_*.json as the driver captures it: ``{cmd, rc, n, parsed,
-    tail}``. The bench summary is ``parsed`` when the driver managed to
-    parse it, else the last ``tail`` stdout line with ``sub_metrics``."""
-    summary = env.get("parsed")
-    if not isinstance(summary, dict) or "sub_metrics" not in summary:
-        summary = None
-        for line in reversed((env.get("tail") or "").splitlines()):
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                obj = json.loads(line)
-            except ValueError:
-                continue
-            if isinstance(obj, dict) and "sub_metrics" in obj:
-                summary = obj
-                break
-    if summary is None:
-        if env.get("rc") not in (0, None):
-            # the bench itself failed; the envelope faithfully records
-            # that — shape validation has nothing to say
-            return []
-        return [f"{where}: no bench summary line found in parsed/tail"]
-    return validate_bench(summary, where=where)
-
-
-def validate_bench_trajectory(
-    traj: Any, where: str = "bench-trajectory"
-) -> List[str]:
-    """``evox_tpu.bench_trajectory/v1`` — the cross-PR ratio-history
-    file built by tools/bench_trajectory.py. The rules live THERE (one
-    source of truth; the builder refuses to write an invalid file), this
-    entry point just routes the shared validator surface to them."""
-    import os
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    if here not in sys.path:
-        sys.path.insert(0, here)
-    import bench_trajectory
-
-    return bench_trajectory.validate_trajectory(traj, where)
-
-
 def validate_chrome_trace(trace: Any, where: str = "trace") -> List[str]:
     errors: List[str] = []
     if not isinstance(trace, dict) or not isinstance(
@@ -2620,30 +2213,16 @@ def validate_file(path: str) -> List[str]:
             return [f"{path}: invalid JSON: {e}"]
     if isinstance(obj, dict) and "traceEvents" in obj:
         errors = validate_chrome_trace(obj)
-    elif isinstance(obj, dict) and str(obj.get("schema", "")).startswith(
-        "evox_tpu.bench_trajectory/"
-    ):
-        errors = validate_bench_trajectory(obj)
-    elif isinstance(obj, dict) and "sub_metrics" in obj:
-        errors = validate_bench(obj)
-    elif isinstance(obj, dict) and "tail" in obj and "cmd" in obj:
-        # driver envelope around a bench run ({cmd, rc, tail, ...}): the
-        # summary is the last stdout line carrying sub_metrics
-        errors = validate_bench_envelope(obj)
     else:
         errors = validate_run_report(obj)
     return [f"{path}: {e}" for e in errors]
 
 
-#: every schema surface this validator understands, newest first — what
-#: ``--schema`` prints so drivers/tests can pin the supported range
-#: without parsing the module
+#: every schema surface this validator understands — what ``--schema``
+#: prints so drivers/tests can pin it without parsing the module
 SUPPORTED_SCHEMAS = (
-    "evox_tpu.run_report/v14 (validates v1-v14)",
+    RUN_REPORT_SCHEMA,
     "evox_tpu.metrics_stream/v1",
-    "evox_tpu.bench_trajectory/v1",
-    "bench summary (sub_metrics)",
-    "bench envelope (cmd+tail)",
     "chrome trace (traceEvents)",
 )
 
@@ -2671,10 +2250,6 @@ def detect_schema(path: str) -> str:
     if isinstance(obj, dict):
         if "traceEvents" in obj:
             return "chrome trace"
-        if "sub_metrics" in obj:
-            return "bench summary"
-        if "tail" in obj and "cmd" in obj:
-            return "bench envelope"
         if isinstance(obj.get("schema"), str):
             return obj["schema"]
     return "unknown"
