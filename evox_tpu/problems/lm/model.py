@@ -33,7 +33,11 @@ head.
   **that are held here** (``experts_held``, a range): the layer routes over
   all the experts and computes its own experts' part of the result; what the
   absent experts would add is left out and no token is dropped. On one chip
-  it runs without its exchange.
+  it runs without its exchange. The held assignments are sorted by expert
+  once; each expert's rows go through its MLP a block at a time and are put
+  back by token there and then, each block's results, rounded to the
+  operands' dtype, weighted in float32 and added to their tokens' float32
+  sums: only held rows are ever moved (``held_experts``).
 - Fitness: mean next-token negative log-likelihood over the held rows of the
   vocabulary, every position but the first of each document.
 - Precision: the operands of every matrix product in the dtype of the
@@ -326,13 +330,17 @@ def route(cfg: LMConfig, p, f, scale, xn) -> tuple:
 
 def held_experts(cfg: LMConfig, p, f, scale, xn, idx, w, block_rows: int) -> tuple:
     """The weighted sum over each token's chosen experts that are held here,
-    and the held experts' loads ``(n_held,)``.
+    the held experts' loads ``(n_held,)`` and the rows the loop moved.
 
-    The assignments are sorted by expert (those of absent experts last), each
-    held expert's rows go through its MLP in blocks of ``block_rows`` (a loop
-    whose trip count is the blocks there are: nothing is padded to a capacity
-    and nothing is dropped), the results are written in sorted order and
-    gathered back by token."""
+    The assignments are sorted by expert once (those of absent experts last;
+    within an expert by token, each token at most once), each held expert's
+    rows go through its MLP in blocks of ``block_rows`` (a loop whose trip
+    count is the blocks there are: nothing is padded to a capacity and nothing
+    is dropped), and each block's results are weighted and added to their
+    tokens' float32 sums where they are made: nothing between the sort and
+    the layer's output has a row for every assignment. ``moved``: the blocks
+    times ``block_rows``, the rows gathered, computed and put back (the held
+    rows and what fills each expert's last block)."""
     pairs, _, t, d = xn.shape
     dt, k, eh = xn.dtype, cfg.num_experts_per_tok, cfg.n_held
     lo, hi = cfg.experts_held
@@ -344,17 +352,21 @@ def held_experts(cfg: LMConfig, p, f, scale, xn, idx, w, block_rows: int) -> tup
     signs = jnp.asarray(_SIGNS, F32)
     xc = xn.reshape(n, d)
 
-    order = jnp.argsort(key, stable=True)
-    inv = jnp.argsort(order)  # where each assignment lies in the sorted order
+    # one stable sort by expert; each assignment's token and weight travel with its key
+    _, tok_sorted, w_sorted = jax.lax.sort(
+        (key, jnp.arange(n * k, dtype=jnp.int32) // k, w.reshape(n * k)), num_keys=1, is_stable=True
+    )
+    tok_sorted, w_sorted = (jnp.pad(v, (0, block_rows)) for v in (tok_sorted, w_sorted))
     counts = jnp.sum(key[:, None] == jnp.arange(eh)[None, :], axis=0, dtype=jnp.int32)
     starts = jnp.cumsum(counts) - counts
     blocks = (counts + block_rows - 1) // block_rows
     ends = jnp.cumsum(blocks)
-    tok_sorted = jnp.pad(order // k, (0, block_rows))
+    at = jnp.arange(block_rows, dtype=jnp.int32)
 
-    def one_block(b, ybuf):
+    def one_block(b, out):
         e = jnp.sum(b >= ends).astype(jnp.int32)  # the expert whose block this is
-        off = starts[e] + (b - (ends[e] - blocks[e])) * block_rows
+        done = (b - (ends[e] - blocks[e])) * block_rows  # rows of it in its earlier blocks
+        off = starts[e] + done
         tok = jax.lax.dynamic_slice_in_dim(tok_sorted, off, block_rows)
         xb = xc[tok]
         # each row's own pair, with its sign and the scale
@@ -375,26 +387,36 @@ def held_experts(cfg: LMConfig, p, f, scale, xn, idx, w, block_rows: int) -> tup
             g = product(xb, w_e["gate"], fe["gate"])
             u = product(xb, w_e["up"], fe["up"])
             y = product((jax.nn.silu(g) * u).astype(dt), w_e["down"], fe["down"]).astype(dt)
-        # rows past the expert's last belong to the next expert, whose own
-        # blocks come later and write over them
-        return jax.lax.dynamic_update_slice_in_dim(ybuf, y, off, axis=0)
+        # The rows past the expert's last are the next expert's (or absent
+        # experts'), computed with this expert's weights: they go to a row
+        # past ``n``, which the add drops. A zero weight would not do: the add
+        # would still move those tokens' sums, name a token twice where it is
+        # a live row of this block too, and spoil it with a row not finite.
+        row = jnp.where(at < counts[e] - done, tok, n)
+        wb = jax.lax.dynamic_slice_in_dim(w_sorted, off, block_rows)
+        return out.at[row].add((y.astype(F32) * wb[:, None]).reshape((block_rows,) + out.shape[1:]), mode="drop")
 
-    ybuf = jax.lax.fori_loop(0, ends[-1], one_block, jnp.zeros((n * k + block_rows, d), dt))
-    y = ybuf[inv].reshape(n, k, d)
-    keep = (key < eh).reshape(n, k, 1)
-    out = jnp.sum(jnp.where(keep, y.astype(F32) * w.reshape(n, k, 1), 0.0), axis=1)
-    return out.astype(dt).reshape(xn.shape), counts
+    # A token's sum is kept as ``(d / lanes, lanes)``, whole tiles of the
+    # chip's memory lying together, not one row of a matrix, which is a sublane
+    # of each of ``d / 128`` tiles: the chip's scatter moves it in well under
+    # half the time (PERF.md section 6, PR 31).
+    lanes = math.gcd(d, 128)
+    out = jax.lax.fori_loop(0, ends[-1], one_block, jnp.zeros((n, d // lanes, lanes), F32))
+    # the sums are put into rows here, once: unheld, the compiler carries their
+    # shape on into the residual stream, the norms and the head, which it slows
+    out = jax.lax.optimization_barrier(out.astype(dt).reshape(xn.shape))
+    return out, counts, ends[-1] * block_rows
 
 
 def expert_layer(cfg: LMConfig, p, f, scale, xn, blocks: dict) -> tuple:
-    """``(shared MLP, held experts' part, loads)`` of an expert layer for the
-    normed ``xn``; the layer's output is the sum of the first two."""
+    """``(shared MLP, held experts' part, loads, moved)`` of an expert layer
+    for the normed ``xn``; the layer's output is the sum of the first two."""
     with scope(LM_MLP):
         shared = mlp(p["shared"], f["shared"], scale, xn, blocks["shared_block_pairs"])
     with scope(LM_ROUTER):
         idx, w = route(cfg, p, f, scale, xn)
-        routed, loads = held_experts(cfg, p, f, scale, xn, idx, w, blocks["expert_block_rows"])
-    return shared, routed, loads
+        routed, loads, moved = held_experts(cfg, p, f, scale, xn, idx, w, blocks["expert_block_rows"])
+    return shared, routed, loads, moved
 
 
 # How the forward pass is cut so that it fits. ``chunk_pairs``: the pairs that
@@ -420,7 +442,9 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
     ``doc``, ``pos``: ``(T,)`` token ids, each token's document and its
     position in it. Returns ``losses`` ``(pairs, 2)``, ``probe`` (pair 0's
     float32 logits at the last ``n_probe`` positions, ``(2, n_probe, vocab)``),
-    per expert layer ``held`` (assignments that landed on held experts) and
+    per expert layer ``held`` (assignments that landed on held experts),
+    ``moved`` (the rows the experts' loop gathered, computed and put back: the
+    held rows and what fills each expert's last block, over the chunks) and
     ``imbalance`` (largest held expert's load over the mean), and
     ``attn_blocks``: the key blocks attention's kernel visits for this row's
     documents over those of a dense causal pass (1 where the plain body runs:
@@ -461,7 +485,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                     x = x + signs[None, :, None, None] * delta[:, None]
             x = x.astype(dt)
 
-        loads = []
+        loads, moved = [], []
         for p, f in zip(center["layers"], fac["layers"]):
             with scope(LM_ATTENTION):
                 x = x + attention(cfg, p["attn"], f["attn"], scale, x, attend, cos, sin,
@@ -472,10 +496,11 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                 with scope(LM_MLP):
                     x = x + mlp(p["mlp"], f["mlp"], scale, xn, blocks["dense_block_pairs"])
             else:
-                shared, routed, load = expert_layer(cfg, p, f, scale, xn, blocks)
+                shared, routed, load, rows = expert_layer(cfg, p, f, scale, xn, blocks)
                 with scope(LM_ROUTER):
                     x = x + shared + routed
                 loads.append(load)
+                moved.append(rows)
 
         with scope(LM_HEAD_LOSS):
             xn = rmsnorm(x, center["final_norm"], cfg.rms_norm_eps).astype(dt)
@@ -494,10 +519,11 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                 jax.tree.map(lambda v: v[:1], fac["head"]), scale, F32,
             )[0]
         loads = jnp.stack(loads) if loads else jnp.zeros((0, cfg.n_held), jnp.int32)
-        return losses, probe, loads
+        moved = jnp.stack(moved) if moved else jnp.zeros((0,), jnp.int32)
+        return losses, probe, loads, moved
 
     split = lambda a: a.reshape((pairs // cp, cp) + a.shape[1:])
-    losses, probe, loads = jax.lax.map(chunk, jax.tree.map(split, factors))
+    losses, probe, loads, moved = jax.lax.map(chunk, jax.tree.map(split, factors))
     with scope(LM_ROUTER):
         loads = jnp.sum(loads, axis=0)  # (expert layers, held experts)
         imbalance = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads.astype(F32), axis=-1), 1.0)
@@ -505,6 +531,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         "losses": losses.reshape(pairs, 2),
         "probe": probe[0],  # pair 0 is the first of the first chunk
         "held": jnp.sum(loads, axis=-1).astype(jnp.int32),
+        "moved": jnp.sum(moved, axis=0).astype(jnp.int32),
         "imbalance": imbalance.astype(F32),
         "attn_blocks": attn_blocks,
     }

@@ -41,7 +41,9 @@ class TokenLMState(PyTreeNode):
     ``fold_in(key, generation)``). Of the last evaluation: every member's
     ``losses``; ``probe``, the float32 logits of members 0 and ``pop / 2``
     (the two signs of pair 0) at the row's last positions; for each expert
-    layer ``held``, the routed assignments that landed on held experts, and
+    layer ``held``, the routed assignments that landed on held experts,
+    ``moved``, the rows the experts' loop gathered, computed and put back
+    (``held`` and what fills each expert's last block of a chunk), and
     ``imbalance``, the largest held expert's load over the mean;
     ``attn_blocks``, the key blocks the attention kernel's loop bounds visited
     over the key blocks of a dense causal pass (how much of the row's
@@ -52,6 +54,7 @@ class TokenLMState(PyTreeNode):
     losses: jax.Array = field(sharding=P(POP_AXIS), storage=False)
     probe: jax.Array = field(sharding=P())
     held: jax.Array = field(sharding=P())
+    moved: jax.Array = field(sharding=P())
     imbalance: jax.Array = field(sharding=P())
     attn_blocks: jax.Array = field(sharding=P())
 
@@ -103,6 +106,7 @@ class TokenLMProblem(Problem):
             losses=jnp.zeros((self.pop_size,), jnp.float32),
             probe=jnp.zeros((2, self.n_probe, self.cfg.vocab_size), jnp.float32),
             held=jnp.zeros((n,), jnp.int32),
+            moved=jnp.zeros((n,), jnp.int32),
             imbalance=jnp.zeros((n,), jnp.float32),
             attn_blocks=jnp.zeros((), jnp.float32),
         )
@@ -120,5 +124,6 @@ class TokenLMProblem(Problem):
         losses = out["losses"].T.reshape(-1)  # the + half, then the - half
         return losses, state.replace(
             generation=state.generation + 1, losses=losses, probe=out["probe"],
-            held=out["held"], imbalance=out["imbalance"], attn_blocks=out["attn_blocks"],
+            held=out["held"], moved=out["moved"], imbalance=out["imbalance"],
+            attn_blocks=out["attn_blocks"],
         )

@@ -46,6 +46,8 @@ if __name__ == "__main__":
     state = wf.init(key)
     for _ in range(3):
         state = wf.run(state, 1)
+        held, moved = state.prob.held, state.prob.moved
         print(f"generation {int(state.generation)}: mean loss {float(state.prob.losses.mean()):.4f}, "
-              f"held assignments a layer {state.prob.held.tolist()}, "
+              f"held assignments a layer {held.tolist()}, "
+              f"rows moved over held {[round(float(v), 2) for v in moved / jnp.maximum(held, 1)]}, "
               f"imbalance {[round(float(v), 2) for v in state.prob.imbalance]}")

@@ -206,6 +206,60 @@ def test_topk_outside_envelope_is_not_the_kernel(one_chip, no_persistent_cache):
     assert "tpu_custom_call" not in jax.jit(fn).lower(arg).compile().as_text()
 
 
+# ------------------------------------------------------- the language model
+
+
+def _routed_path():
+    """The router and ``held_experts`` of one expert layer at the cell's
+    shapes: a chunk of 4 pairs, 16,384 tokens, 98,304 assignments."""
+    from benchmark.lib import manifest as mf
+    from evox_tpu.core.lowrank import tree_factors
+    from evox_tpu.problems.lm import LMConfig
+    from evox_tpu.problems.lm import model as lm
+
+    _, _, config, traffic = mf.cell_parts(mf.load(), "moonlight_es_pop64_seq2k")
+    cfg, blocks = LMConfig.from_dict(config), config["blocks"]
+    pairs = blocks["chunk_pairs"]
+    layer = lm.param_shapes(cfg)["layers"][cfg.first_k_dense_replace]
+    center = {k: layer[k] for k in ("router", "router_bias", "experts")}
+    f32 = jax.tree.map(lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32), center, is_leaf=lm._is_shape)
+    factors = jax.eval_shape(lambda c: tree_factors(jax.random.PRNGKey(0), c, pairs, int(config["rank"])), f32)
+    p = jax.tree.map(lambda v: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16 if len(v.shape) > 1 else v.dtype), f32)
+    xn = jax.ShapeDtypeStruct((pairs, 2, int(traffic["seq_len"]), cfg.hidden_size), jnp.bfloat16)
+
+    def fn(p, f, xn):
+        idx, w = lm.route(cfg, p, f, jnp.float32(1e-3), xn)
+        return lm.held_experts(cfg, p, f, jnp.float32(1e-3), xn, idx, w, blocks["expert_block_rows"])
+
+    return fn, (p, factors, xn)
+
+
+def test_routed_path_keeps_no_row_for_every_assignment_on_the_chip(one_chip, no_persistent_cache):
+    """The chip's compiler, for a chunk of the language-model cell: what the
+    routed path keeps beside its arguments and its output is the tokens'
+    float32 sums (16,384 x 2,048 x 4 B = 134 MB) and little more. The path
+    that wrote every assignment's row and gathered it back kept 1,481,524,736
+    B (PR 31's compile of its parent)."""
+    fn, args = _routed_path()
+    stats = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile().memory_analysis()
+    assert stats.temp_size_in_bytes < 150_000_000, stats
+
+
+def test_language_model_cell_compiles_for_v5e(topo, no_persistent_cache, monkeypatch):
+    """The cell's steady run loop at its real shapes, with the body the chip
+    takes (``forward`` asks the backend, steered here): the attention kernel
+    is there, once a layer, and the temporaries stay under what they were
+    while the routed path kept a row for every assignment (2,752,349,184 B
+    at PR 31; the bfloat16 copy of the centre is 1.69 GB of them)."""
+    from benchmark import rehearse
+    from benchmark.lib import manifest as mf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = rehearse.rehearse(mf.load(), "moonlight_es_pop64_seq2k", topo)
+    assert got["custom_calls"] == 5, got
+    assert got["temp_bytes_per_device"] < 4_258_132_480, got  # the parent of PR 31, which wrote every assignment's row
+
+
 # --------------------------------------------------------------- four chips
 
 

@@ -163,7 +163,7 @@ def test_the_shares_of_an_expert_layer_add_up():
     for lo in (0, 2, 4, 6):
         cfg = LMConfig.from_dict(dict(TINY, experts_held=[lo, lo + 2]))
         p = dict(full, experts=jax.tree.map(lambda v: v[lo : lo + 2], full["experts"]))
-        shared, routed, loads = lm.expert_layer(
+        shared, routed, loads, _ = lm.expert_layer(
             cfg, p, no_noise(p), jnp.float32(0.0), xn, {**lm.DEFAULT_BLOCKS, **BLOCKS}
         )
         total = total + routed
@@ -181,6 +181,134 @@ def test_the_shares_of_an_expert_layer_add_up():
         mine = jnp.sum(jnp.where(idx == e, weight, 0), axis=-1)
         want = want + mine[:, None] * ref._swiglu(x, jax.tree.map(lambda v: v[e], full["experts"]))
     np.testing.assert_allclose(total.reshape(-1, d), want, rtol=0, atol=1e-5)
+
+
+# ---------------------------------------------------------------- the routed path
+# 24 tokens (one pair of 12), 8 experts, two choices a token, blocks of 8 rows;
+# experts 2, 3, 4 held unless a case says otherwise
+ROUTED = dict(TINY, hidden_size=16, moe_intermediate_size=8, n_routed_experts=3, experts_held=[2, 5])
+ROUTED_T, ROUTED_ROWS = 12, 8
+
+
+def _choices(chosen: dict, rest=(0, 1)) -> np.ndarray:
+    """``(24, 2)`` expert ids: ``chosen[token]`` where given, else ``rest``."""
+    idx = np.tile(np.asarray(rest, np.int32), (2 * ROUTED_T, 1))
+    for token, pair in chosen.items():
+        idx[token] = pair
+    return idx
+
+
+HELD_CASES = {
+    # expert 3 is held and nobody chooses it
+    "an_expert_with_no_row": (ROUTED, _choices({t: (2, 4) for t in range(0, 24, 3)})),
+    # expert 2: 8 rows, one full block; expert 3: 9 rows, a block and one row; expert 4: none
+    "a_full_block_and_one_row_more": (
+        ROUTED, _choices({**{t: (2, 7) for t in range(8)}, **{t: (6, 3) for t in range(10, 19)}})),
+    "one_row_in_all": (ROUTED, _choices({17: (5, 4)})),
+    "every_choice_absent": (ROUTED, _choices({t: (5, 7) for t in range(0, 24, 2)})),
+    "every_choice_held": (
+        dict(ROUTED, n_routed_experts=8, experts_held=[0, 8]),
+        np.stack([np.arange(24) % 8, (np.arange(24) * 3 + 1) % 8], axis=1).astype(np.int32)),
+    # The double count: expert 2 has rows 1, 5, 9 and its one block's tail holds the first five
+    # rows of expert 3, tokens 5, 6, 7, 9, 11: tokens 5 and 9 are live rows of that block AND tail
+    # rows of it, and live rows again in expert 3's own block, which comes next.
+    "a_token_live_in_a_block_and_in_its_tail": (
+        ROUTED, _choices({1: (2, 0), 5: (3, 2), 9: (2, 3), 6: (3, 7), 7: (1, 3), 11: (3, 4), 20: (4, 3)})),
+}
+
+
+def _routed_case(config, idx, dtype=jnp.float32):
+    cfg = LMConfig.from_dict(config)
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    experts = ref._init(dict(config, n_routed_experts=cfg.n_held), keys[0])["layers"][1]["experts"]
+    xn = jax.random.normal(keys[1], (1, 2, ROUTED_T, cfg.hidden_size)).astype(dtype)
+    w = jax.random.uniform(keys[2], idx.shape, minval=0.2, maxval=1.5)
+    p = {"experts": jax.tree.map(lambda v: v.astype(dtype), experts)}
+    f = {"experts": jax.tree.map(lambda v: (jnp.zeros((1,) + v.shape[:-1] + (1,)),
+                                             jnp.zeros((1, v.shape[0], v.shape[2], 1))), experts)}
+    return cfg, p, f, xn, jnp.asarray(idx), w
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_held_experts_is_the_plain_weighted_sum_over_the_held_experts(case, monkeypatch):
+    """``held_experts`` against a float32 loop over the held experts with
+    masks, the loads and ``moved`` exactly; and, block by block, each add
+    names the block's live rows and no other row of the sums (the held rows
+    are put back once each), and whatever it tells the compiler of its rows
+    (ascending, none named twice) is true: a claim no sum on the CPU would
+    test, its scatter being serial."""
+    config, idx = HELD_CASES[case]
+    cfg, p, f, xn, idx, w = _routed_case(config, idx)
+    lo, hi = cfg.experts_held
+    got, loads, moved = jax.jit(
+        lambda *a: lm.held_experts(cfg, *a, ROUTED_ROWS))(p, f, jnp.float32(0.0), xn, idx, w)
+
+    x = xn.reshape(-1, cfg.hidden_size)
+    want = jnp.zeros_like(x)
+    for e in range(lo, hi):
+        mine = jnp.sum(jnp.where(idx == e, w, 0.0), axis=-1)
+        want = want + mine[:, None] * ref._swiglu(x, jax.tree.map(lambda v: v[e - lo], p["experts"]))
+    np.testing.assert_allclose(got.reshape(x.shape), want, rtol=0, atol=1e-6)
+    counts = np.asarray([(np.asarray(idx) == e).sum() for e in range(lo, hi)])
+    np.testing.assert_array_equal(loads, counts)
+    assert int(moved) == int(np.sum(-(-counts // ROUTED_ROWS)) * ROUTED_ROWS)
+
+    bind, seen = jax.lax.scatter_add_p.bind, []
+
+    def honest(operand, rows, updates, **params):
+        if not isinstance(rows, jax.core.Tracer):  # the call on values; its dispatch binds once more, traced
+            at = np.asarray(rows).reshape(-1)
+            assert not params["indices_are_sorted"] or np.all(np.diff(at) >= 0), at
+            assert not params["unique_indices"] or len(set(at.tolist())) == at.size, at
+            seen.append(int(np.sum(at < operand.shape[0])))
+        return bind(operand, rows, updates, **params)
+
+    monkeypatch.setattr(jax.lax.scatter_add_p, "bind", honest)
+    with jax.disable_jit():  # the loop runs block by block, every operation on its values
+        eager, _, _ = lm.held_experts(cfg, p, f, jnp.float32(0.0), xn, idx, w, ROUTED_ROWS)
+    monkeypatch.undo()
+    assert sum(seen) == counts.sum() and len(seen) * ROUTED_ROWS == int(moved)  # each held row put back once
+    np.testing.assert_allclose(eager, got, rtol=0, atol=1e-6)
+
+
+def _sub_jaxprs(jaxpr):
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for inner in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _sub_jaxprs(inner)
+
+
+def test_the_routed_path_sorts_once_and_keeps_no_row_for_every_assignment():
+    """The law of the routed path: one ``sort``; nothing it makes is as
+    large as ``n * k`` rows of width ``hidden``, however it is shaped;
+    ``moved`` is the blocks of the loop
+    times their rows, between ``held`` and ``held`` plus what can fill each
+    held expert's last block."""
+    cfg, p, f, xn, _, _ = _routed_case(ROUTED, _choices({}), jnp.bfloat16)
+    f["router"], p["router"] = None, jax.random.normal(jax.random.PRNGKey(12), (cfg.hidden_size, 8)).astype(xn.dtype)
+    p["router_bias"] = jnp.zeros((8,))
+    idx, w = lm.route(cfg, p, f, jnp.float32(0.0), xn)
+    fn = lambda p, f, xn, idx, w: lm.held_experts(cfg, p, f, jnp.float32(1e-3), xn, idx, w, ROUTED_ROWS)
+    n, k, d = 2 * ROUTED_T, cfg.num_experts_per_tok, cfg.hidden_size
+    eqns = [e for j in _sub_jaxprs(jax.make_jaxpr(fn)(p, f, xn, idx, w).jaxpr) for e in j.eqns]
+    assert sum(e.primitive.name == "sort" for e in eqns) == 1
+    wide = [v.aval.shape for e in eqns for v in e.outvars if np.prod(v.aval.shape) >= n * k * d]
+    assert not wide, wide
+    _, loads, moved = fn(p, f, xn, idx, w)
+    held = int(jnp.sum(loads))
+    assert held > 0 and int(moved) == int(jnp.sum(-(-loads // ROUTED_ROWS)) * ROUTED_ROWS)
+    assert held <= int(moved) <= held + cfg.n_held * (ROUTED_ROWS - 1)
+
+    wf, key = _workflow()
+    state = wf.run(wf.init(key).replace(first_step=False), 1)
+    held, moved = np.asarray(state.prob.held), np.asarray(state.prob.moved)
+    chunks = TRAFFIC["pop"] // 2 // BLOCKS["chunk_pairs"]
+    fill = chunks * TINY["n_routed_experts"] * (BLOCKS["expert_block_rows"] - 1)
+    assert np.all(held > 0) and np.all(moved % BLOCKS["expert_block_rows"] == 0)
+    assert np.all(held <= moved) and np.all(moved <= held + fill)
 
 
 def test_packed_row_packs_documents_until_the_row_is_full():
