@@ -16,10 +16,16 @@ fallback stays the default.
   MLA heads (keys 192 wide, values 128) over a packed row of documents; its
   fallback and reference is ``problems/lm/model.py`` ``attend_plain``, and
   the caller chooses by platform and shape (``flash_block_sizes``).
+- ``kda_scan``: Kimi Delta Attention's gated delta rule over a packed row,
+  chunkwise and exact, the state in VMEM across the row's chunks; its
+  fallback is the same chunk arithmetic in XLA (``kda_scan_chunked``), its
+  oracle the token-by-token recurrence (``kda_scan_reference``); the caller
+  chooses by platform and head width.
 """
 
 from .dominance import packed_dominance, packed_dominance_reference
 from .flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from .kda_scan import kda_scan, kda_scan_chunked, kda_scan_reference
 from .topk import default_use_kernel, partial_topk, partial_topk_reference
 from .rollout import (
     SoAEnv,
@@ -42,6 +48,9 @@ __all__ = [
     "flash_attention",
     "flash_block_bounds",
     "flash_block_sizes",
+    "kda_scan",
+    "kda_scan_chunked",
+    "kda_scan_reference",
     "default_use_kernel",
     "partial_topk",
     "partial_topk_reference",

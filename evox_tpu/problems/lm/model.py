@@ -1,11 +1,14 @@
 """The member model of :class:`~evox_tpu.problems.lm.TokenLMProblem`: a
-decoder-only language model with latent attention (MLA) and a mixture of
-experts (the ``deepseek_v3`` family), evaluated for every member of a
+decoder-only language model whose layers mix tokens by latent attention (MLA)
+or by Kimi Delta Attention (KDA, a gated delta-rule linear attention), each
+layer of the kind the configuration's pattern gives it, over a mixture of
+experts (two families: ``deepseek_v3``, every layer MLA with RoPE, and
+``kimi_linear``, KDA and unrotated MLA), evaluated for every member of a
 low-rank population at once.
 
 The equations (each departure from the published modelling code is listed in
 the benchmark configuration's ``assumed``). Pre-norm residual blocks, ``h = x
-+ attn(norm(x))``, ``y = h + mlp(norm(h))``, RMSNorm, a final norm, an untied
++ mixer(norm(x))``, ``y = h + mlp(norm(h))``, RMSNorm, a final norm, an untied
 head.
 
 - MLA, per token: ``q = x Wq`` as ``heads`` of ``qk_nope + qk_rope``. ``x
@@ -13,7 +16,8 @@ head.
   through RMSNorm give ``c``, the rest are ``k_rope``, one for all heads.
   RoPE (``rope_theta``, no scaling, the two halves of the rope dimensions
   paired, positions counted from the start of the token's document) on
-  ``q_rope`` and ``k_rope``. ``c Wkvb`` gives for each head ``k_nope`` and
+  ``q_rope`` and ``k_rope``; with ``mla_use_nope`` none: those dimensions are
+  used as they come. ``c Wkvb`` gives for each head ``k_nope`` and
   ``v``. ``k = [k_nope, k_rope]``; scores ``q.k / sqrt(qk_nope + qk_rope)``,
   causal and within a document only, softmax in float32; the heads' outputs
   through ``Wo``. Two bodies, one result (``forward`` chooses by what it can
@@ -24,6 +28,24 @@ head.
   wholly of earlier documents are not visited; only the output is written);
   elsewhere ``attend_plain``, which makes every member's and head's ``(T,
   T)`` scores in HBM and is the kernel's reference in the tests.
+- KDA, per token, on the normed ``xn``: ``q~ = xn Wq``, ``k~ = xn Wk``, ``v~ =
+  xn Wv``, ``heads * head_dim`` wide each. A short convolution on each of the
+  three (depthwise, causal, ``short_conv_kernel_size`` taps, then SiLU): ``u_t
+  = silu(sum_j w[:, j] * u~_{t - (taps - 1) + j})``, a tap that reaches before
+  the token's document began reading zero. ``w`` is a leaf of two axes
+  ``(channels, taps)``, so the search perturbs it, and being no ``x @ W`` each
+  member's ``w + sign * scale * A_p B_p^T`` is formed. Per head ``q <- q /
+  sqrt(sum q^2 + 1e-6) / sqrt(head_dim)``, ``k <- k / sqrt(sum k^2 + 1e-6)``.
+  The decay, per head and key channel: ``g = -exp(A_log[h]) * softplus((xn Wfa)
+  Wfb + dt_bias) <= 0``; ``beta = sigmoid(xn Wb)`` a head. The delta rule, the
+  state ``S`` ``(head_dim, head_dim)`` zero at the first token of every
+  document: ``S' = diag(exp(g_t)) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t -
+  S'^T k_t)^T``, ``o_t = S_t^T q_t``. Then per head ``rmsnorm(o) *
+  sigmoid((xn Wga) Wgb)`` and ``Wo``. The recurrence is computed chunkwise and
+  exactly (``kernels/kda_scan.py`` says how): on the TPU backend, where a
+  head is a whole lane tile wide, by the ``kda_scan`` kernel (the state stays
+  in VMEM across the row's chunks), elsewhere by the same chunk arithmetic in
+  plain XLA; ``forward`` chooses by what it can observe, no option.
 - The dense layers' MLP and the shared experts (one MLP of width
   ``n_shared_experts * moe_intermediate_size``): ``down(silu(gate x) * up x)``.
 - Router: ``s = sigmoid(x Wr)`` in float32 over all ``n_routed_experts``; the
@@ -47,7 +69,11 @@ head.
   accumulator are float32 in both bodies; the probabilities are cast to the
   operands' dtype as the operand of ``p.v`` (normalised in the plain body,
   unnormalised in the kernel, which divides the accumulator by the sum at the
-  end: the same relative rounding), and the output once more.
+  end: the same relative rounding), and the output once more. In KDA the
+  convolutions, the L2 norms, ``g`` and its running sums, ``beta``, the
+  chunk's triangular solve, the carried state and the output norm and gate
+  are float32; ``q``, ``k``, ``v`` and every operand of the scan's products
+  are in the operands' dtype.
 
 Members: the population's two halves are the two signs of ``pairs``
 perturbations (``core/lowrank.py``). Activations are laid out ``(pairs, 2,
@@ -74,22 +100,29 @@ from ...core.instrument import (
     LM_EXPERTS,
     LM_FORWARD,
     LM_HEAD_LOSS,
+    LM_KDA,
+    LM_KDA_SCAN,
     LM_LOWRANK,
     LM_MLP,
     LM_ROUTER,
     scope,
 )
 from ...kernels.flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from ...kernels.kda_scan import KDA_CHUNK, kda_scan, kda_scan_chunked
 
 F32 = jnp.float32
 
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """The model's shapes. ``n_routed_experts`` is the router's width;
-    ``experts_held`` the range ``[lo, hi)`` of experts this chip holds;
-    ``vocab_size`` the rows of the vocabulary held here; ``layers`` the depth
-    held here, its first ``first_k_dense_replace`` layers dense."""
+    """The model's shapes, under the ``deepseek_v3`` family's names.
+    ``n_routed_experts`` is the router's width; ``experts_held`` the range
+    ``[lo, hi)`` of experts this chip holds; ``vocab_size`` the rows of the
+    vocabulary held here; ``layers`` the depth held here, its first
+    ``first_k_dense_replace`` layers dense. ``layer_kinds``: how each layer
+    held mixes tokens, ``"mla"`` or ``"kda"`` (empty: every layer MLA).
+    ``mla_use_nope``: MLA does not rotate (``rope_theta`` is then unused).
+    ``kda_num_heads``, ``kda_head_dim``, ``kda_conv_size``: KDA's sizes."""
 
     hidden_size: int
     num_attention_heads: int
@@ -108,24 +141,55 @@ class LMConfig:
     layers: int
     vocab_size: int
     rms_norm_eps: float = 1e-5
-    rope_theta: float = 50000.0
+    rope_theta: Optional[float] = None
     init_std: float = 0.02
+    layer_kinds: Tuple[str, ...] = ()
+    mla_use_nope: bool = False
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_size: int = 0
 
     @classmethod
     def from_dict(cls, config: dict) -> "LMConfig":
-        """From a configuration file's keys (the published ``config.json``'s
-        names; ``layers`` the depth held, ``n_routed_experts`` the experts
-        held beside ``n_routed_experts_published`` and ``experts_held``)."""
+        """From a configuration file's keys: the published ``config.json``'s
+        names of either family, told apart by ``model_type``, beside ``layers``
+        (the depth held) and ``experts_held``. ``deepseek_v3``:
+        ``n_routed_experts`` the experts held beside
+        ``n_routed_experts_published``. ``kimi_linear``: ``num_experts`` the
+        experts held beside ``num_experts_published``, and
+        ``linear_attn_config``, whose layer numbers count from 1 and of which
+        the first ``layers`` are held."""
+        family = config.get("model_type", "deepseek_v3")
+        if family not in _FAMILY_KEYS:
+            raise ValueError(f"model_type {family!r} is not one of {sorted(_FAMILY_KEYS)}")
+        own = {"experts_held", "rope_theta", "layer_kinds", "mla_use_nope"}
+        names = {f.name for f in dataclasses.fields(cls) if not f.name.startswith("kda_")} - own
+        keys = {name: _FAMILY_KEYS[family].get(name, name) for name in names}
+        given = {name: config[key] for name, key in keys.items() if key in config}
         lo, hi = (int(v) for v in config["experts_held"])
-        if hi - lo != int(config["n_routed_experts"]):
+        if hi - lo != int(given["n_routed_experts"]):
             raise ValueError(
-                f"experts_held {lo}..{hi} is not n_routed_experts={config['n_routed_experts']} experts"
+                f"experts_held {lo}..{hi} is not {keys['n_routed_experts']}={given['n_routed_experts']} experts"
             )
-        names = {f.name for f in dataclasses.fields(cls)} - {"n_routed_experts", "experts_held"}
+        given["n_routed_experts"] = int(config[keys["n_routed_experts"] + "_published"])
+        layers = int(config["layers"])
+        kinds, kda = ("mla",) * layers, {}
+        if family == "kimi_linear":
+            for key, want in _KIMI_ROUTER.items():  # what ``route`` does
+                if config[key] != want:
+                    raise ValueError(f"kimi_linear: {key}={config[key]!r}, the router here does {want!r}")
+            linear = config["linear_attn_config"]
+            where = {**{int(l): "mla" for l in linear["full_attn_layers"]},
+                     **{int(l): "kda" for l in linear["kda_layers"]}}
+            kinds = tuple(where[l] for l in range(1, layers + 1))
+            kda = dict(kda_num_heads=int(linear["num_heads"]), kda_head_dim=int(linear["head_dim"]),
+                       kda_conv_size=int(linear["short_conv_kernel_size"]))
+        nope = bool(config.get("mla_use_nope", False))
         return cls(
-            n_routed_experts=int(config["n_routed_experts_published"]),
-            experts_held=(lo, hi),
-            **{k: config[k] for k in names if k in config},
+            experts_held=(lo, hi), layer_kinds=kinds, mla_use_nope=nope,
+            # a rotating family states its theta: no other model's stands in for it
+            rope_theta=None if nope else float(config["rope_theta"]),
+            **given, **kda,
         )
 
     @property
@@ -135,6 +199,24 @@ class LMConfig:
     @property
     def expert_layers(self) -> int:
         return self.layers - self.first_k_dense_replace
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return self.layer_kinds or ("mla",) * self.layers
+
+    @property
+    def kda_layers(self) -> int:
+        return sum(kind == "kda" for kind in self.kinds)
+
+
+# a field of LMConfig under another family's published name
+_FAMILY_KEYS = {
+    "deepseek_v3": {},
+    "kimi_linear": {"n_routed_experts": "num_experts", "num_experts_per_tok": "num_experts_per_token",
+                    "n_shared_experts": "num_shared_experts"},
+}
+_KIMI_ROUTER = {"moe_renormalize": True, "moe_router_activation_func": "sigmoid", "use_grouped_topk": True,
+                "num_expert_group": 1, "topk_group": 1}
 
 
 def param_shapes(cfg: LMConfig) -> dict:
@@ -148,13 +230,25 @@ def param_shapes(cfg: LMConfig) -> dict:
         "kvb": (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
         "o": (h * cfg.v_head_dim, d),
     }
+    kh, kd, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_size
+    kda = {
+        "norm": (d,),
+        "q": (d, kh * kd), "k": (d, kh * kd), "v": (d, kh * kd),
+        "q_conv": (kh * kd, taps), "k_conv": (kh * kd, taps), "v_conv": (kh * kd, taps),
+        "f_a": (d, kd), "f_b": (kd, kh * kd),  # the decay's low-rank projection
+        "A_log": (kh,), "dt_bias": (kh * kd,),
+        "beta": (d, kh),
+        "g_a": (d, kd), "g_b": (kd, kh * kd),  # the output gate's
+        "o_norm": (kd,),
+        "o": (kh * kd, d),
+    }
 
     def mlp(width, stack=()):
         return {"gate": stack + (d, width), "up": stack + (d, width), "down": stack + (width, d)}
 
     layers = []
-    for l in range(cfg.layers):
-        layer = {"attn": dict(attn), "mlp_norm": (d,)}
+    for l, kind in enumerate(cfg.kinds):
+        layer = {"mlp_norm": (d,), **({"kda": dict(kda)} if kind == "kda" else {"attn": dict(attn)})}
         if l < cfg.first_k_dense_replace:
             layer["mlp"] = mlp(cfg.intermediate_size)
         else:
@@ -176,16 +270,24 @@ def _is_shape(x: Any) -> bool:
 
 
 def init_params(cfg: LMConfig, key: jax.Array) -> dict:
-    """Seeded float32 parameters: matrix ``l`` (its index among the leaves)
-    ``init_std * normal(fold_in(key, l))``, norm gains one, the router's
-    correction bias zero."""
+    """Seeded float32 parameters, leaf ``l`` (its index among the leaves) from
+    ``fold_in(key, l)``: a leaf of two or three axes ``init_std * normal``
+    (KDA's convolutions among them), norm gains one, the router's correction
+    bias zero, KDA's ``A_log = log(uniform(1, 16))`` and ``dt_bias`` the
+    inverse softplus of a ``dt`` log-uniform in 0.001 to 0.1."""
     paths, treedef = jax.tree.flatten_with_path(param_shapes(cfg), is_leaf=_is_shape)
     leaves = []
     for l, (path, shape) in enumerate(paths):
+        name, k = getattr(path[-1], "key", None), jax.random.fold_in(key, l)
         if len(shape) >= 2:
-            leaves.append(cfg.init_std * jax.random.normal(jax.random.fold_in(key, l), shape, F32))
-        elif getattr(path[-1], "key", None) == "router_bias":
+            leaves.append(cfg.init_std * jax.random.normal(k, shape, F32))
+        elif name == "router_bias":
             leaves.append(jnp.zeros(shape, F32))
+        elif name == "A_log":
+            leaves.append(jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0)))
+        elif name == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(k, shape, F32, math.log(0.001), math.log(0.1)))
+            leaves.append(dt + jnp.log(-jnp.expm1(-dt)))
         else:
             leaves.append(jnp.ones(shape, F32))
     return jax.tree.unflatten(treedef, leaves)
@@ -273,11 +375,12 @@ def _flash_blocks(cfg: LMConfig, t: int) -> Optional[tuple]:
     return flash_block_sizes(t, cfg.qk_nope_head_dim, cfg.v_head_dim)
 
 
-def attention(cfg: LMConfig, p, f, scale, x, attend, cos, sin, block_pairs: int) -> jax.Array:
+def attention(cfg: LMConfig, p, f, scale, x, attend, rotate, block_pairs: int) -> jax.Array:
     """``attn(norm(x))``, a block of pairs at a time (the plain body's scores
     of all members at once would be ``pop * heads * T * T`` floats).
     ``attend``: ``attend_plain`` or ``attend_flash`` with the row's mask or
-    bounds bound."""
+    bounds bound. ``rotate``: RoPE at the row's positions, or the identity
+    where MLA does not rotate."""
     pairs, _, t, _ = x.shape
     dt = x.dtype
     h, dn, dr, dv, dl = (cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -293,12 +396,73 @@ def attention(cfg: LMConfig, p, f, scale, x, attend, cos, sin, block_pairs: int)
         kva = linear(xn, p["kva"], fb["kva"], scale, F32)
         c = rmsnorm(kva[..., :dl], p["kv_norm"], cfg.rms_norm_eps).astype(dt)
         kv = linear(c, p["kvb"], fb["kvb"], scale, dt).reshape(m, t, h, dn + dv)
-        q_rope = _rope(q[..., dn:], cos, sin).astype(dt)
-        k_rope = _rope(kva[..., dl:].reshape(m, t, 1, dr), cos, sin).astype(dt)[:, :, 0]
+        q_rope = rotate(q[..., dn:]).astype(dt)
+        k_rope = rotate(kva[..., dl:].reshape(m, t, 1, dr)).astype(dt)[:, :, 0]
         o = attend(q, q_rope, kv, k_rope)
         return linear(o.reshape(bp, 2, t, h * dv), p["o"], fb["o"], scale, dt)
 
     return jax.lax.map(block, (split(x), jax.tree.map(split, f))).reshape(x.shape)
+
+
+def short_conv(u, w, fac, scale, reach) -> jax.Array:
+    """KDA's short convolution and SiLU on one stream. ``u`` ``(pairs, 2, T,
+    channels)``; ``w`` ``(channels, taps)``, depthwise, its last tap on the
+    token itself; ``reach`` ``(taps, T)``: whether the tap ``s`` tokens back
+    lies in the token's document. Each member's own ``w + sign * scale * A_p
+    B_p^T`` is formed: the leaf is no ``x @ W``. Float32."""
+    t, taps = u.shape[2], w.shape[1]
+    w = w.astype(F32)[None, None]
+    if fac is not None:
+        with scope(LM_LOWRANK):
+            delta = jnp.einsum("pcr,pjr->pcj", *fac, preferred_element_type=F32)
+            w = w + (scale * jnp.asarray(_SIGNS, F32))[None, :, None, None] * delta[:, None]
+    u = u.astype(F32)
+    y = 0.0
+    for back in range(taps):
+        past = jnp.pad(u, ((0, 0), (0, 0), (back, 0), (0, 0)))[:, :, :t]
+        y = y + jnp.where(reach[back][:, None], past, 0.0) * w[:, :, None, :, taps - 1 - back]
+    return jax.nn.silu(y)
+
+
+def _kda_kernel(cfg: LMConfig) -> bool:
+    """Whether the scan runs as the ``kda_scan`` kernel: on the TPU backend,
+    a head a whole lane tile wide. Elsewhere the chunk arithmetic in XLA."""
+    return jax.default_backend() == "tpu" and cfg.kda_head_dim % 128 == 0
+
+
+def kda(cfg: LMConfig, p, f, scale, x, scan, reach, block_pairs: int) -> tuple:
+    """``kda(norm(x))``, a block of pairs at a time, and the mean of
+    ``exp(g)``. ``scan``: the recurrence over the row (``kda_scan`` or
+    ``kda_scan_chunked`` with the row's documents bound)."""
+    pairs, _, t, _ = x.shape
+    dt = x.dtype
+    h, dk = cfg.kda_num_heads, cfg.kda_head_dim
+    bp = _blocks(pairs, block_pairs)
+    m = 2 * bp
+    split = lambda a: a.reshape((pairs // bp, bp) + a.shape[1:])
+    heads = lambda a: a.reshape(bp, 2, t, h, dk)
+
+    def block(args):
+        xb, fb = args
+        xn = rmsnorm(xb, p["norm"], cfg.rms_norm_eps).astype(dt)
+        q, k, v = (
+            heads(short_conv(linear(xn, p[n], fb[n], scale, dt), p[n + "_conv"], fb[n + "_conv"], scale, reach))
+            for n in ("q", "k", "v")
+        )
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        low = lambda a, b, out: linear(linear(xn, p[a], fb[a], scale, dt), p[b], fb[b], scale, out)
+        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(heads(low("f_a", "f_b", F32) + p["dt_bias"]))
+        beta = jax.nn.sigmoid(linear(xn, p["beta"], fb["beta"], scale, F32))
+        with scope(LM_KDA_SCAN):
+            flat = lambda a, to: a.astype(to).reshape(m, t, h * dk)
+            o = scan(flat(q, dt), flat(k, dt), flat(v, dt), flat(g, F32), beta.reshape(m, t, h))
+        o = rmsnorm(heads(o), p["o_norm"], cfg.rms_norm_eps) * jax.nn.sigmoid(heads(low("g_a", "g_b", F32)))
+        out = linear(o.astype(dt).reshape(bp, 2, t, h * dk), p["o"], fb["o"], scale, dt)
+        return out, jnp.mean(jnp.exp(g))
+
+    out, kept = jax.lax.map(block, (split(x), jax.tree.map(split, f)))
+    return out.reshape(x.shape), jnp.mean(kept)
 
 
 def mlp(p, f, scale, xn, block_pairs: int) -> jax.Array:
@@ -422,11 +586,12 @@ def expert_layer(cfg: LMConfig, p, f, scale, xn, blocks: dict) -> tuple:
 # How the forward pass is cut so that it fits. ``chunk_pairs``: the pairs that
 # go through the whole model together (their tokens are the rows of every base
 # product and of the experts' sort); within a chunk, the pairs a block of
-# attention, of the dense MLP and of the shared MLP takes; the rows of a block
-# of an expert's product.
+# attention, of KDA, of the dense MLP and of the shared MLP takes; the rows of
+# a block of an expert's product.
 DEFAULT_BLOCKS = {
     "chunk_pairs": 4,
     "attn_block_pairs": 1,
+    "kda_block_pairs": 1,
     "dense_block_pairs": 1,
     "shared_block_pairs": 4,
     "expert_block_rows": 512,
@@ -448,17 +613,24 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
     ``imbalance`` (largest held expert's load over the mean), and
     ``attn_blocks``: the key blocks attention's kernel visits for this row's
     documents over those of a dense causal pass (1 where the plain body runs:
-    the whole row is its one block)."""
+    the whole row is its one block); per KDA layer ``kda_retention`` (the mean
+    of ``exp(g)`` over members, tokens, heads and channels: how much of the
+    state a token keeps) and ``kda_boundary_chunks`` (the scan's chunks in
+    which a document starts over its chunks: how often the reset inside a
+    chunk runs)."""
     t = ids.shape[0]
     dt = center["embed"].dtype
     pairs = jax.tree.leaves(factors)[0].shape[0]
     cp = _blocks(pairs, blocks["chunk_pairs"])
     signs = jnp.asarray(_SIGNS, F32)
 
-    half = cfg.qk_rope_head_dim // 2
-    freq = cfg.rope_theta ** (-jnp.arange(half, dtype=F32) / half)
-    angle = pos.astype(F32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if cfg.mla_use_nope:
+        rotate = lambda a: a
+    else:
+        half = cfg.qk_rope_head_dim // 2
+        freq = cfg.rope_theta ** (-jnp.arange(half, dtype=F32) / half)
+        angle = pos.astype(F32)[:, None] * freq[None, :]
+        rotate = functools.partial(_rope, cos=jnp.cos(angle), sin=jnp.sin(angle))
     at = jnp.arange(t)
     flash = _flash_blocks(cfg, t)
     if flash is None:
@@ -470,6 +642,14 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         attend = functools.partial(attend_flash, cfg, doc, (first, last), flash)
         # a query attends itself, so ``last`` is the diagonal's block: a dense causal pass visits 0 .. last
         attn_blocks = jnp.sum(last - first + 1).astype(F32) / jnp.sum(last + 1)
+    boundary_chunks = 0.0
+    if cfg.kda_layers:
+        reach = pos[None, :] >= jnp.arange(cfg.kda_conv_size)[:, None]
+        scan = (functools.partial(kda_scan, interpret=jax.default_backend() != "tpu") if _kda_kernel(cfg)
+                else kda_scan_chunked)
+        scan = functools.partial(scan, doc=doc, heads=cfg.kda_num_heads, chunk=KDA_CHUNK)
+        starts = jnp.pad(pos == 0, (0, -t % KDA_CHUNK)).reshape(-1, KDA_CHUNK)
+        boundary_chunks = jnp.mean(jnp.any(starts, axis=1).astype(F32))
     target = jnp.roll(ids, -1)
     counted = (at + 1 < t) & (jnp.roll(doc, -1) == doc)  # the next token is of this document
     weight = counted.astype(F32) / jnp.maximum(jnp.sum(counted), 1)
@@ -485,11 +665,18 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                     x = x + signs[None, :, None, None] * delta[:, None]
             x = x.astype(dt)
 
-        loads, moved = [], []
-        for p, f in zip(center["layers"], fac["layers"]):
-            with scope(LM_ATTENTION):
-                x = x + attention(cfg, p["attn"], f["attn"], scale, x, attend, cos, sin,
-                                  blocks["attn_block_pairs"])
+        loads, moved, kept = [], [], []
+        for kind, p, f in zip(cfg.kinds, center["layers"], fac["layers"]):
+            if kind == "kda":
+                with scope(LM_KDA):
+                    mixed, retention = kda(cfg, p["kda"], f["kda"], scale, x, scan, reach,
+                                           blocks["kda_block_pairs"])
+                    x = x + mixed
+                kept.append(retention)
+            else:
+                with scope(LM_ATTENTION):
+                    x = x + attention(cfg, p["attn"], f["attn"], scale, x, attend, rotate,
+                                      blocks["attn_block_pairs"])
             with scope(LM_MLP):
                 xn = rmsnorm(x, p["mlp_norm"], cfg.rms_norm_eps).astype(dt)
             if "mlp" in p:
@@ -520,10 +707,11 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
             )[0]
         loads = jnp.stack(loads) if loads else jnp.zeros((0, cfg.n_held), jnp.int32)
         moved = jnp.stack(moved) if moved else jnp.zeros((0,), jnp.int32)
-        return losses, probe, loads, moved
+        kept = jnp.stack(kept) if kept else jnp.zeros((0,), F32)
+        return losses, probe, loads, moved, kept
 
     split = lambda a: a.reshape((pairs // cp, cp) + a.shape[1:])
-    losses, probe, loads, moved = jax.lax.map(chunk, jax.tree.map(split, factors))
+    losses, probe, loads, moved, kept = jax.lax.map(chunk, jax.tree.map(split, factors))
     with scope(LM_ROUTER):
         loads = jnp.sum(loads, axis=0)  # (expert layers, held experts)
         imbalance = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads.astype(F32), axis=-1), 1.0)
@@ -534,4 +722,6 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         "moved": jnp.sum(moved, axis=0).astype(jnp.int32),
         "imbalance": imbalance.astype(F32),
         "attn_blocks": attn_blocks,
+        "kda_retention": jnp.mean(kept, axis=0),
+        "kda_boundary_chunks": jnp.full((cfg.kda_layers,), boundary_chunks, F32),
     }

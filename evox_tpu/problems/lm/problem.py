@@ -47,7 +47,11 @@ class TokenLMState(PyTreeNode):
     ``imbalance``, the largest held expert's load over the mean;
     ``attn_blocks``, the key blocks the attention kernel's loop bounds visited
     over the key blocks of a dense causal pass (how much of the row's
-    attention the documents let it skip; 1 where the plain body ran)."""
+    attention the documents let it skip; 1 where the plain body ran); for
+    each KDA layer ``kda_retention``, the mean of ``exp(g)`` over members,
+    tokens, heads and channels (how much of the state a token keeps), and
+    ``kda_boundary_chunks``, the scan's chunks in which a document starts
+    over its chunks (how often the reset inside a chunk runs)."""
 
     key: jax.Array = field(sharding=P())
     generation: jax.Array = field(sharding=P())
@@ -57,6 +61,8 @@ class TokenLMState(PyTreeNode):
     moved: jax.Array = field(sharding=P())
     imbalance: jax.Array = field(sharding=P())
     attn_blocks: jax.Array = field(sharding=P())
+    kda_retention: jax.Array = field(sharding=P())
+    kda_boundary_chunks: jax.Array = field(sharding=P())
 
 
 class TokenLMProblem(Problem):
@@ -109,6 +115,8 @@ class TokenLMProblem(Problem):
             moved=jnp.zeros((n,), jnp.int32),
             imbalance=jnp.zeros((n,), jnp.float32),
             attn_blocks=jnp.zeros((), jnp.float32),
+            kda_retention=jnp.zeros((self.cfg.kda_layers,), jnp.float32),
+            kda_boundary_chunks=jnp.zeros((self.cfg.kda_layers,), jnp.float32),
         )
 
     def evaluate(self, state: TokenLMState, pop: Any) -> Tuple[jax.Array, TokenLMState]:
@@ -125,5 +133,6 @@ class TokenLMProblem(Problem):
         return losses, state.replace(
             generation=state.generation + 1, losses=losses, probe=out["probe"],
             held=out["held"], moved=out["moved"], imbalance=out["imbalance"],
-            attn_blocks=out["attn_blocks"],
+            attn_blocks=out["attn_blocks"], kda_retention=out["kda_retention"],
+            kda_boundary_chunks=out["kda_boundary_chunks"],
         )

@@ -154,6 +154,17 @@ def _flash_kernel(members=2, t=2048, heads=16, nope=128, rope=64, v=128):
                 i32(t), i32(t // bq), i32(t // bq))
 
 
+def _kda_kernel(members=2, t=2048, heads=32, width=128):
+    """``kda_scan`` at the hybrid language-model cell's shapes: two members a
+    call, 32 heads of 128 keys and values, bfloat16 operands, float32 decay."""
+    from evox_tpu.kernels.kda_scan import kda_scan
+
+    fn = lambda q, k, v, g, beta, doc: kda_scan(q, k, v, g, beta, doc, heads=heads)  # noqa: E731
+    wide = jax.ShapeDtypeStruct((members, t, heads * width), jnp.bfloat16)
+    return fn, (wide, wide, wide, jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+                jax.ShapeDtypeStruct((members, t, heads), jnp.float32), jax.ShapeDtypeStruct((t,), jnp.int32))
+
+
 KERNELS = {
     "fused_mlp_rollout-244x64x64x17-n16384-T100": _walker_kernel,
     "fused_mlp_rollout-genome20945-n16384-T100": _walker_kernel_genome,
@@ -164,6 +175,7 @@ KERNELS = {
     "partial_topk-n4096-k128": _topk_kernel,
     "packed_dominance-n20000-m3": _dominance_kernel,
     "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
+    "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,
 }
 
 

@@ -1,7 +1,9 @@
 """The token language model under low-rank OpenES, at a tiny size on the CPU:
-the program against the plain reference (``benchmark/reference``) on seeded
-weights, the properties the member model has to have, and ``LowRankOpenES``
-against ``OpenES`` on materialised members."""
+the program against the plain references (``benchmark/reference``) on seeded
+weights for both families (``deepseek_v3``: every layer MLA with RoPE;
+``kimi_linear``: KDA and unrotated MLA by the pattern), the properties the
+member model has to have, and ``LowRankOpenES`` against ``OpenES`` on
+materialised members."""
 
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import kimi_linear_48b_a3b_es as ref_kimi
 from benchmark.reference import moonlight_16b_a3b_es as ref
 from evox_tpu import StdWorkflow
 from evox_tpu.algorithms.so.es import LowRankOpenES, OpenES
@@ -32,19 +35,38 @@ TINY = dict(
     vocab_size=32, rms_norm_eps=1e-5, rope_theta=50000, init_std=0.02, rank=1,
     noise_stdev=0.001, learning_rate=0.0005, probe_positions=64,
 )
+# the same cut of the kimi_linear family (tests/benchmark_checks/test_kimi_linear_cell.py enters it): KDA of 2
+# heads x 16 with 4 taps in layers 1, 2, 3, 5 as the pattern counts them, MLA unrotated in layer 4, one shared expert
+TINY_KIMI = dict(
+    model_type="kimi_linear", hidden_size=64, num_attention_heads=2, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, kv_lora_rank=24, intermediate_size=96, moe_intermediate_size=32, num_shared_experts=1,
+    num_experts=2, num_experts_published=8, experts_held=[0, 2], num_experts_per_token=2,
+    routed_scaling_factor=2.446, first_k_dense_replace=1, layers=5, vocab_size=32, rms_norm_eps=1e-5,
+    init_std=0.02, mla_use_nope=True, moe_renormalize=True, moe_router_activation_func="sigmoid",
+    use_grouped_topk=True, num_expert_group=1, topk_group=1,
+    linear_attn_config=dict(kda_layers=[1, 2, 3, 5, 6, 7], full_attn_layers=[4, 8], num_heads=2, head_dim=16,
+                            short_conv_kernel_size=4),
+    rank=1, noise_stdev=0.001, learning_rate=0.0005, probe_positions=64,
+)
+FAMILIES = {"deepseek_v3": (TINY, ref), "kimi_linear": (TINY_KIMI, ref_kimi)}
 TRAFFIC = dict(pop=8, seq_len=48, doc_len_median=12, doc_len_sigma=1.0, doc_len_min=4,
                rows_per_member=1)
-BLOCKS = {"expert_block_rows": 8, "chunk_pairs": 2, "attn_block_pairs": 2}
+BLOCKS = {"expert_block_rows": 8, "chunk_pairs": 2, "attn_block_pairs": 2, "kda_block_pairs": 2}
 SEED = 2**33 + 5
 
 
 @pytest.fixture(params=("plain", "kernel"))
 def body(request, monkeypatch):
-    """Attention's two bodies: the plain one, which the CPU backend takes, and
-    the flash kernel (interpreted here), which the TPU backend takes at
-    shapes it accepts; the choice is steered here, not by an option."""
+    """The two bodies of the token mixers: the plain ones, which the CPU
+    backend takes (attention's scores in HBM, KDA's chunk arithmetic in XLA),
+    and the kernels (``flash_attention``, ``kda_scan``, interpreted here),
+    which the TPU backend takes at shapes they accept; the choice is steered
+    here, not by an option. The scan's chunks are 16 tokens here, so that a
+    row of 48 carries its state across two chunk ends."""
+    monkeypatch.setattr(lm, "KDA_CHUNK", 16)
     if request.param == "kernel":
         monkeypatch.setattr(lm, "_flash_blocks", lambda cfg, t: (8, 8))
+        monkeypatch.setattr(lm, "_kda_kernel", lambda cfg: True)
     return request.param
 
 
@@ -74,33 +96,43 @@ def _snapshots(wf, key, steps=2):
             "losses": np.asarray(state.prob.losses),
             "probe": np.asarray(state.prob.probe),
             "held": np.asarray(state.prob.held),
+            "kda_retention": np.asarray(state.prob.kda_retention),
         })
     return snaps
 
 
+@pytest.mark.parametrize("family", list(FAMILIES))
 @pytest.mark.parametrize("rank,layers,steps", ((1, 5, 2), (2, 3, 1)))
-def test_program_agrees_with_the_reference_in_float32(rank, layers, steps, body):
-    """Logits, losses, routing and centre, through ``StdWorkflow.run``, a
-    second generation from the first's centre."""
-    config = dict(TINY, rank=rank, layers=layers)
+def test_program_agrees_with_the_reference_in_float32(rank, layers, steps, family, body):
+    """Logits, losses, routing and centre (for ``kimi_linear`` the hybrid
+    pattern at five layers, and what the KDA layers keep of their state),
+    through ``StdWorkflow.run``, a second generation from the first's centre."""
+    tiny, reference = FAMILIES[family]
+    config = dict(tiny, rank=rank, layers=layers)
     wf, key = _workflow(config, rank=rank)
     snaps = _snapshots(wf, key, steps)
     generations = list(range(1, steps + 1))
-    want = ref.follow(config, TRAFFIC, SEED, generations, program=snaps)
+    want = reference.follow(config, TRAFFIC, SEED, generations, program=snaps)
     for got, w in zip(snaps, want):
         np.testing.assert_allclose(got["losses"], w["losses"], rtol=0, atol=2e-6)
         np.testing.assert_allclose(got["probe"], w["probe"], rtol=0, atol=2e-5)
         np.testing.assert_array_equal(got["held"], w["held"])
         assert w["center_step"] > 0 and w["center_diff"] < 1e-5 * w["center_step"]
-    numbers = ref.numbers(config, snaps, want)
+    numbers = reference.numbers(config, snaps, want)
     assert all(numbers[f"step{k}_generation_off"] == 0 for k in generations)
     assert max(numbers[f"step{k}_logit_err"] for k in generations) < 1e-5
+    if family == "kimi_linear":
+        assert snaps[0]["kda_retention"].shape == ({5: 4, 3: 3}[layers],)  # layer 4 of the pattern is MLA
+        assert max(numbers[f"step{k}_retention_err"] for k in generations) < 1e-5
+        assert np.all((0 < snaps[0]["kda_retention"]) & (snaps[0]["kda_retention"] < 1))
 
 
-def test_bfloat16_operands_stay_near_the_reference(body):
-    wf, key = _workflow(compute_dtype=jnp.bfloat16)
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_bfloat16_operands_stay_near_the_reference(family, body):
+    tiny, reference = FAMILIES[family]
+    wf, key = _workflow(tiny, compute_dtype=jnp.bfloat16)
     snaps = _snapshots(wf, key, steps=1)
-    numbers = ref.numbers(TINY, snaps, ref.follow(TINY, TRAFFIC, SEED, [1], program=snaps))
+    numbers = reference.numbers(tiny, snaps, reference.follow(tiny, TRAFFIC, SEED, [1], program=snaps))
     assert numbers["step1_logit_err"] < 0.02 and numbers["step1_center_err"] < 1e-5
 
 
@@ -109,10 +141,13 @@ def _forward(cfg, center, ids, doc, pos, pairs=2):
     return lm.forward(cfg, center, factors, jnp.float32(1e-3), ids, doc, pos, 8, {**lm.DEFAULT_BLOCKS, **BLOCKS})
 
 
-def test_a_token_sees_only_its_document_and_its_past(body):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_token_sees_only_its_document_and_its_past(family, body):
     """The prefix property: the logits at a position do not change when a
-    later token, or a token of an earlier document, changes."""
-    cfg = LMConfig.from_dict(TINY)
+    later token, or a token of an earlier document, changes. In a KDA layer
+    that is the convolution's taps and the scan's state, both reset where
+    document 1 starts, inside the scan's first chunk of 16."""
+    cfg = LMConfig.from_dict(FAMILIES[family][0])
     center = init_params(cfg, jax.random.PRNGKey(1))
     t = 24
     ids = jax.random.randint(jax.random.PRNGKey(2), (t,), 0, cfg.vocab_size)
@@ -147,12 +182,26 @@ def test_attn_blocks_is_the_visited_share_of_a_dense_causal_pass(body):
     assert 0.0 < float(state.prob.attn_blocks) <= 1.0
 
 
-def test_the_shares_of_an_expert_layer_add_up():
-    """The outputs of the expert layer for ``experts_held`` 0-1, 2-3, 4-5 and
-    6-7, the shared MLP counted once, sum to the uncut reference's layer."""
-    whole = dict(TINY, n_routed_experts=8, experts_held=[0, 8])
-    full = ref._init(whole, jax.random.PRNGKey(7))["layers"][1]
-    pairs, t, d = 2, 16, TINY["hidden_size"]
+# family, the key that counts the experts held, the router's width, a share, the choices a token
+SHARES = {
+    "8_experts_top_2": ("deepseek_v3", "n_routed_experts", 8, 2, 2),
+    "256_experts_top_8": ("kimi_linear", "num_experts", 256, 64, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(SHARES))
+def test_the_shares_of_an_expert_layer_add_up(case):
+    """The outputs of the expert layer for every share of the experts (0-1,
+    2-3, 4-5 and 6-7 of 8; four shares of 64 of a 256-wide router at top 8),
+    the shared MLP counted once, sum to the uncut reference's layer."""
+    family, held_key, width, share, top = SHARES[case]
+    tiny, reference = FAMILIES[family]
+    top_key = "num_experts_per_tok" if family == "deepseek_v3" else "num_experts_per_token"
+    tiny = {**tiny, held_key + "_published": width, top_key: top}
+    whole = {**tiny, held_key: width, "experts_held": [0, width]}
+    full = reference._init(whole, jax.random.PRNGKey(7))["layers"][1]
+    full.pop("attn", None), full.pop("kda", None)
+    pairs, t, d = 2, 16, tiny["hidden_size"]
     xn = jax.random.normal(jax.random.PRNGKey(8), (pairs, 2, t, d))
     no_noise = lambda tree: jax.tree.map(
         lambda v: (jnp.zeros((pairs,) + v.shape[:-2] + (v.shape[-2], 1)),
@@ -160,9 +209,9 @@ def test_the_shares_of_an_expert_layer_add_up():
         tree,
     )
     total = 0.0
-    for lo in (0, 2, 4, 6):
-        cfg = LMConfig.from_dict(dict(TINY, experts_held=[lo, lo + 2]))
-        p = dict(full, experts=jax.tree.map(lambda v: v[lo : lo + 2], full["experts"]))
+    for lo in range(0, width, share):
+        cfg = LMConfig.from_dict({**tiny, held_key: share, "experts_held": [lo, lo + share]})
+        p = dict(full, experts=jax.tree.map(lambda v: v[lo : lo + share], full["experts"]))
         shared, routed, loads, _ = lm.expert_layer(
             cfg, p, no_noise(p), jnp.float32(0.0), xn, {**lm.DEFAULT_BLOCKS, **BLOCKS}
         )
@@ -170,14 +219,14 @@ def test_the_shares_of_an_expert_layer_add_up():
         assert int(jnp.sum(loads)) > 0
     total = total + shared
 
-    # the uncut layer, plainly: every chosen expert of all eight
+    # the uncut layer, plainly: every chosen expert of all of them
     x = xn.reshape(-1, d)
     score = jax.nn.sigmoid(x @ full["router"])
-    _, idx = jax.lax.top_k(score + full["router_bias"], 2)
+    _, idx = jax.lax.top_k(score + full["router_bias"], top)
     chosen = jnp.take_along_axis(score, idx, axis=-1)
     weight = TINY["routed_scaling_factor"] * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     want = ref._swiglu(x, full["shared"])
-    for e in range(8):
+    for e in range(width):
         mine = jnp.sum(jnp.where(idx == e, weight, 0), axis=-1)
         want = want + mine[:, None] * ref._swiglu(x, jax.tree.map(lambda v: v[e], full["experts"]))
     np.testing.assert_allclose(total.reshape(-1, d), want, rtol=0, atol=1e-5)
@@ -371,27 +420,108 @@ def test_problem_refuses_a_dense_population():
         wf.problem.evaluate(wf.problem.init(key), jnp.zeros((8, 4)))
 
 
-def test_configuration_keeps_the_published_widths():
+# configuration: the catalog row's numbers, the keys cut, their values here, what ``published`` says,
+# the parameters held, and words the deployment has to say
+PUBLISHED = {
+    "moonlight_16b_a3b_es": (
+        {
+            "hidden_size": 2048, "intermediate_size": 11264, "moe_intermediate_size": 1408,
+            "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+            "num_attention_heads": 16, "num_key_value_heads": 16, "num_experts_per_tok": 6,
+            "n_shared_experts": 2, "num_hidden_layers": 27, "first_k_dense_replace": 1,
+            "routed_scaling_factor": 2.446, "rope_theta": 50000, "rms_norm_eps": 1e-05,
+            "q_lora_rank": None, "n_group": 1, "topk_group": 1, "max_position_embeddings": 8192,
+        },
+        ["layers", "n_routed_experts", "vocab_size"], (5, 16, 20480),
+        {"num_hidden_layers": 27, "n_routed_experts": 64, "vocab_size": 163840, "parameters": "16B, 3B active"},
+        845_308_672, ("shared by 4 chips", "the vocabulary by 8", "pipeline stages"),
+    ),
+    "kimi_linear_48b_a3b_es": (
+        {
+            "first_k_dense_replace": 1, "head_dim": 72, "hidden_act": "silu", "hidden_size": 2304,
+            "intermediate_size": 9216, "kv_lora_rank": 512,
+            "linear_attn_config": {
+                "full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 128,
+                "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26],
+                "num_heads": 32, "short_conv_kernel_size": 4,
+            },
+            "mla_use_nope": True, "model_max_length": 1048576, "model_type": "kimi_linear",
+            "moe_intermediate_size": 1024, "moe_layer_freq": 1, "moe_renormalize": True,
+            "moe_router_activation_func": "sigmoid", "num_attention_heads": 32, "num_expert_group": 1,
+            "num_experts_per_token": 8, "num_hidden_layers": 27, "num_key_value_heads": 32,
+            "num_nextn_predict_layers": 0, "num_shared_experts": 1, "q_lora_rank": None,
+            "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rms_norm_eps": 1e-05, "rope_scaling": None,
+            "rope_theta": 10000, "routed_scaling_factor": 2.446, "tie_word_embeddings": False,
+            "topk_group": 1, "use_grouped_topk": True, "v_head_dim": 128,
+        },
+        ["layers", "num_experts", "vocab_size"], (5, 16, 20480),
+        {"num_hidden_layers": 27, "num_experts": 256, "vocab_size": 163840, "parameters": "48B, 3B active"},
+        828_926_848, ("shared by 16 chips", "the vocabulary by 8", "pipeline stages"),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", list(PUBLISHED))
+def test_configuration_keeps_the_published_widths(name):
     """The configuration file holds every number of the catalog row's config
-    at its published value, but for the keys it lists under ``reduced``."""
-    config = json.loads((ROOT / "benchmark/configs/moonlight_16b_a3b_es.json").read_text())
-    published = {
-        "hidden_size": 2048, "intermediate_size": 11264, "moe_intermediate_size": 1408,
-        "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
-        "num_attention_heads": 16, "num_key_value_heads": 16, "num_experts_per_tok": 6,
-        "n_shared_experts": 2, "num_hidden_layers": 27, "first_k_dense_replace": 1,
-        "routed_scaling_factor": 2.446, "rope_theta": 50000, "rms_norm_eps": 1e-05,
-        "q_lora_rank": None, "n_group": 1, "topk_group": 1, "max_position_embeddings": 8192,
-    }
+    at its published value, but for the keys it lists under ``reduced``; it
+    says what deployment the cut stands for and how many parameters are held."""
+    published, reduced, cut, said, held, words = PUBLISHED[name]
+    config = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
     assert {k: config[k] for k in published} == published
-    assert config["reduced"] == ["layers", "n_routed_experts", "vocab_size"]
-    assert (config["layers"], config["n_routed_experts"], config["vocab_size"]) == (5, 16, 20480)
-    assert config["published"] == {"num_hidden_layers": 27, "n_routed_experts": 64,
-                                   "vocab_size": 163840, "parameters": "16B, 3B active"}
+    assert config["reduced"] == reduced and tuple(config[k] for k in reduced) == cut
+    assert config["published"] == said and all(w in config["deployment"] for w in words)
     cfg = LMConfig.from_dict(config)
     shapes = jax.tree.leaves(lm.param_shapes(cfg), is_leaf=lm._is_shape)
-    assert sum(int(np.prod(s)) for s in shapes) == config["parameters_held"] == 845_308_672
+    assert sum(int(np.prod(s)) for s in shapes) == config["parameters_held"] == held
     assert set(config["limits"]) == set(config["limits_why"])
+    assert (cfg.n_routed_experts, cfg.n_held) == (said[reduced[1]], 16)  # the router keeps its width
+
+
+def test_the_hybrid_configuration_is_read_by_its_own_keys():
+    config = json.loads((ROOT / "benchmark/configs/kimi_linear_48b_a3b_es.json").read_text())
+    cfg = LMConfig.from_dict(config)
+    assert cfg.kinds == ("kda", "kda", "kda", "mla", "kda") and cfg.kda_layers == 4
+    assert (cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_size) == (32, 128, 4)
+    assert cfg.mla_use_nope and cfg.rope_theta is None  # the file's theta is not used: nothing rotates
+    assert (cfg.num_experts_per_tok, cfg.n_shared_experts, cfg.first_k_dense_replace) == (8, 1, 1)
+    moon = LMConfig.from_dict(json.loads((ROOT / "benchmark/configs/moonlight_16b_a3b_es.json").read_text()))
+    assert moon.kinds == ("mla",) * 5 and moon.kda_layers == 0 and moon.rope_theta == 50000 and not moon.mla_use_nope
+    # what ``route`` does is checked, not assumed; a rotating family states its theta; a family is one of two
+    for key, other in (("moe_renormalize", False), ("moe_router_activation_func", "softmax"),
+                       ("use_grouped_topk", False), ("num_expert_group", 8), ("topk_group", 4)):
+        with pytest.raises(ValueError, match=key):
+            LMConfig.from_dict({**config, key: other})
+    with pytest.raises(KeyError, match="rope_theta"):
+        LMConfig.from_dict({k: v for k, v in TINY.items() if k != "rope_theta"})
+    with pytest.raises(ValueError, match="model_type"):
+        LMConfig.from_dict({**config, "model_type": "llama"})
+    with pytest.raises(KeyError):  # a layer held that the pattern does not name
+        LMConfig.from_dict({**config, "linear_attn_config": {**config["linear_attn_config"], "kda_layers": [1, 2]}})
+
+
+def test_the_convolutions_lowrank_perturbation_is_the_materialised_members():
+    """``short_conv`` with factors equals, member by member, the convolution
+    with that member's dense ``w + sign * scale * A B^T``; a tap before the
+    document's start reads zero."""
+    pairs, t, channels, taps, scale = 3, 12, 8, 4, 0.3
+    keys = jax.random.split(jax.random.PRNGKey(21), 4)
+    u = jax.random.normal(keys[0], (pairs, 2, t, channels))
+    w = jax.random.normal(keys[1], (channels, taps))
+    fac = (jax.random.normal(keys[2], (pairs, channels, 1)), jax.random.normal(keys[3], (pairs, taps, 1)))
+    pos = jnp.asarray([0, 1, 2, 3, 4, 0, 1, 0, 1, 2, 3, 4])
+    reach = pos[None, :] >= jnp.arange(taps)[:, None]
+    got = lm.short_conv(u, w, fac, jnp.float32(scale), reach)
+    for p in range(pairs):
+        for i, sign in enumerate((1.0, -1.0)):
+            dense = w + sign * scale * fac[0][p] @ fac[1][p].T
+            want = np.zeros((t, channels))
+            for at in range(t):
+                for j in range(taps):
+                    back = taps - 1 - j
+                    if pos[at] >= back:
+                        want[at] += np.asarray(dense[:, j] * u[p, i, at - back])
+            np.testing.assert_allclose(got[p, i], jax.nn.silu(want), rtol=0, atol=1e-5)
 
 
 def test_work_counts_of_the_cell():
@@ -408,3 +538,30 @@ def test_work_counts_of_the_cell():
     whole = dict(traffic, doc_len_median=1e9, doc_len_min=2048)
     assert work_lm.expected_attended(whole) == pytest.approx(1024.5)
     assert parts["total"] == pytest.approx(sum(v for k, v in parts.items() if k != "total"))
+
+
+def test_work_counts_of_the_hybrid_cell():
+    """711 MFLOP a token to three digits, by part (ISSUE 32's reckoning)."""
+    from benchmark.lib import work_lm_hybrid as work
+
+    config = json.loads((ROOT / "benchmark/configs/kimi_linear_48b_a3b_es.json").read_text())
+    traffic = json.loads((ROOT / "benchmark/traffic/closed_pop64_seq2048_g1.json").read_text())
+    parts = work.lm_flops_per_token(config, traffic)
+    assert work.layer_kinds(config) == ["kda", "kda", "kda", "mla", "kda"]
+    assert work.held_choices_per_token(config) == 0.5
+    projections = 2 * (4 * 2304 * 4096 + 2 * (2304 * 128 + 128 * 4096) + 2304 * 32)
+    assert work.kda_scan_flops_per_token(config) == 7 * 32 * 128 * 128
+    assert parts["kda"] == 4 * (projections + 2 * 3 * 4096 * 4 + 7 * 32 * 128 * 128)
+    assert parts["kda_scan"] == 4 * 7 * 32 * 128 * 128
+    assert parts["dense_mlp"] == 2 * 3 * 2304 * 9216 and parts["head"] == 2 * 2304 * 20480
+    assert parts["shared"] == 4 * 2 * (3 * 2304 * 1024 + 2304 * 256)
+    assert parts["experts"] == 4 * 0.5 * 2 * 3 * 2304 * 1024
+    rounded = {k: round(v / 1e6, 1) for k, v in parts.items()}
+    assert rounded == {"kda": 330.8, "attention": 69.0, "dense_mlp": 127.4, "shared": 61.3, "experts": 28.3,
+                       "head": 94.4, "total": 711.2, "kda_scan": 14.7}
+    assert parts["total"] == pytest.approx(sum(v for k, v in parts.items() if k not in ("total", "kda_scan")))
+    assert work.lm_flops_per_eval(config, traffic) * 64 == pytest.approx(9.32e13, rel=2e-3)
+    # the scan's floor is bandwidth: 49,280 B a token and layer, 31.5 ms a generation at 819 GB/s
+    assert work.kda_scan_bytes_per_token(config) == 32 * (4 * 128 * 2 + 4 * 128 + 4) == 49_280
+    peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    assert work.kda_scan_least_seconds(config, traffic, 64, peak) == pytest.approx(0.03155, rel=1e-3)
