@@ -21,10 +21,16 @@ fallback stays the default.
   fallback is the same chunk arithmetic in XLA (``kda_scan_chunked``), its
   oracle the token-by-token recurrence (``kda_scan_reference``); the caller
   chooses by platform and head width.
+- ``kda_conv``: what lies between KDA's projections and that scan, for one of
+  ``q``, ``k``, ``v``: the short convolution, SiLU and the head's L2 norm as
+  one pass that reads the projection once and writes the scan's operand once;
+  its fallback and reference is ``problems/lm/model.py`` ``short_conv`` with
+  the norm lines of ``kda``; the caller chooses by the same rule.
 """
 
 from .dominance import packed_dominance, packed_dominance_reference
 from .flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from .kda_conv import kda_conv
 from .kda_scan import kda_scan, kda_scan_chunked, kda_scan_reference
 from .topk import default_use_kernel, partial_topk, partial_topk_reference
 from .rollout import (
@@ -48,6 +54,7 @@ __all__ = [
     "flash_attention",
     "flash_block_bounds",
     "flash_block_sizes",
+    "kda_conv",
     "kda_scan",
     "kda_scan_chunked",
     "kda_scan_reference",

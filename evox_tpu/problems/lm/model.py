@@ -45,7 +45,10 @@ head.
   exactly (``kernels/kda_scan.py`` says how): on the TPU backend, where a
   head is a whole lane tile wide, by the ``kda_scan`` kernel (the state stays
   in VMEM across the row's chunks), elsewhere by the same chunk arithmetic in
-  plain XLA; ``forward`` chooses by what it can observe, no option.
+  plain XLA; ``forward`` chooses by what it can observe, no option. By the
+  same rule the way from each projection to the scan's operand (convolution,
+  SiLU, L2 norm, the rounding) is one pass of the ``kda_conv`` kernel on the
+  TPU backend and ``short_conv`` with the norms as float32 passes elsewhere.
 - The dense layers' MLP and the shared experts (one MLP of width
   ``n_shared_experts * moe_intermediate_size``): ``down(silu(gate x) * up x)``.
 - Router: ``s = sigmoid(x Wr)`` in float32 over all ``n_routed_experts``; the
@@ -108,6 +111,7 @@ from ...core.instrument import (
     scope,
 )
 from ...kernels.flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from ...kernels.kda_conv import kda_conv
 from ...kernels.kda_scan import KDA_CHUNK, kda_scan, kda_scan_chunked
 
 F32 = jnp.float32
@@ -404,6 +408,18 @@ def attention(cfg: LMConfig, p, f, scale, x, attend, rotate, block_pairs: int) -
     return jax.lax.map(block, (split(x), jax.tree.map(split, f))).reshape(x.shape)
 
 
+def member_taps(w, fac, scale) -> jax.Array:
+    """Each member's own ``w + sign * scale * A_p B_p^T`` of a convolution's
+    ``(channels, taps)``: ``(pairs, 2, channels, taps)`` float32, or ``(1, 1,
+    channels, taps)`` where there are no factors."""
+    w = w.astype(F32)[None, None]
+    if fac is None:
+        return w
+    with scope(LM_LOWRANK):
+        delta = jnp.einsum("pcr,pjr->pcj", *fac, preferred_element_type=F32)
+        return w + (scale * jnp.asarray(_SIGNS, F32))[None, :, None, None] * delta[:, None]
+
+
 def short_conv(u, w, fac, scale, reach) -> jax.Array:
     """KDA's short convolution and SiLU on one stream. ``u`` ``(pairs, 2, T,
     channels)``; ``w`` ``(channels, taps)``, depthwise, its last tap on the
@@ -411,11 +427,7 @@ def short_conv(u, w, fac, scale, reach) -> jax.Array:
     lies in the token's document. Each member's own ``w + sign * scale * A_p
     B_p^T`` is formed: the leaf is no ``x @ W``. Float32."""
     t, taps = u.shape[2], w.shape[1]
-    w = w.astype(F32)[None, None]
-    if fac is not None:
-        with scope(LM_LOWRANK):
-            delta = jnp.einsum("pcr,pjr->pcj", *fac, preferred_element_type=F32)
-            w = w + (scale * jnp.asarray(_SIGNS, F32))[None, :, None, None] * delta[:, None]
+    w = member_taps(w, fac, scale)
     u = u.astype(F32)
     y = 0.0
     for back in range(taps):
@@ -425,15 +437,19 @@ def short_conv(u, w, fac, scale, reach) -> jax.Array:
 
 
 def _kda_kernel(cfg: LMConfig) -> bool:
-    """Whether the scan runs as the ``kda_scan`` kernel: on the TPU backend,
-    a head a whole lane tile wide. Elsewhere the chunk arithmetic in XLA."""
+    """Whether KDA runs its kernels (``kda_conv`` between the projections and
+    the scan, ``kda_scan``): on the TPU backend, a head a whole lane tile
+    wide. Elsewhere ``short_conv`` and the chunk arithmetic in XLA."""
     return jax.default_backend() == "tpu" and cfg.kda_head_dim % 128 == 0
 
 
-def kda(cfg: LMConfig, p, f, scale, x, scan, reach, block_pairs: int) -> tuple:
+def kda(cfg: LMConfig, p, f, scale, x, scan, conv, reach, block_pairs: int) -> tuple:
     """``kda(norm(x))``, a block of pairs at a time, and the mean of
     ``exp(g)``. ``scan``: the recurrence over the row (``kda_scan`` or
-    ``kda_scan_chunked`` with the row's documents bound)."""
+    ``kda_scan_chunked`` with the row's documents bound). ``conv``: ``kda_conv``
+    with the row's positions bound, which takes a projection as ``linear``
+    leaves it to the scan's operand in one pass, or ``None``: ``short_conv``
+    over ``reach`` and the L2 norms in float32, a pass each."""
     pairs, _, t, _ = x.shape
     dt = x.dtype
     h, dk = cfg.kda_num_heads, cfg.kda_head_dim
@@ -445,12 +461,18 @@ def kda(cfg: LMConfig, p, f, scale, x, scan, reach, block_pairs: int) -> tuple:
     def block(args):
         xb, fb = args
         xn = rmsnorm(xb, p["norm"], cfg.rms_norm_eps).astype(dt)
-        q, k, v = (
-            heads(short_conv(linear(xn, p[n], fb[n], scale, dt), p[n + "_conv"], fb[n + "_conv"], scale, reach))
-            for n in ("q", "k", "v")
-        )
-        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
-        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        streams = [(linear(xn, p[n], fb[n], scale, dt), p[n + "_conv"], fb[n + "_conv"]) for n in ("q", "k", "v")]
+        if conv is None:
+            q, k, v = (heads(short_conv(u, w, fac, scale, reach)) for u, w, fac in streams)
+            q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        else:
+            # the kernel's layouts: the stream as ``linear`` leaves it, a member's taps a row each
+            own = lambda w, fac: jnp.broadcast_to(member_taps(w, fac, scale), (bp, 2) + w.shape).reshape((m,) + w.shape)
+            q, k, v = (
+                conv(u.reshape(m, t, h * dk), own(w, fac).transpose(0, 2, 1), width=dk, normalise=norm)
+                for (u, w, fac), norm in zip(streams, ("l2_scaled", "l2", None))
+            )
         low = lambda a, b, out: linear(linear(xn, p[a], fb[a], scale, dt), p[b], fb[b], scale, out)
         g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(heads(low("f_a", "f_b", F32) + p["dt_bias"]))
         beta = jax.nn.sigmoid(linear(xn, p["beta"], fb["beta"], scale, F32))
@@ -648,6 +670,8 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         scan = (functools.partial(kda_scan, interpret=jax.default_backend() != "tpu") if _kda_kernel(cfg)
                 else kda_scan_chunked)
         scan = functools.partial(scan, doc=doc, heads=cfg.kda_num_heads, chunk=KDA_CHUNK)
+        conv = (functools.partial(kda_conv, pos=pos, interpret=jax.default_backend() != "tpu") if _kda_kernel(cfg)
+                else None)
         starts = jnp.pad(pos == 0, (0, -t % KDA_CHUNK)).reshape(-1, KDA_CHUNK)
         boundary_chunks = jnp.mean(jnp.any(starts, axis=1).astype(F32))
     target = jnp.roll(ids, -1)
@@ -669,7 +693,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         for kind, p, f in zip(cfg.kinds, center["layers"], fac["layers"]):
             if kind == "kda":
                 with scope(LM_KDA):
-                    mixed, retention = kda(cfg, p["kda"], f["kda"], scale, x, scan, reach,
+                    mixed, retention = kda(cfg, p["kda"], f["kda"], scale, x, scan, conv, reach,
                                            blocks["kda_block_pairs"])
                     x = x + mixed
                 kept.append(retention)

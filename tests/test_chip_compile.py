@@ -165,6 +165,16 @@ def _kda_kernel(members=2, t=2048, heads=32, width=128):
                 jax.ShapeDtypeStruct((members, t, heads), jnp.float32), jax.ShapeDtypeStruct((t,), jnp.int32))
 
 
+def _kda_conv_kernel(normalise, members=2, t=2048, heads=32, width=128):
+    """``kda_conv`` at the hybrid language-model cell's shapes: a projection of
+    two members as ``linear`` leaves it, bfloat16, each member's four taps."""
+    from evox_tpu.kernels.kda_conv import kda_conv
+
+    fn = lambda u, w, pos: kda_conv(u, w, pos, width=width, normalise=normalise)  # noqa: E731
+    return fn, (jax.ShapeDtypeStruct((members, t, heads * width), jnp.bfloat16),
+                jax.ShapeDtypeStruct((members, 4, heads * width), jnp.float32), jax.ShapeDtypeStruct((t,), jnp.int32))
+
+
 KERNELS = {
     "fused_mlp_rollout-244x64x64x17-n16384-T100": _walker_kernel,
     "fused_mlp_rollout-genome20945-n16384-T100": _walker_kernel_genome,
@@ -176,6 +186,9 @@ KERNELS = {
     "packed_dominance-n20000-m3": _dominance_kernel,
     "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
     "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,
+    "kda_conv-m2-h32-t2048-w128-none": functools.partial(_kda_conv_kernel, None),
+    "kda_conv-m2-h32-t2048-w128-l2": functools.partial(_kda_conv_kernel, "l2"),
+    "kda_conv-m2-h32-t2048-w128-l2_scaled": functools.partial(_kda_conv_kernel, "l2_scaled"),
 }
 
 

@@ -163,6 +163,61 @@ def test_a_token_sees_only_its_document_and_its_past(family, body):
     assert not np.array_equal(base, probe(changed(12)))
 
 
+def _row(t=48, seed=2, vocab=32):
+    ids = jax.random.randint(jax.random.PRNGKey(seed), (t,), 0, vocab)
+    lengths = [3, 1, 2, 19, 23]  # starts inside a tap's reach of each other, and inside the scan's chunks of 16
+    doc = jnp.repeat(jnp.arange(len(lengths)), jnp.asarray(lengths), total_repeat_length=t)
+    pos = jnp.concatenate([jnp.arange(n) for n in lengths])
+    return ids, doc, pos
+
+
+@pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+def test_forward_with_kdas_kernels_is_forward_with_the_plain_bodies(dtype, monkeypatch):
+    """The hybrid cut through ``forward`` twice: as the CPU backend takes it
+    (``short_conv``, the norms, the chunk arithmetic in XLA) and as the TPU
+    backend does (``kda_conv`` and ``kda_scan``, interpreted here): the
+    losses, the probe and what the KDA layers keep of their state agree within
+    what the reference is held to."""
+    monkeypatch.setattr(lm, "KDA_CHUNK", 16)
+    cfg = LMConfig.from_dict(TINY_KIMI)
+    center = init_params(cfg, jax.random.PRNGKey(1))
+    if dtype == "bfloat16":
+        center = jax.tree.map(lambda v: v.astype(jnp.bfloat16) if v.ndim >= 2 else v, center)
+    ids, doc, pos = _row()
+    run = lambda: jax.jit(lambda ids: _forward(cfg, center, ids, doc, pos))(ids)
+    plain = run()
+    seen = []
+    conv = lm.kda_conv
+    monkeypatch.setattr(lm, "kda_conv", lambda *a, **k: (seen.append(k["normalise"]), conv(*a, **k))[1])
+    monkeypatch.setattr(lm, "_kda_kernel", lambda cfg: True)
+    kernels = run()
+    assert seen == ["l2_scaled", "l2", None] * cfg.kda_layers  # q, k, v of every KDA layer, once a traced block
+    tol = {"float32": (2e-6, 2e-5, 1e-6), "bfloat16": (0.02, 0.05, 1e-3)}[dtype]
+    np.testing.assert_allclose(kernels["losses"], plain["losses"], rtol=0, atol=tol[0])
+    np.testing.assert_allclose(kernels["probe"], plain["probe"], rtol=0, atol=tol[1])
+    np.testing.assert_allclose(kernels["kda_retention"], plain["kda_retention"], rtol=0, atol=tol[2])
+    np.testing.assert_array_equal(kernels["held"], plain["held"])
+
+
+def test_a_model_without_kda_layers_never_reaches_kdas_kernels(monkeypatch):
+    """Moonlight's path: a configuration whose pattern has no KDA layer lowers
+    to the same text whether KDA's kernels would be chosen or not, and with
+    ``kda_conv`` and ``kda_scan`` taken away."""
+    cfg = LMConfig.from_dict(TINY)
+    center = init_params(cfg, jax.random.PRNGKey(1))
+    ids, doc, pos = _row()
+    lower = lambda: jax.jit(lambda ids: _forward(cfg, center, ids, doc, pos)).lower(ids).as_text()
+    base = lower()
+
+    def gone(*a, **k):
+        raise AssertionError("a KDA kernel was reached")
+
+    monkeypatch.setattr(lm, "_kda_kernel", lambda cfg: True)
+    monkeypatch.setattr(lm, "kda_conv", gone)
+    monkeypatch.setattr(lm, "kda_scan", gone)
+    assert lower() == base
+
+
 def test_attn_blocks_is_the_visited_share_of_a_dense_causal_pass(body):
     """The counter against a count from the dense mask: key blocks that hold
     a key some query of the block attends, over the blocks at or under the
@@ -500,21 +555,33 @@ def test_the_hybrid_configuration_is_read_by_its_own_keys():
         LMConfig.from_dict({**config, "linear_attn_config": {**config["linear_attn_config"], "kda_layers": [1, 2]}})
 
 
-def test_the_convolutions_lowrank_perturbation_is_the_materialised_members():
+@pytest.mark.parametrize("factors", ("with_factors", "without_factors"))
+@pytest.mark.parametrize("via", ("short_conv", "kda_conv"))
+def test_the_convolutions_lowrank_perturbation_is_the_materialised_members(via, factors):
     """``short_conv`` with factors equals, member by member, the convolution
     with that member's dense ``w + sign * scale * A B^T``; a tap before the
-    document's start reads zero."""
+    document's start reads zero. So does the ``kda_conv`` kernel (interpreted)
+    on the taps ``member_taps`` forms for it: the two members of a pair
+    differ, and without factors every member has the centre's taps."""
     pairs, t, channels, taps, scale = 3, 12, 8, 4, 0.3
     keys = jax.random.split(jax.random.PRNGKey(21), 4)
     u = jax.random.normal(keys[0], (pairs, 2, t, channels))
     w = jax.random.normal(keys[1], (channels, taps))
     fac = (jax.random.normal(keys[2], (pairs, channels, 1)), jax.random.normal(keys[3], (pairs, taps, 1)))
+    if factors == "without_factors":
+        fac, scale = None, 0.0
     pos = jnp.asarray([0, 1, 2, 3, 4, 0, 1, 0, 1, 2, 3, 4])
     reach = pos[None, :] >= jnp.arange(taps)[:, None]
-    got = lm.short_conv(u, w, fac, jnp.float32(scale), reach)
+    if via == "short_conv":
+        got = lm.short_conv(u, w, fac, jnp.float32(scale), reach)
+    else:
+        own = jnp.broadcast_to(lm.member_taps(w, fac, jnp.float32(scale)), (pairs, 2, channels, taps))
+        got = lm.kda_conv(u.reshape(pairs * 2, t, channels), own.reshape(pairs * 2, channels, taps).transpose(0, 2, 1),
+                          pos, width=channels, interpret=True).reshape(u.shape)
+        assert (fac is None) == bool(np.array_equal(own[:, 0], own[:, 1]))  # the two signs' taps differ
     for p in range(pairs):
         for i, sign in enumerate((1.0, -1.0)):
-            dense = w + sign * scale * fac[0][p] @ fac[1][p].T
+            dense = w + (sign * scale * fac[0][p] @ fac[1][p].T if fac else 0.0)
             want = np.zeros((t, channels))
             for at in range(t):
                 for j in range(taps):
