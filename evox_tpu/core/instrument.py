@@ -113,7 +113,8 @@ CAST = "cast"  # ask (low-rank ES): the centre's matrices cast for the forward p
 # evaluate (the token language model, problems/lm): the parts of its forward pass
 LM_FORWARD = "lm/forward"  # the whole pass: what no part below names (the batch, the loop over chunks of pairs)
 LM_EMBED = "lm/embed"  # the rows gathered
-LM_ATTENTION = "lm/attention"  # the MLA layers: norms, projections, RoPE, scores, softmax, output
+LM_ATTENTION = "lm/attention"  # the attention layers (MLA, or grouped-query with QK norm): norms, projections, RoPE, scores, softmax, output
+LM_CONV = "lm/conv"  # the gated short convolution layers: norm, input projection, gates, taps, output projection
 LM_KDA = "lm/kda"  # the KDA layers: norm, projections, convolutions and L2 norms (the kda_conv kernel on the chip), gates, output norm and gate, output
 LM_KDA_SCAN = "lm/kda_scan"  # inside lm/kda: the delta rule's recurrence over the row, kernel or XLA body
 LM_MLP = "lm/mlp"  # the dense layer's MLP and the shared experts'

@@ -16,6 +16,11 @@ fallback stays the default.
   MLA heads (keys 192 wide, values 128) over a packed row of documents; its
   fallback and reference is ``problems/lm/model.py`` ``attend_plain``, and
   the caller chooses by platform and shape (``flash_block_sizes``).
+- ``gqa_flash_attention``: the same for grouped-query heads half a lane tile
+  wide (64): a grid cell takes a pair of key-value heads, one tile of ``k``
+  and of ``v`` fetched once for the eight query heads that read them; its
+  fallback and reference is ``attend_gqa_plain``; the caller chooses by
+  platform and shape (``gqa_block_sizes``).
 - ``kda_scan``: Kimi Delta Attention's gated delta rule over a packed row,
   chunkwise and exact, the state in VMEM across the row's chunks; its
   fallback is the same chunk arithmetic in XLA (``kda_scan_chunked``), its
@@ -30,6 +35,7 @@ fallback stays the default.
 
 from .dominance import packed_dominance, packed_dominance_reference
 from .flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from .gqa_flash_attention import gqa_block_sizes, gqa_flash_attention
 from .kda_conv import kda_conv
 from .kda_scan import kda_scan, kda_scan_chunked, kda_scan_reference
 from .topk import default_use_kernel, partial_topk, partial_topk_reference
@@ -54,6 +60,8 @@ __all__ = [
     "flash_attention",
     "flash_block_bounds",
     "flash_block_sizes",
+    "gqa_block_sizes",
+    "gqa_flash_attention",
     "kda_conv",
     "kda_scan",
     "kda_scan_chunked",

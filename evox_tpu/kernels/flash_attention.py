@@ -69,12 +69,19 @@ _LANES = 128
 _BLOCKS = (512, 256, 128)  # the largest that divides the row: the sweep above
 
 
+def row_block(t: int) -> Optional[int]:
+    """The block, of queries and of keys, for a row of ``t`` tokens: the
+    largest of ``_BLOCKS`` that divides it, or ``None``. Shared with
+    ``gqa_flash_attention``, whose sweep on the chip chose the same."""
+    return next((b for b in _BLOCKS if t % b == 0), None)
+
+
 def flash_block_sizes(t: int, nope: int, v: int) -> Optional[Tuple[int, int]]:
     """``(block_q, block_k)`` for a row of ``t`` tokens, or ``None`` where the
     compiled kernel does not take the shapes: the head's ``k_nope`` and ``v``
     are picked out of ``kv`` by a block index, so both are one lane tile
     wide; ``t`` divides into blocks of a multiple of it."""
-    block = next((b for b in _BLOCKS if t % b == 0), None)
+    block = row_block(t)
     return None if nope != _LANES or v != _LANES or block is None else (block, block)
 
 
@@ -94,6 +101,36 @@ def flash_block_bounds(doc: jax.Array, block_q: int, block_k: int) -> Tuple[jax.
     return first.astype(jnp.int32), last.astype(jnp.int32)
 
 
+def queries_ahead(i, block_q: int, block_k: int) -> jax.Array:
+    """``(block_k, block_q)``: how far query ``col`` of query block ``i`` lies
+    ahead of row ``row`` of a key block that starts at 0. Key ``k0 + row <=``
+    query ``i * block_q + col`` is ``k0 <= ahead``."""
+    shape = (block_k, block_q)
+    return (i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+
+
+def fold_key_block(s, keep, scale: float, v, m_ref, l_ref, acc_ref, at=..., rows=None) -> None:
+    """One step of the online softmax, shared by the attention kernels: a key
+    block's raw scores ``s`` ``(keys, queries)`` scaled, masked by ``keep`` and
+    folded with the block's values ``v`` ``(keys, width)`` into the running
+    maximum, the running sum and the ``(width, queries)`` accumulator that lie
+    at ``at`` of their float32 scratch; ``rows`` picks the accumulator's rows
+    out of ``v^T p`` where ``v`` is wider than the head."""
+    s = jnp.where(keep, s * scale, jnp.finfo(jnp.float32).min)
+    # a query with no key in this block reads exp(0) = 1 down its column;
+    # its own document's first block, which comes later, has a finite
+    # maximum, and alpha = exp(finfo.min - maximum) = 0 wipes that out
+    m_prev = m_ref[at]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)
+    l_ref[at] = alpha * l_ref[at] + jnp.sum(p, axis=0, keepdims=True)
+    pv = jax.lax.dot_general(v, p.astype(v.dtype), (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_ref[at] = alpha * acc_ref[at] + (pv if rows is None else pv[rows])
+    m_ref[at] = m_next
+
+
 def _flash_kernel(first_ref, last_ref, dq_ref, dk_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_q: int, block_k: int):
     """One member, one head, one block of queries. Scores are held ``(keys,
@@ -103,35 +140,18 @@ def _flash_kernel(first_ref, last_ref, dq_ref, dk_ref, qn_ref, qr_ref, kn_ref, k
     i = pl.program_id(2)
     q = jnp.concatenate([qn_ref[0], qr_ref[0, 0]], axis=1)
     dq = dq_ref[0]  # (1, block_q)
-    # key k0 + row <= query i * block_q + col, as k0 <= ahead
-    shape = (block_k, block_q)
-    ahead = (i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-             - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    ahead = queries_ahead(i, block_q, block_k)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     contract_last = (((1,), (1,)), ((), ()))
-    contract_first = (((0,), (0,)), ((), ()))
 
     def key_block(j, carry):
         k0 = pl.multiple_of(j * block_k, block_k)
         k = jnp.concatenate([kn_ref[0, pl.ds(k0, block_k), :], kr_ref[0, pl.ds(k0, block_k), :]], axis=1)
         s = jax.lax.dot_general(k, q, contract_last, preferred_element_type=jnp.float32)  # (keys, queries)
         keep = (ahead >= k0) & (dk_ref[pl.ds(k0, block_k), :] == dq)
-        s = jnp.where(keep, s * scale, jnp.finfo(jnp.float32).min)
-        # a query with no key in this block reads exp(0) = 1 down its column;
-        # its own document's first block, which comes later, has a finite
-        # maximum, and alpha = exp(finfo.min - maximum) = 0 wipes that out
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
-        v = v_ref[0, pl.ds(k0, block_k), :]
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            v, p.astype(v.dtype), contract_first, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_next
+        fold_key_block(s, keep, scale, v_ref[0, pl.ds(k0, block_k), :], m_ref, l_ref, acc_ref)
         return carry
 
     jax.lax.fori_loop(first_ref[i], last_ref[i] + 1, key_block, 0)

@@ -1,10 +1,12 @@
 """The member model of :class:`~evox_tpu.problems.lm.TokenLMProblem`: a
-decoder-only language model whose layers mix tokens by latent attention (MLA)
-or by Kimi Delta Attention (KDA, a gated delta-rule linear attention), each
+decoder-only language model whose layers mix tokens by latent attention
+(MLA), by Kimi Delta Attention (KDA, a gated delta-rule linear attention), by
+a gated short convolution or by grouped-query attention with a QK norm, each
 layer of the kind the configuration's pattern gives it, over a mixture of
-experts (two families: ``deepseek_v3``, every layer MLA with RoPE, and
-``kimi_linear``, KDA and unrotated MLA), evaluated for every member of a
-low-rank population at once.
+experts (``deepseek_v3``: every layer MLA with RoPE; ``kimi_linear``: KDA and
+unrotated MLA; ``lfm2_moe``: gated short convolutions and grouped-query
+attention, no shared expert), evaluated for every member of a low-rank
+population at once.
 
 The equations (each departure from the published modelling code is listed in
 the benchmark configuration's ``assumed``). Pre-norm residual blocks, ``h = x
@@ -49,12 +51,36 @@ head.
   same rule the way from each projection to the scan's operand (convolution,
   SiLU, L2 norm, the rounding) is one pass of the ``kda_conv`` kernel on the
   TPU backend and ``short_conv`` with the norms as float32 passes elsewhere.
+- Gated short convolution (``lfm2_moe``'s ``conv`` layers), per token, on the
+  normed ``xn``: ``[B, C, u] = xn W_in`` (``W_in`` ``(hidden, 3 hidden)``, a
+  third each in that order); ``z = B * u``; ``c_t = sum_j w[:, j] * z_{t -
+  (taps - 1) + j}``, depthwise and causal over ``conv_L_cache`` taps, the last
+  on the token itself, no bias, a tap that reaches before the token's
+  document began reading zero; ``(C * c) W_out``. No activation anywhere in
+  it. ``w`` ``(hidden, taps)`` is a leaf of two axes, perturbed a member at a
+  time as KDA's convolutions are (``member_taps``). Plain XLA on every
+  backend.
+- Grouped-query attention (``lfm2_moe``'s ``full_attention`` layers): ``q = xn
+  Wq`` as ``num_attention_heads`` heads, ``k = xn Wk`` and ``v = xn Wv`` as
+  ``num_key_value_heads``, of ``head_dim``; ``q`` and ``k`` through an RMS
+  norm over each head (gains ``q_norm``, ``k_norm``, one a dimension, shared
+  by the heads); RoPE over the whole head (the two halves paired, positions
+  counted from the start of the token's document) on both; query head ``h``
+  reads key-value head ``h // (heads / kv heads)``; scores ``q.k /
+  sqrt(head_dim)``, causal and within a document, softmax in float32; the
+  heads' outputs through ``Wo``. No bias. Two bodies, chosen as MLA's are: on
+  the TPU backend, at heads of 64 in pairs of key-value heads and a row that
+  divides into blocks of 128, ``kernels.gqa_flash_attention`` (a pair of
+  key-value heads is fetched once for the query heads that read it);
+  elsewhere ``attend_gqa_plain``.
 - The dense layers' MLP and the shared experts (one MLP of width
-  ``n_shared_experts * moe_intermediate_size``): ``down(silu(gate x) * up x)``.
+  ``n_shared_experts * moe_intermediate_size``; a family with none has no such
+  MLP): ``down(silu(gate x) * up x)``.
 - Router: ``s = sigmoid(x Wr)`` in float32 over all ``n_routed_experts``; the
   ``num_experts_per_tok`` largest of ``s + b`` (``b`` the correction bias);
-  weights ``routed_scaling_factor * s_e / sum of the chosen s``. The layer's
-  output is the shared MLP plus the weighted sum over the chosen experts
+  weights ``routed_scaling_factor * s_e / sum of the chosen s`` (``lfm2_moe``:
+  ``/ (sum + 1e-6)``, ``router_eps``). The layer's
+  output is the shared MLP (where there is one) plus the weighted sum over the chosen experts
   **that are held here** (``experts_held``, a range): the layer routes over
   all the experts and computes its own experts' part of the result; what the
   absent experts would add is left out and no token is dropped. On one chip
@@ -76,7 +102,11 @@ head.
   convolutions, the L2 norms, ``g`` and its running sums, ``beta``, the
   chunk's triangular solve, the carried state and the output norm and gate
   are float32; ``q``, ``k``, ``v`` and every operand of the scan's products
-  are in the operands' dtype.
+  are in the operands' dtype. In the gated convolution the gates' products,
+  the taps' sum and ``C * c`` are float32 from the projection's rounded
+  values, rounded once more as the operand of ``W_out``. In grouped-query
+  attention the QK norms and RoPE are float32, ``q`` and ``k`` rounded once
+  after them; the rest as in MLA.
 
 Members: the population's two halves are the two signs of ``pairs``
 perturbations (``core/lowrank.py``). Activations are laid out ``(pairs, 2,
@@ -99,6 +129,7 @@ import jax.numpy as jnp
 
 from ...core.instrument import (
     LM_ATTENTION,
+    LM_CONV,
     LM_EMBED,
     LM_EXPERTS,
     LM_FORWARD,
@@ -111,6 +142,7 @@ from ...core.instrument import (
     scope,
 )
 from ...kernels.flash_attention import flash_attention, flash_block_bounds, flash_block_sizes
+from ...kernels.gqa_flash_attention import gqa_block_sizes, gqa_flash_attention
 from ...kernels.kda_conv import kda_conv
 from ...kernels.kda_scan import KDA_CHUNK, kda_scan, kda_scan_chunked
 
@@ -124,9 +156,15 @@ class LMConfig:
     ``[lo, hi)`` of experts this chip holds; ``vocab_size`` the rows of the
     vocabulary held here; ``layers`` the depth held here, its first
     ``first_k_dense_replace`` layers dense. ``layer_kinds``: how each layer
-    held mixes tokens, ``"mla"`` or ``"kda"`` (empty: every layer MLA).
-    ``mla_use_nope``: MLA does not rotate (``rope_theta`` is then unused).
-    ``kda_num_heads``, ``kda_head_dim``, ``kda_conv_size``: KDA's sizes."""
+    held mixes tokens, ``"mla"``, ``"kda"``, ``"conv"`` (gated short
+    convolution) or ``"gqa"`` (grouped-query attention with QK norm) (empty:
+    every layer MLA). ``mla_use_nope``: MLA does not rotate (``rope_theta`` is
+    then unused). ``kda_num_heads``, ``kda_head_dim``, ``kda_conv_size``: KDA's
+    sizes. ``num_key_value_heads``, ``head_dim``: the grouped-query layers'
+    (their query heads are ``num_attention_heads``); ``conv_size``: the gated
+    convolution's taps. ``n_shared_experts`` 0: an expert layer is its routed
+    part alone. ``router_eps``: added to the sum of the chosen scores before
+    the weights are divided by it."""
 
     hidden_size: int
     num_attention_heads: int
@@ -152,21 +190,30 @@ class LMConfig:
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_conv_size: int = 0
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    conv_size: int = 0
+    router_eps: float = 0.0
 
     @classmethod
     def from_dict(cls, config: dict) -> "LMConfig":
         """From a configuration file's keys: the published ``config.json``'s
-        names of either family, told apart by ``model_type``, beside ``layers``
+        names of a family, told apart by ``model_type``, beside ``layers``
         (the depth held) and ``experts_held``. ``deepseek_v3``:
         ``n_routed_experts`` the experts held beside
         ``n_routed_experts_published``. ``kimi_linear``: ``num_experts`` the
         experts held beside ``num_experts_published``, and
         ``linear_attn_config``, whose layer numbers count from 1 and of which
-        the first ``layers`` are held."""
+        the first ``layers`` are held. ``lfm2_moe``: ``num_experts`` likewise,
+        and ``layers_held``, the range ``[lo, hi)`` of ``layer_types`` (which
+        counts from 0) held here: ``layers`` of them, those under
+        ``num_dense_layers`` dense, the expert layers among them of the two
+        kinds in the published proportion."""
         family = config.get("model_type", "deepseek_v3")
         if family not in _FAMILY_KEYS:
             raise ValueError(f"model_type {family!r} is not one of {sorted(_FAMILY_KEYS)}")
-        own = {"experts_held", "rope_theta", "layer_kinds", "mla_use_nope"}
+        own = {"experts_held", "rope_theta", "layer_kinds", "mla_use_nope", "num_key_value_heads", "head_dim",
+               "conv_size", "router_eps"}
         names = {f.name for f in dataclasses.fields(cls) if not f.name.startswith("kda_")} - own
         keys = {name: _FAMILY_KEYS[family].get(name, name) for name in names}
         given = {name: config[key] for name, key in keys.items() if key in config}
@@ -177,6 +224,8 @@ class LMConfig:
             )
         given["n_routed_experts"] = int(config[keys["n_routed_experts"] + "_published"])
         layers = int(config["layers"])
+        if family == "lfm2_moe":
+            return cls(experts_held=(lo, hi), **{**_LFM2_ABSENT, **given}, **_lfm2_layers(config, layers))
         kinds, kda = ("mla",) * layers, {}
         if family == "kimi_linear":
             for key, want in _KIMI_ROUTER.items():  # what ``route`` does
@@ -212,15 +261,55 @@ class LMConfig:
     def kda_layers(self) -> int:
         return sum(kind == "kda" for kind in self.kinds)
 
+    @property
+    def conv_layers(self) -> int:
+        return sum(kind == "conv" for kind in self.kinds)
+
 
 # a field of LMConfig under another family's published name
 _FAMILY_KEYS = {
     "deepseek_v3": {},
     "kimi_linear": {"n_routed_experts": "num_experts", "num_experts_per_tok": "num_experts_per_token",
                     "n_shared_experts": "num_shared_experts"},
+    "lfm2_moe": {"n_routed_experts": "num_experts", "rms_norm_eps": "norm_eps"},
 }
 _KIMI_ROUTER = {"moe_renormalize": True, "moe_router_activation_func": "sigmoid", "use_grouped_topk": True,
                 "num_expert_group": 1, "topk_group": 1}
+# what ``route`` (with ``router_eps``) and ``gated_conv`` do
+_LFM2_STATED = {"norm_topk_prob": True, "use_expert_bias": True, "conv_bias": False}
+# MLA's sizes and the shared expert, which the family does not have
+_LFM2_ABSENT = dict(qk_nope_head_dim=0, qk_rope_head_dim=0, v_head_dim=0, kv_lora_rank=0, n_shared_experts=0)
+_LFM2_KINDS = {"conv": "conv", "full_attention": "gqa"}
+
+
+def _lfm2_layers(config: dict, layers: int) -> dict:
+    """The ``lfm2_moe`` family's own fields: the kinds of the layers held
+    (``layers_held`` of ``layer_types``), how many of them are dense, the
+    mixers' sizes, RoPE's base and the router's ``1e-6``."""
+    for key, want in _LFM2_STATED.items():
+        if config[key] != want:
+            raise ValueError(f"lfm2_moe: {key}={config[key]!r}, the model here does {want!r}")
+    rope = config["rope_parameters"]
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"lfm2_moe: rope_type {rope['rope_type']!r}, the model here rotates plainly")
+    types, dense = list(config["layer_types"]), int(config["num_dense_layers"])
+    first, last = (int(v) for v in config["layers_held"])
+    if not 0 <= first < last <= len(types) or last - first != layers:
+        raise ValueError(f"lfm2_moe: layers_held {first}..{last} is not layers={layers} of the {len(types)} layer_types")
+    kinds = tuple(_LFM2_KINDS[kind] for kind in types[first:last])
+    sparse = kinds[max(dense - first, 0):]
+    if types.count("full_attention") * len(sparse) != sparse.count("gqa") * len(types):
+        raise ValueError(
+            f"lfm2_moe: the expert layers held {sparse} break the pattern: {types.count('full_attention')} of "
+            f"{len(types)} published layers are full_attention"
+        )
+    heads = int(config["num_attention_heads"])
+    return dict(
+        layer_kinds=kinds, first_k_dense_replace=len(kinds) - len(sparse), rope_theta=float(rope["rope_theta"]),
+        num_key_value_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config.get("head_dim") or int(config["hidden_size"]) // heads),
+        conv_size=int(config["conv_L_cache"]), router_eps=1e-6,
+    )
 
 
 def param_shapes(cfg: LMConfig) -> dict:
@@ -247,18 +336,32 @@ def param_shapes(cfg: LMConfig) -> dict:
         "o": (kh * kd, d),
     }
 
+    conv = {  # ``in_proj``: the gates B and C and the stream u, a third each in that order
+        "norm": (d,), "in_proj": (d, 3 * d), "taps": (d, cfg.conv_size), "out_proj": (d, d),
+    }
+    gh, gd = cfg.num_key_value_heads, cfg.head_dim
+    gqa = {
+        "norm": (d,),
+        "q": (d, h * gd), "k": (d, gh * gd), "v": (d, gh * gd),
+        "q_norm": (gd,), "k_norm": (gd,),  # one gain a dimension of the head, shared by the heads
+        "o": (h * gd, d),
+    }
+    mixers = {"mla": ("attn", attn), "kda": ("kda", kda), "conv": ("conv", conv), "gqa": ("gqa", gqa)}
+
     def mlp(width, stack=()):
         return {"gate": stack + (d, width), "up": stack + (d, width), "down": stack + (width, d)}
 
     layers = []
     for l, kind in enumerate(cfg.kinds):
-        layer = {"mlp_norm": (d,), **({"kda": dict(kda)} if kind == "kda" else {"attn": dict(attn)})}
+        name, mixer = mixers[kind]
+        layer = {"mlp_norm": (d,), name: dict(mixer)}
         if l < cfg.first_k_dense_replace:
             layer["mlp"] = mlp(cfg.intermediate_size)
         else:
             layer["router"] = (d, cfg.n_routed_experts)
             layer["router_bias"] = (cfg.n_routed_experts,)
-            layer["shared"] = mlp(cfg.n_shared_experts * cfg.moe_intermediate_size)
+            if cfg.n_shared_experts:
+                layer["shared"] = mlp(cfg.n_shared_experts * cfg.moe_intermediate_size)
             layer["experts"] = mlp(cfg.moe_intermediate_size, (cfg.n_held,))
         layers.append(layer)
     return {
@@ -379,6 +482,68 @@ def _flash_blocks(cfg: LMConfig, t: int) -> Optional[tuple]:
     return flash_block_sizes(t, cfg.qk_nope_head_dim, cfg.v_head_dim)
 
 
+def attend_gqa_plain(cfg: LMConfig, mask: jax.Array, q, k, v) -> jax.Array:
+    """The grouped-query layers' plain body: the scores of all the block's
+    members and heads, ``(m, kv heads, group, T, T)`` float32, through HBM.
+    ``q`` ``(m, T, heads * head_dim)``, ``k`` and ``v`` ``(m, T, kv heads *
+    head_dim)``, ``q`` and ``k`` normed and rotated; query head ``h`` reads
+    key-value head ``h // group``. Returns ``(m, T, heads * head_dim)``."""
+    m, t, _ = q.shape
+    gh, gd = cfg.num_key_value_heads, cfg.head_dim
+    k, v = k.reshape(m, t, gh, gd), v.reshape(m, t, gh, gd)
+    s = jnp.einsum("mqgjd,mkgd->mgjqk", q.reshape(m, t, gh, -1, gd), k, preferred_element_type=F32)
+    s = jnp.where(mask, s * (1.0 / math.sqrt(gd)), jnp.finfo(F32).min)
+    w = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("mgjqk,mkgd->mqgjd", w.astype(q.dtype), v, preferred_element_type=F32)
+    return o.astype(q.dtype).reshape(q.shape)
+
+
+def attend_gqa_flash(cfg: LMConfig, doc: jax.Array, bounds: tuple, block_sizes: tuple, q, k, v) -> jax.Array:
+    """The same, by ``kernels.gqa_flash_attention``."""
+    return gqa_flash_attention(
+        q, k, v, doc, bounds, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
+        scale=1.0 / math.sqrt(cfg.head_dim), block_q=block_sizes[0], block_k=block_sizes[1],
+        interpret=jax.default_backend() != "tpu",
+    )
+
+
+def _gqa_blocks(cfg: LMConfig, t: int) -> Optional[tuple]:
+    """The grouped-query kernel's ``(block_q, block_k)`` where it runs: on the
+    TPU backend, at head counts and widths and a row length it takes.
+    Elsewhere ``None``: the plain body."""
+    if jax.default_backend() != "tpu":
+        return None
+    return gqa_block_sizes(t, cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
+
+
+def gqa_attention(cfg: LMConfig, p, f, scale, x, attend, rotate, block_pairs: int) -> jax.Array:
+    """``gqa(norm(x))``, a block of pairs at a time: ``q`` as
+    ``num_attention_heads`` heads and ``k``, ``v`` as ``num_key_value_heads``
+    of ``head_dim``; ``q`` and ``k`` through an RMS norm over each head (one
+    gain a dimension, shared by the heads) and RoPE over the whole head, in
+    float32, rounded once; ``attend``: ``attend_gqa_plain`` or
+    ``attend_gqa_flash`` with the row's mask or bounds bound."""
+    pairs, _, t, _ = x.shape
+    dt = x.dtype
+    h, gh, gd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    bp = _blocks(pairs, block_pairs)
+    m = 2 * bp
+    split = lambda a: a.reshape((pairs // bp, bp) + a.shape[1:])
+
+    def block(args):
+        xb, fb = args
+        xn = rmsnorm(xb, p["norm"], cfg.rms_norm_eps).astype(dt)
+        q = linear(xn, p["q"], fb["q"], scale, dt).reshape(m, t, h, gd)
+        k = linear(xn, p["k"], fb["k"], scale, dt).reshape(m, t, gh, gd)
+        v = linear(xn, p["v"], fb["v"], scale, dt).reshape(m, t, gh * gd)
+        q = rotate(rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)).astype(dt).reshape(m, t, h * gd)
+        k = rotate(rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)).astype(dt).reshape(m, t, gh * gd)
+        o = attend(q, k, v)
+        return linear(o.reshape(bp, 2, t, h * gd), p["o"], fb["o"], scale, dt)
+
+    return jax.lax.map(block, (split(x), jax.tree.map(split, f))).reshape(x.shape)
+
+
 def attention(cfg: LMConfig, p, f, scale, x, attend, rotate, block_pairs: int) -> jax.Array:
     """``attn(norm(x))``, a block of pairs at a time (the plain body's scores
     of all members at once would be ``pop * heads * T * T`` floats).
@@ -426,14 +591,50 @@ def short_conv(u, w, fac, scale, reach) -> jax.Array:
     token itself; ``reach`` ``(taps, T)``: whether the tap ``s`` tokens back
     lies in the token's document. Each member's own ``w + sign * scale * A_p
     B_p^T`` is formed: the leaf is no ``x @ W``. Float32."""
-    t, taps = u.shape[2], w.shape[1]
     w = member_taps(w, fac, scale)
-    u = u.astype(F32)
+    return jax.nn.silu(_taps(u.astype(F32), w, reach))
+
+
+def _taps(u, w, reach) -> jax.Array:
+    """``sum_back w[..., taps - 1 - back] * u_{t - back}`` over the row, a tap
+    outside ``reach`` reading zero. ``u`` ``(pairs, 2, T, channels)`` float32;
+    ``w`` each member's taps as ``member_taps`` forms them."""
+    t, taps = u.shape[2], w.shape[-1]
+    # one expand each, then whole slices of a leading axis: an index and a new axis a tap (``reach[back][:, None]``)
+    # lower to reshape pairs that MLIR merges under a fused location, which XLA names after the scope it lies in,
+    # so that a named scope would change more of the program than its metadata
+    keep = reach[:, :, None]  # (taps, T, 1)
+    w = jnp.moveaxis(w, -1, 0)[:, :, :, None]  # (taps, pairs or 1, 2 or 1, 1, channels)
     y = 0.0
     for back in range(taps):
         past = jnp.pad(u, ((0, 0), (0, 0), (back, 0), (0, 0)))[:, :, :t]
-        y = y + jnp.where(reach[back][:, None], past, 0.0) * w[:, :, None, :, taps - 1 - back]
-    return jax.nn.silu(y)
+        y = y + jnp.where(keep[back], past, 0.0) * w[taps - 1 - back]
+    return y
+
+
+def gated_conv(cfg: LMConfig, p, f, scale, x, reach) -> tuple:
+    """``conv(norm(x))`` of the chunk's pairs, and four sums of squares, ``(2,
+    2)``: of the mixer's output and of its normed input, each over every token
+    and over the tokens a tap of which reaches before their document began
+    (``conv_gain`` is the roots of the two ratios). ``[B, C, u] = xn W_in``, a
+    third each; ``c`` the short depthwise causal convolution of ``B * u`` (each
+    member's own taps, none reaching before the token's document: ``reach``);
+    ``(C * c) W_out``. No activation. The gates' products, the taps' sum and
+    ``C * c`` are float32 from the projection's rounded values, rounded once
+    more as the operand of ``W_out``."""
+    d, dt = x.shape[-1], x.dtype
+    first = ~reach[-1]  # the tokens whose farthest tap the mask zeroes
+
+    def squares(a):
+        by_token = jnp.sum(jnp.square(a.astype(F32)), axis=(0, 1, 3))
+        return jnp.stack([jnp.sum(by_token), jnp.sum(jnp.where(first, by_token, 0.0))])
+
+    xn = rmsnorm(x, p["norm"], cfg.rms_norm_eps).astype(dt)
+    bcu = linear(xn, p["in_proj"], f["in_proj"], scale, dt).astype(F32)
+    b, c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
+    y = c * _taps(b * u, member_taps(p["taps"], f["taps"], scale), reach)
+    out = linear(y.astype(dt), p["out_proj"], f["out_proj"], scale, dt)
+    return out, jnp.stack([squares(out), squares(xn)])
 
 
 def _kda_kernel(cfg: LMConfig) -> bool:
@@ -510,7 +711,10 @@ def route(cfg: LMConfig, p, f, scale, xn) -> tuple:
     s = jax.nn.sigmoid(z)
     _, idx = jax.lax.top_k(s + p["router_bias"], cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    w = cfg.routed_scaling_factor * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    if cfg.router_eps:  # a family's own renormalisation
+        total = total + cfg.router_eps
+    w = cfg.routed_scaling_factor * chosen / total
     return idx, w
 
 
@@ -596,9 +800,12 @@ def held_experts(cfg: LMConfig, p, f, scale, xn, idx, w, block_rows: int) -> tup
 
 def expert_layer(cfg: LMConfig, p, f, scale, xn, blocks: dict) -> tuple:
     """``(shared MLP, held experts' part, loads, moved)`` of an expert layer
-    for the normed ``xn``; the layer's output is the sum of the first two."""
-    with scope(LM_MLP):
-        shared = mlp(p["shared"], f["shared"], scale, xn, blocks["shared_block_pairs"])
+    for the normed ``xn``; the layer's output is the sum of the first two, or
+    the second alone where the model has no shared expert (``None``)."""
+    shared = None
+    if "shared" in p:
+        with scope(LM_MLP):
+            shared = mlp(p["shared"], f["shared"], scale, xn, blocks["shared_block_pairs"])
     with scope(LM_ROUTER):
         idx, w = route(cfg, p, f, scale, xn)
         routed, loads, moved = held_experts(cfg, p, f, scale, xn, idx, w, blocks["expert_block_rows"])
@@ -608,8 +815,9 @@ def expert_layer(cfg: LMConfig, p, f, scale, xn, blocks: dict) -> tuple:
 # How the forward pass is cut so that it fits. ``chunk_pairs``: the pairs that
 # go through the whole model together (their tokens are the rows of every base
 # product and of the experts' sort); within a chunk, the pairs a block of
-# attention, of KDA, of the dense MLP and of the shared MLP takes; the rows of
-# a block of an expert's product.
+# attention (of either kind), of KDA, of the dense MLP and of the shared MLP
+# takes (the gated convolution takes the chunk whole); the rows of a block of
+# an expert's product.
 DEFAULT_BLOCKS = {
     "chunk_pairs": 4,
     "attn_block_pairs": 1,
@@ -639,31 +847,37 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
     of ``exp(g)`` over members, tokens, heads and channels: how much of the
     state a token keeps) and ``kda_boundary_chunks`` (the scan's chunks in
     which a document starts over its chunks: how often the reset inside a
-    chunk runs)."""
+    chunk runs); per gated convolution layer ``conv_gain`` (the root mean
+    square of the mixer's output over that of its normed input, over members,
+    tokens and channels, and the same over the first ``taps - 1`` tokens of
+    the documents alone, where the mask of the taps acts: ``(layers, 2)``)."""
     t = ids.shape[0]
     dt = center["embed"].dtype
     pairs = jax.tree.leaves(factors)[0].shape[0]
     cp = _blocks(pairs, blocks["chunk_pairs"])
     signs = jnp.asarray(_SIGNS, F32)
+    grouped = "gqa" in cfg.kinds  # a family's attention is of one kind: MLA, or grouped-query heads
 
     if cfg.mla_use_nope:
         rotate = lambda a: a
     else:
-        half = cfg.qk_rope_head_dim // 2
+        half = (cfg.head_dim if grouped else cfg.qk_rope_head_dim) // 2
         freq = cfg.rope_theta ** (-jnp.arange(half, dtype=F32) / half)
         angle = pos.astype(F32)[:, None] * freq[None, :]
         rotate = functools.partial(_rope, cos=jnp.cos(angle), sin=jnp.sin(angle))
     at = jnp.arange(t)
-    flash = _flash_blocks(cfg, t)
+    flash = _gqa_blocks(cfg, t) if grouped else _flash_blocks(cfg, t)
     if flash is None:
         mask = (at[:, None] >= at[None, :]) & (doc[:, None] == doc[None, :])
-        attend = functools.partial(attend_plain, cfg, mask)
+        attend = functools.partial(attend_gqa_plain if grouped else attend_plain, cfg, mask)
         attn_blocks = jnp.ones((), F32)  # the whole row is one block
     else:
         first, last = flash_block_bounds(doc, *flash)
-        attend = functools.partial(attend_flash, cfg, doc, (first, last), flash)
+        attend = functools.partial(attend_gqa_flash if grouped else attend_flash, cfg, doc, (first, last), flash)
         # a query attends itself, so ``last`` is the diagonal's block: a dense causal pass visits 0 .. last
         attn_blocks = jnp.sum(last - first + 1).astype(F32) / jnp.sum(last + 1)
+    if cfg.conv_layers:
+        conv_reach = pos[None, :] >= jnp.arange(cfg.conv_size)[:, None]
     boundary_chunks = 0.0
     if cfg.kda_layers:
         reach = pos[None, :] >= jnp.arange(cfg.kda_conv_size)[:, None]
@@ -689,7 +903,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                     x = x + signs[None, :, None, None] * delta[:, None]
             x = x.astype(dt)
 
-        loads, moved, kept = [], [], []
+        loads, moved, kept, squares = [], [], [], []
         for kind, p, f in zip(cfg.kinds, center["layers"], fac["layers"]):
             if kind == "kda":
                 with scope(LM_KDA):
@@ -697,6 +911,15 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
                                            blocks["kda_block_pairs"])
                     x = x + mixed
                 kept.append(retention)
+            elif kind == "conv":
+                with scope(LM_CONV):
+                    mixed, square = gated_conv(cfg, p["conv"], f["conv"], scale, x, conv_reach)
+                    x = x + mixed
+                squares.append(square)
+            elif kind == "gqa":
+                with scope(LM_ATTENTION):
+                    x = x + gqa_attention(cfg, p["gqa"], f["gqa"], scale, x, attend, rotate,
+                                          blocks["attn_block_pairs"])
             else:
                 with scope(LM_ATTENTION):
                     x = x + attention(cfg, p["attn"], f["attn"], scale, x, attend, rotate,
@@ -709,7 +932,7 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
             else:
                 shared, routed, load, rows = expert_layer(cfg, p, f, scale, xn, blocks)
                 with scope(LM_ROUTER):
-                    x = x + shared + routed
+                    x = x + routed if shared is None else x + shared + routed
                 loads.append(load)
                 moved.append(rows)
 
@@ -732,10 +955,12 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         loads = jnp.stack(loads) if loads else jnp.zeros((0, cfg.n_held), jnp.int32)
         moved = jnp.stack(moved) if moved else jnp.zeros((0,), jnp.int32)
         kept = jnp.stack(kept) if kept else jnp.zeros((0,), F32)
-        return losses, probe, loads, moved, kept
+        return losses, probe, loads, moved, kept, squares
 
     split = lambda a: a.reshape((pairs // cp, cp) + a.shape[1:])
-    losses, probe, loads, moved, kept = jax.lax.map(chunk, jax.tree.map(split, factors))
+    losses, probe, loads, moved, kept, squares = jax.lax.map(chunk, jax.tree.map(split, factors))
+    # a layer's sums of squares over the chunks: (output, input) x (every token, the documents' first)
+    squares = [jnp.sum(square, axis=0) for square in squares]
     with scope(LM_ROUTER):
         loads = jnp.sum(loads, axis=0)  # (expert layers, held experts)
         imbalance = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads.astype(F32), axis=-1), 1.0)
@@ -748,4 +973,6 @@ def forward(cfg: LMConfig, center, factors, scale, ids, doc, pos, n_probe: int,
         "attn_blocks": attn_blocks,
         "kda_retention": jnp.mean(kept, axis=0),
         "kda_boundary_chunks": jnp.full((cfg.kda_layers,), boundary_chunks, F32),
+        "conv_gain": (jnp.stack([jnp.sqrt(out / within) for out, within in squares]) if squares
+                      else jnp.zeros((0, 2), F32)),
     }

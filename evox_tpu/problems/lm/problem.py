@@ -51,7 +51,11 @@ class TokenLMState(PyTreeNode):
     each KDA layer ``kda_retention``, the mean of ``exp(g)`` over members,
     tokens, heads and channels (how much of the state a token keeps), and
     ``kda_boundary_chunks``, the scan's chunks in which a document starts
-    over its chunks (how often the reset inside a chunk runs)."""
+    over its chunks (how often the reset inside a chunk runs); for each gated
+    convolution layer ``conv_gain``, the root mean square of the mixer's
+    output over that of its normed input, over members, tokens and channels,
+    and the same over the documents' first ``taps - 1`` tokens alone, where the
+    taps' document mask acts (two numbers a layer)."""
 
     key: jax.Array = field(sharding=P())
     generation: jax.Array = field(sharding=P())
@@ -63,6 +67,7 @@ class TokenLMState(PyTreeNode):
     attn_blocks: jax.Array = field(sharding=P())
     kda_retention: jax.Array = field(sharding=P())
     kda_boundary_chunks: jax.Array = field(sharding=P())
+    conv_gain: jax.Array = field(sharding=P())
 
 
 class TokenLMProblem(Problem):
@@ -117,6 +122,7 @@ class TokenLMProblem(Problem):
             attn_blocks=jnp.zeros((), jnp.float32),
             kda_retention=jnp.zeros((self.cfg.kda_layers,), jnp.float32),
             kda_boundary_chunks=jnp.zeros((self.cfg.kda_layers,), jnp.float32),
+            conv_gain=jnp.zeros((self.cfg.conv_layers, 2), jnp.float32),
         )
 
     def evaluate(self, state: TokenLMState, pop: Any) -> Tuple[jax.Array, TokenLMState]:
@@ -134,5 +140,5 @@ class TokenLMProblem(Problem):
             generation=state.generation + 1, losses=losses, probe=out["probe"],
             held=out["held"], moved=out["moved"], imbalance=out["imbalance"],
             attn_blocks=out["attn_blocks"], kda_retention=out["kda_retention"],
-            kda_boundary_chunks=out["kda_boundary_chunks"],
+            kda_boundary_chunks=out["kda_boundary_chunks"], conv_gain=out["conv_gain"],
         )

@@ -154,6 +154,23 @@ def _flash_kernel(members=2, t=2048, heads=16, nope=128, rope=64, v=128):
                 i32(t), i32(t // bq), i32(t // bq))
 
 
+def _gqa_kernel(members=2, t=8192, heads=32, kv_heads=8, width=64):
+    """``gqa_flash_attention`` at the third language-model cell's shapes: two
+    members a call, a row of 8,192 tokens, 32 query heads on 8 key-value heads
+    of 64, bfloat16."""
+    from evox_tpu.kernels.gqa_flash_attention import gqa_block_sizes, gqa_flash_attention
+
+    bq, bk = gqa_block_sizes(t, heads, kv_heads, width)
+    fn = lambda q, k, v, doc, first, last: gqa_flash_attention(  # noqa: E731
+        q, k, v, doc, (first, last), heads=heads, kv_heads=kv_heads, scale=width**-0.5,
+        block_q=bq, block_k=bk, interpret=False,
+    )
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return fn, (bf16(members, t, heads * width), bf16(members, t, kv_heads * width), bf16(members, t, kv_heads * width),
+                i32(t), i32(t // bq), i32(t // bq))
+
+
 def _kda_kernel(members=2, t=2048, heads=32, width=128):
     """``kda_scan`` at the hybrid language-model cell's shapes: two members a
     call, 32 heads of 128 keys and values, bfloat16 operands, float32 decay."""
@@ -185,6 +202,7 @@ KERNELS = {
     "partial_topk-n4096-k128": _topk_kernel,
     "packed_dominance-n20000-m3": _dominance_kernel,
     "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
+    "gqa_flash_attention-m2-h32-kv8-t8192-d64": _gqa_kernel,
     "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,
     "kda_conv-m2-h32-t2048-w128-none": functools.partial(_kda_conv_kernel, None),
     "kda_conv-m2-h32-t2048-w128-l2": functools.partial(_kda_conv_kernel, "l2"),
@@ -283,6 +301,21 @@ def test_language_model_cell_compiles_for_v5e(topo, no_persistent_cache, monkeyp
     got = rehearse.rehearse(mf.load(), "moonlight_es_pop64_seq2k", topo)
     assert got["custom_calls"] == 5, got
     assert got["temp_bytes_per_device"] < 4_258_132_480, got  # the parent of PR 31, which wrote every assignment's row
+
+
+def test_lfm2_cell_compiles_for_v5e(topo, no_persistent_cache, monkeypatch):
+    """The third language-model cell's steady run loop at its real shapes
+    (rows of 8,192 tokens, a pair a chunk), with the bodies the chip takes:
+    the grouped-query kernel is there, once (one attention layer of five; the
+    plain body's scores of one pair would be 17.2 GB), and the program fits
+    the chip: 10,578,376,704 B at PR 34, of which the float32 centre twice."""
+    from benchmark import rehearse
+    from benchmark.lib import manifest as mf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = rehearse.rehearse(mf.load(), "lfm2_es_pop16_seq8k", topo)
+    assert got["custom_calls"] == 1, got
+    assert 0.25 * 16e9 < got["total_bytes_per_device"] < 16e9, got
 
 
 # --------------------------------------------------------------- four chips

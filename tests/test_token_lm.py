@@ -1,7 +1,8 @@
 """The token language model under low-rank OpenES, at a tiny size on the CPU:
 the program against the plain references (``benchmark/reference``) on seeded
-weights for both families (``deepseek_v3``: every layer MLA with RoPE;
-``kimi_linear``: KDA and unrotated MLA by the pattern), the properties the
+weights for the three families (``deepseek_v3``: every layer MLA with RoPE;
+``kimi_linear``: KDA and unrotated MLA by the pattern; ``lfm2_moe``: gated
+short convolutions and grouped-query attention, no shared expert), the properties the
 member model has to have, and ``LowRankOpenES`` against ``OpenES`` on
 materialised members."""
 
@@ -16,6 +17,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import kimi_linear_48b_a3b_es as ref_kimi
+from benchmark.reference import lfm2_24b_a2b_es as ref_lfm2
 from benchmark.reference import moonlight_16b_a3b_es as ref
 from evox_tpu import StdWorkflow
 from evox_tpu.algorithms.so.es import LowRankOpenES, OpenES
@@ -48,7 +50,18 @@ TINY_KIMI = dict(
                             short_conv_kernel_size=4),
     rank=1, noise_stdev=0.001, learning_rate=0.0005, probe_positions=64,
 )
-FAMILIES = {"deepseek_v3": (TINY, ref), "kimi_linear": (TINY_KIMI, ref_kimi)}
+# the cut of the lfm2_moe family (tests/benchmark_checks/test_lfm2_cell.py enters it): layers 1 to 5 of the pattern
+# counted from 0 (conv + dense MLP; attention, conv, conv, conv + experts), 4 query heads on 2 key-value heads of 16,
+# 3 taps, no shared expert
+TINY_LFM2 = dict(
+    model_type="lfm2_moe", hidden_size=64, num_attention_heads=4, num_key_value_heads=2, conv_L_cache=3,
+    conv_bias=False, intermediate_size=96, moe_intermediate_size=32, num_experts=2, num_experts_published=8,
+    experts_held=[0, 2], num_experts_per_tok=2, routed_scaling_factor=1, norm_topk_prob=True, use_expert_bias=True,
+    num_dense_layers=2, layer_types=["conv", "conv", "full_attention", "conv"] * 2, layers=5, layers_held=[1, 6],
+    vocab_size=32, norm_eps=1e-5, rope_parameters={"rope_theta": 1000000, "rope_type": "default"}, init_std=0.02,
+    rank=1, noise_stdev=0.001, learning_rate=0.0005, probe_positions=64,
+)
+FAMILIES = {"deepseek_v3": (TINY, ref), "kimi_linear": (TINY_KIMI, ref_kimi), "lfm2_moe": (TINY_LFM2, ref_lfm2)}
 TRAFFIC = dict(pop=8, seq_len=48, doc_len_median=12, doc_len_sigma=1.0, doc_len_min=4,
                rows_per_member=1)
 BLOCKS = {"expert_block_rows": 8, "chunk_pairs": 2, "attn_block_pairs": 2, "kda_block_pairs": 2}
@@ -59,13 +72,14 @@ SEED = 2**33 + 5
 def body(request, monkeypatch):
     """The two bodies of the token mixers: the plain ones, which the CPU
     backend takes (attention's scores in HBM, KDA's chunk arithmetic in XLA),
-    and the kernels (``flash_attention``, ``kda_scan``, interpreted here),
-    which the TPU backend takes at shapes they accept; the choice is steered
+    and the kernels (``flash_attention``, ``gqa_flash_attention``, ``kda_scan``,
+    interpreted here), which the TPU backend takes at shapes they accept; the choice is steered
     here, not by an option. The scan's chunks are 16 tokens here, so that a
     row of 48 carries its state across two chunk ends."""
     monkeypatch.setattr(lm, "KDA_CHUNK", 16)
     if request.param == "kernel":
         monkeypatch.setattr(lm, "_flash_blocks", lambda cfg, t: (8, 8))
+        monkeypatch.setattr(lm, "_gqa_blocks", lambda cfg, t: (8, 8))
         monkeypatch.setattr(lm, "_kda_kernel", lambda cfg: True)
     return request.param
 
@@ -97,16 +111,19 @@ def _snapshots(wf, key, steps=2):
             "probe": np.asarray(state.prob.probe),
             "held": np.asarray(state.prob.held),
             "kda_retention": np.asarray(state.prob.kda_retention),
+            "conv_gain": np.asarray(state.prob.conv_gain),
         })
     return snaps
 
 
-@pytest.mark.parametrize("family", list(FAMILIES))
-@pytest.mark.parametrize("rank,layers,steps", ((1, 5, 2), (2, 3, 1)))
+# rank 2 at three layers for the two older families; the third runs its five layers only (each case compiles a run loop)
+@pytest.mark.parametrize("rank,layers,steps,family", [
+    *((1, 5, 2, family) for family in FAMILIES), (2, 3, 1, "deepseek_v3"), (2, 3, 1, "kimi_linear")])
 def test_program_agrees_with_the_reference_in_float32(rank, layers, steps, family, body):
     """Logits, losses, routing and centre (for ``kimi_linear`` the hybrid
-    pattern at five layers, and what the KDA layers keep of their state),
-    through ``StdWorkflow.run``, a second generation from the first's centre."""
+    pattern at five layers, and what the KDA layers keep of their state; for
+    ``lfm2_moe`` the convolutions' gains), through ``StdWorkflow.run``, a
+    second generation from the first's centre."""
     tiny, reference = FAMILIES[family]
     config = dict(tiny, rank=rank, layers=layers)
     wf, key = _workflow(config, rank=rank)
@@ -125,6 +142,10 @@ def test_program_agrees_with_the_reference_in_float32(rank, layers, steps, famil
         assert snaps[0]["kda_retention"].shape == ({5: 4, 3: 3}[layers],)  # layer 4 of the pattern is MLA
         assert max(numbers[f"step{k}_retention_err"] for k in generations) < 1e-5
         assert np.all((0 < snaps[0]["kda_retention"]) & (snaps[0]["kda_retention"] < 1))
+    if family == "lfm2_moe":
+        assert snaps[0]["conv_gain"].shape == (4, 2) and np.all(snaps[0]["conv_gain"] > 0)
+        # float32 sums of squares in another order than the reference's
+        assert max(numbers[f"step{k}_conv_gain_err"] for k in generations) < 1e-5
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
@@ -241,21 +262,26 @@ def test_attn_blocks_is_the_visited_share_of_a_dense_causal_pass(body):
 SHARES = {
     "8_experts_top_2": ("deepseek_v3", "n_routed_experts", 8, 2, 2),
     "256_experts_top_8": ("kimi_linear", "num_experts", 256, 64, 8),
+    "64_experts_top_4_no_shared_expert": ("lfm2_moe", "num_experts", 64, 16, 4),
 }
 
 
 @pytest.mark.parametrize("case", list(SHARES))
 def test_the_shares_of_an_expert_layer_add_up(case):
     """The outputs of the expert layer for every share of the experts (0-1,
-    2-3, 4-5 and 6-7 of 8; four shares of 64 of a 256-wide router at top 8),
-    the shared MLP counted once, sum to the uncut reference's layer."""
+    2-3, 4-5 and 6-7 of 8; four shares of 64 of a 256-wide router at top 8;
+    experts 0-15, 16-31, 32-47, 48-63 of a 64-wide router at top 4, the
+    published proportion of ``lfm2_24b_a2b_es``), the shared MLP counted once
+    (``lfm2_moe`` has none to count: the routed parts alone add up), sum to
+    the uncut reference's layer."""
     family, held_key, width, share, top = SHARES[case]
     tiny, reference = FAMILIES[family]
-    top_key = "num_experts_per_tok" if family == "deepseek_v3" else "num_experts_per_token"
+    top_key = "num_experts_per_token" if family == "kimi_linear" else "num_experts_per_tok"
     tiny = {**tiny, held_key + "_published": width, top_key: top}
     whole = {**tiny, held_key: width, "experts_held": [0, width]}
     full = reference._init(whole, jax.random.PRNGKey(7))["layers"][1]
-    full.pop("attn", None), full.pop("kda", None)
+    for mixer in ("attn", "kda", "conv", "gqa"):
+        full.pop(mixer, None)
     pairs, t, d = 2, 16, tiny["hidden_size"]
     xn = jax.random.normal(jax.random.PRNGKey(8), (pairs, 2, t, d))
     no_noise = lambda tree: jax.tree.map(
@@ -271,16 +297,18 @@ def test_the_shares_of_an_expert_layer_add_up(case):
             cfg, p, no_noise(p), jnp.float32(0.0), xn, {**lm.DEFAULT_BLOCKS, **BLOCKS}
         )
         total = total + routed
-        assert int(jnp.sum(loads)) > 0
-    total = total + shared
+        assert int(jnp.sum(loads)) > 0 and (shared is None) == (family == "lfm2_moe")
+    if shared is not None:
+        total = total + shared
 
     # the uncut layer, plainly: every chosen expert of all of them
     x = xn.reshape(-1, d)
     score = jax.nn.sigmoid(x @ full["router"])
     _, idx = jax.lax.top_k(score + full["router_bias"], top)
     chosen = jnp.take_along_axis(score, idx, axis=-1)
-    weight = TINY["routed_scaling_factor"] * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    want = ref._swiglu(x, full["shared"])
+    renorm = jnp.sum(chosen, axis=-1, keepdims=True) + (1e-6 if family == "lfm2_moe" else 0.0)
+    weight = tiny["routed_scaling_factor"] * chosen / renorm
+    want = ref._swiglu(x, full["shared"]) if "shared" in full else 0.0
     for e in range(width):
         mine = jnp.sum(jnp.where(idx == e, weight, 0), axis=-1)
         want = want + mine[:, None] * ref._swiglu(x, jax.tree.map(lambda v: v[e], full["experts"]))
@@ -516,6 +544,21 @@ PUBLISHED = {
 }
 
 
+PUBLISHED["lfm2_24b_a2b_es"] = (
+    {
+        "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048, "intermediate_size": 11776,
+        "layer_types": ["conv", "conv", "full_attention", "conv"] * 10, "max_position_embeddings": 128000,
+        "model_type": "lfm2_moe", "moe_intermediate_size": 1536, "norm_eps": 1e-05, "norm_topk_prob": True,
+        "num_attention_heads": 32, "num_dense_layers": 2, "num_experts_per_tok": 4, "num_hidden_layers": 40,
+        "num_key_value_heads": 8, "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "routed_scaling_factor": 1, "use_expert_bias": True,
+    },
+    ["layers", "num_experts", "vocab_size"], (5, 16, 16384),
+    {"num_hidden_layers": 40, "num_experts": 64, "vocab_size": 65536, "parameters": "24B, 2B active"},
+    821_606_784, ("shared by 4 chips", "rows 0 to 16383 of the 65536", "pipeline stages", "layers 1 to 5 of 40"),
+)
+
+
 @pytest.mark.parametrize("name", list(PUBLISHED))
 def test_configuration_keeps_the_published_widths(name):
     """The configuration file holds every number of the catalog row's config
@@ -553,6 +596,85 @@ def test_the_hybrid_configuration_is_read_by_its_own_keys():
         LMConfig.from_dict({**config, "model_type": "llama"})
     with pytest.raises(KeyError):  # a layer held that the pattern does not name
         LMConfig.from_dict({**config, "linear_attn_config": {**config["linear_attn_config"], "kda_layers": [1, 2]}})
+
+
+REFUSED = {
+    # the expert layers held are conv, conv, conv: no attention layer of the period's one in four
+    "held_layers_break_the_pattern": ({"layers_held": [2, 7], "num_dense_layers": 4}, "break the pattern"),
+    "held_layers_are_not_the_depth": ({"layers_held": [1, 5]}, "layers_held"),
+    "held_layers_outside_the_model": ({"layers_held": [36, 41]}, "layers_held"),
+    "experts_held_disagrees_with_num_experts": ({"experts_held": [0, 8]}, "experts_held"),
+    "a_router_that_does_not_renormalise": ({"norm_topk_prob": False}, "norm_topk_prob"),
+    "a_router_without_its_bias": ({"use_expert_bias": False}, "use_expert_bias"),
+    "a_convolution_with_a_bias": ({"conv_bias": True}, "conv_bias"),
+    "a_scaled_rope": ({"rope_parameters": {"rope_theta": 1000000, "rope_type": "yarn"}}, "rope_type"),
+}
+
+
+@pytest.mark.parametrize("case", list(REFUSED))
+def test_from_dict_refuses_an_lfm2_moe_file_it_would_misread(case):
+    config = json.loads((ROOT / "benchmark/configs/lfm2_24b_a2b_es.json").read_text())
+    change, said = REFUSED[case]
+    with pytest.raises(ValueError, match=said):
+        LMConfig.from_dict({**config, **change})
+
+
+def test_the_lfm2_configuration_is_read_by_its_own_keys():
+    config = json.loads((ROOT / "benchmark/configs/lfm2_24b_a2b_es.json").read_text())
+    cfg = LMConfig.from_dict(config)
+    assert cfg.kinds == ("conv", "gqa", "conv", "conv", "conv") and cfg.conv_layers == 4 and cfg.kda_layers == 0
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.conv_size) == (32, 8, 64, 3)
+    assert cfg.first_k_dense_replace == 1 and cfg.expert_layers == 4  # of the two dense layers, layer 1 is held
+    assert (cfg.n_shared_experts, cfg.router_eps, cfg.rope_theta, cfg.rms_norm_eps) == (0, 1e-6, 1e6, 1e-5)
+    shapes = lm.param_shapes(cfg)
+    assert [sorted(set(layer) - {"mlp_norm"}) for layer in shapes["layers"]] == [
+        ["conv", "mlp"], ["experts", "gqa", "router", "router_bias"], *[["conv", "experts", "router", "router_bias"]] * 3]
+    count = lambda tree: sum(int(np.prod(v)) for v in jax.tree.leaves(tree, is_leaf=lm._is_shape))
+    assert count(shapes["layers"][0]["conv"]) == 16_783_360 + 2048  # ISSUE 34's reckoning, and the norm's gains
+    assert count(shapes["layers"][1]["gqa"]) == 10_485_760 + 128 + 2048
+    assert count(shapes["layers"][0]["mlp"]) == 72_351_744 and count(shapes["layers"][1]["experts"]) == 150_994_944
+    # the other families' models have none of this: their routers divide by the plain sum, beside a shared expert
+    moon = LMConfig.from_dict(json.loads((ROOT / "benchmark/configs/moonlight_16b_a3b_es.json").read_text()))
+    assert (moon.router_eps, moon.conv_layers, moon.num_key_value_heads) == (0.0, 0, 0) and moon.n_shared_experts == 2
+
+
+def test_the_gated_convolution_is_its_equations_member_by_member():
+    """``gated_conv`` against the layer written out for each member on its
+    dense weights (the taps' ``w + sign * scale * A B^T`` among them): ``[B,
+    C, u] = xn W_in``, the three taps of ``B * u`` with none reaching before
+    the document's start, ``(C * c) W_out``; the two members of a pair differ;
+    the sums of squares are the output's and the normed input's, over every
+    token and over the documents' first two."""
+    cfg = LMConfig.from_dict(TINY_LFM2)
+    pairs, t, d, scale = 2, 12, cfg.hidden_size, 0.3
+    p = init_params(cfg, jax.random.PRNGKey(31))["layers"][0]["conv"]
+    p["norm"] = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(32), (d,))
+    f = tree_factors(jax.random.PRNGKey(33), p, pairs, 1)
+    x = jax.random.normal(jax.random.PRNGKey(34), (pairs, 2, t, d))
+    pos = jnp.asarray([0, 1, 2, 3, 4, 0, 1, 0, 1, 2, 3, 4])
+    reach = pos[None, :] >= jnp.arange(cfg.conv_size)[:, None]
+    got, squares = lm.gated_conv(cfg, p, f, jnp.float32(scale), x, reach)
+    assert not np.allclose(got[:, 0], got[:, 1])
+    total_in = 0.0
+    for pair in range(pairs):
+        for i, sign in enumerate((1.0, -1.0)):
+            dense = {k: v + sign * scale * f[k][0][pair] @ f[k][1][pair].T for k, v in p.items() if v.ndim == 2}
+            xn = np.asarray(lm.rmsnorm(x[pair, i], p["norm"], cfg.rms_norm_eps), np.float64)
+            b, c, u = np.split(xn @ np.asarray(dense["in_proj"], np.float64), 3, axis=1)
+            z, y = b * u, np.zeros((t, d))
+            for at in range(t):
+                for j in range(cfg.conv_size):
+                    back = cfg.conv_size - 1 - j
+                    if pos[at] >= back:
+                        y[at] += np.asarray(dense["taps"][:, j], np.float64) * z[at - back]
+            # float32 against float64, at values of some tens: the perturbation is large here
+            np.testing.assert_allclose(got[pair, i], (c * y) @ np.asarray(dense["out_proj"], np.float64),
+                                       rtol=1e-5, atol=1e-5)
+            total_in += float(np.sum(xn * xn))
+    first = np.asarray(pos) < cfg.conv_size - 1  # the documents' first tokens, where the mask of the taps acts
+    xn = lm.rmsnorm(x, p["norm"], cfg.rms_norm_eps)
+    np.testing.assert_allclose(squares, [[float(jnp.sum(got * got)), float(jnp.sum(got[:, :, first] ** 2))],
+                                         [total_in, float(jnp.sum(xn[:, :, first] ** 2))]], rtol=1e-5)
 
 
 @pytest.mark.parametrize("factors", ("with_factors", "without_factors"))
@@ -605,6 +727,40 @@ def test_work_counts_of_the_cell():
     whole = dict(traffic, doc_len_median=1e9, doc_len_min=2048)
     assert work_lm.expected_attended(whole) == pytest.approx(1024.5)
     assert parts["total"] == pytest.approx(sum(v for k, v in parts.items() if k != "total"))
+
+
+def test_work_counts_of_the_lfm2_cell():
+    """460 MFLOP a token to three digits, by part (ISSUE 34's reckoning, with
+    the scores at the keys a query attends once the row cuts its last
+    document: 1,940.5, not the 2,421 of ``work_lm.expected_attended``)."""
+    from benchmark.lib import work_lm, work_lm_lfm2 as work
+
+    config = json.loads((ROOT / "benchmark/configs/lfm2_24b_a2b_es.json").read_text())
+    traffic = json.loads((ROOT / "benchmark/traffic/closed_pop16_seq8192_g1.json").read_text())
+    parts = work.lm_flops_per_token(config, traffic)
+    assert work.layers_held(config) == [("conv", True), ("full_attention", False), *[("conv", False)] * 3]
+    assert work.held_choices_per_token(config) == 1.0 and work.head_dim(config) == 64
+    assert parts["conv"] == 4 * (2 * (2048 * 6144 + 2048 * 2048) + 2 * 2048 * 3 + 2 * 2048)
+    attended = work.attended_keys_per_token(traffic)
+    assert attended == pytest.approx(1940.5, rel=1e-4) and work_lm.expected_attended(traffic) > 1.24 * attended
+    # a row of one document attends (T + 1) / 2 keys a query; documents much shorter than the row are hardly cut
+    assert work.attended_keys_per_token(dict(traffic, doc_len_median=1e9, doc_len_min=8192)) == pytest.approx(4096.5)
+    short = dict(traffic, doc_len_median=64, doc_len_sigma=0.5)
+    assert work.attended_keys_per_token(short) == pytest.approx(work_lm.expected_attended(short), rel=0.01)
+    assert parts["gqa_scores"] == pytest.approx(2 * 32 * (64 + 64) * attended)
+    assert parts["attention"] == pytest.approx(2 * (2 * 2048 * 2048 + 2 * 2048 * 512) + parts["gqa_scores"])
+    assert parts["dense_mlp"] == 2 * 3 * 2048 * 11776 and parts["head"] == 2 * 2048 * 16384
+    assert parts["experts"] == 4 * 1.0 * 2 * 3 * 2048 * 1536 and parts["router"] == 4 * 2 * 2048 * 64
+    rounded = {k: round(v / 1e6, 1) for k, v in parts.items()}
+    assert rounded == {"conv": 134.3, "attention": 36.9, "dense_mlp": 144.7, "router": 1.0, "experts": 75.5,
+                       "head": 67.1, "total": 459.5, "gqa_scores": 15.9}
+    assert parts["total"] == pytest.approx(sum(v for k, v in parts.items() if k not in ("total", "gqa_scores")))
+    assert work.lm_flops_per_eval(config, traffic) * 16 == pytest.approx(6.02e13, rel=2e-3)
+    # the kernel's floor is operations: 2.1e12 a generation, 10.6 ms at 197 TFLOP/s; its bytes 1.6 ms at 819 GB/s
+    assert work.gqa_kernel_bytes_per_token(config) == (2 * 2048 + 2 * 512) * 2 == 10_240
+    peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    assert work.gqa_kernel_least_seconds(config, traffic, 16, peak) == pytest.approx(0.010577, rel=1e-3)
+    assert 16 * 8192 * 10_240 / 819e9 < 0.0105
 
 
 def test_work_counts_of_the_hybrid_cell():
