@@ -46,8 +46,11 @@ head.
   sigmoid((xn Wga) Wgb)`` and ``Wo``. The recurrence is computed chunkwise and
   exactly (``kernels/kda_scan.py`` says how): on the TPU backend, where a
   head is a whole lane tile wide, by the ``kda_scan`` kernel (the state stays
-  in VMEM across the row's chunks), elsewhere by the same chunk arithmetic in
-  plain XLA; ``forward`` chooses by what it can observe, no option. By the
+  in VMEM across the row's chunks; a grid cell's two heads go through the
+  chunk arithmetic joined, their tokens masked from each other as two
+  documents' are, so that its products are 128 wide: each head's result is
+  what it is alone), elsewhere by the same chunk arithmetic, a head at a
+  time, in plain XLA; ``forward`` chooses by what it can observe, no option. By the
   same rule the way from each projection to the scan's operand (convolution,
   SiLU, L2 norm, the rounding) is one pass of the ``kda_conv`` kernel on the
   TPU backend and ``short_conv`` with the norms as float32 passes elsewhere.

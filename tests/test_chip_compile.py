@@ -203,7 +203,8 @@ KERNELS = {
     "packed_dominance-n20000-m3": _dominance_kernel,
     "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
     "gqa_flash_attention-m2-h32-kv8-t8192-d64": _gqa_kernel,
-    "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,
+    "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,  # two heads a grid cell, joined: (128, 128) products
+    "kda_scan-m2-h3-t512-k128-v128": functools.partial(_kda_kernel, t=512, heads=3),  # an odd count: a head a cell
     "kda_conv-m2-h32-t2048-w128-none": functools.partial(_kda_conv_kernel, None),
     "kda_conv-m2-h32-t2048-w128-l2": functools.partial(_kda_conv_kernel, "l2"),
     "kda_conv-m2-h32-t2048-w128-l2_scaled": functools.partial(_kda_conv_kernel, "l2_scaled"),

@@ -28,6 +28,7 @@ ROWS = {
     "a_document_a_token": (30, 1, 1, 1, 15),
     "a_row_no_multiple_of_the_chunk": (18, 3, 4, 16),
     "a_row_shorter_than_a_chunk": (5, 6),
+    "five_chunks": (30, 3, 47),
 }
 
 
@@ -102,6 +103,65 @@ def test_bfloat16_operands_stay_near_the_recurrence(body):
     assert got.dtype == jnp.bfloat16
     err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
     assert err.max() < 0.03 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("heads", [1, 2, 3, 4])
+def test_the_kernel_joins_two_heads_or_takes_one(heads):
+    """An even head count runs the chunk arithmetic on a grid cell's two
+    heads joined (2: one cell, 4: two), an odd one a head at a time (the
+    form the XLA path runs): both are the recurrence, at the kernel's own
+    chunk of four sub-blocks."""
+    args, _ = _inputs((70, 9, 40, 11), heads=heads, width=16, seed=4)
+    want = ks.kda_scan_reference(*args, heads=heads)
+    got = ks.kda_scan(*args, heads=heads, chunk=ks.KDA_CHUNK, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("body", list(BODIES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_a_head_takes_nothing_from_its_cells_other_head(body, dtype):
+    """The joined products add the other head's entries times exact zeros, or
+    make entries that a select drops: whatever head 1 (and 3) holds, large
+    finite values included (keys stay of unit length, as the model's are: the
+    triangular system's entries are what overflow first), heads 0 and 2 come
+    out bit for bit the same."""
+    heads, width = 4, 16
+    args, _ = _inputs((70, 9, 40, 11), heads=heads, width=width, dtype=dtype, seed=5)
+    base = BODIES[body](*args, heads=heads, chunk=ks.KDA_CHUNK)
+    odd = (jnp.arange(heads * width) // width) % 2 == 1  # the lanes of heads 1 and 3
+    q, k, v, g, beta, doc = args
+    fresh, _ = _inputs((70, 9, 40, 11), heads=heads, width=width, dtype=dtype, seed=6)
+    changed = [jnp.where(odd, 30.0 * fresh[0], q), jnp.where(odd, -fresh[1], k), jnp.where(odd, 1e4 * fresh[2], v),
+               jnp.where(odd, 3.0 * fresh[3], g), jnp.where(jnp.arange(heads) % 2 == 1, fresh[4], beta), doc]
+    got = BODIES[body](*changed, heads=heads, chunk=ks.KDA_CHUNK)
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    np.testing.assert_array_equal(np.asarray(got)[..., ~odd], np.asarray(base)[..., ~odd])
+    assert not np.array_equal(np.asarray(got)[..., odd], np.asarray(base)[..., odd])
+
+
+@pytest.mark.parametrize("decay", [(-30.0, -30.0), (-30.0, -1e-6), (-1.0, -1e-3), (-1e-3, -1e-6), (-1e-6, -1e-6)],
+                         ids=lambda d: f"{d[0]:g}_to_{d[1]:g}")
+def test_three_part_sums_are_the_full_precision_products(decay):
+    """``G`` by three bfloat16 passes against the float32 product at
+    ``HIGHEST``, on two heads' rows under one mask. The parts' products are
+    exact and their float32 sums nearly so (8-bit terms); the float32 product
+    rounds its running sum once a term, up to 63 times half an ulp of it: the
+    two differ by that (8 half-ulps of the sum of |g| seen), the three-part
+    one the nearer to the sum in float64. A masked term adds exactly zero."""
+    low, high = np.log(-decay[0]), np.log(-decay[1])
+    g = -jnp.exp(jax.random.uniform(jax.random.PRNGKey(7), (128, 24), minval=min(low, high) - 0.05, maxval=max(low, high)))
+    doc = np.repeat(np.arange(3), (50, 30, 48))
+    at = np.arange(128)
+    mask = (doc[:, None] == doc[None, :]) & (at[:, None] >= at[None, :]) & (at[:, None] // 64 == at[None, :] // 64)
+    got = np.asarray(ks._masked_sums(jnp.asarray(mask), g), np.float64)
+    full = np.asarray(ks._mm(jnp.asarray(mask, jnp.float32), g), np.float64)
+    exact = mask.astype(np.float64) @ np.asarray(g, np.float64)
+    scale = mask.astype(np.float64) @ np.abs(np.asarray(g, np.float64))
+    assert np.all(np.abs(got - full) <= 16 * 2.0**-24 * scale)
+    assert np.abs(got - exact).max() <= np.abs(full - exact).max()
+    poisoned = jnp.where((doc == 1)[:, None], -1e30, g)  # another document's decay is never summed in
+    again = np.asarray(ks._masked_sums(jnp.asarray(mask), poisoned))
+    np.testing.assert_array_equal(again[doc != 1], np.asarray(ks._masked_sums(jnp.asarray(mask), g))[doc != 1])
 
 
 def test_shapes_that_are_not_the_scans_are_refused():
