@@ -39,14 +39,23 @@ layer (core/xla_cost.py):
   as a Chrome trace-event JSON timeline (Perfetto / chrome://tracing),
   with TelemetryMonitor rings and farm health counters as counter tracks.
 
-The module also holds the one table of names the program writes into a
-profiler trace, always: ``scope`` (``jax.named_scope``: the layer each
-device operation belongs to, in its ``op_name``) and ``span``
-(``jax.profiler.TraceAnnotation``: the entry points' host time on the
-device trace's clock). The recorder's wall-clock bookkeeping runs on
-``time.perf_counter``, a clock the device does not share;
-``write_chrome_trace`` is the timeline for runs without the profiler, the
-profiler's trace the one on the device's clock.
+The module also holds the one table of names the program writes, always:
+``scope`` (``jax.named_scope``: the layer each device operation belongs to,
+in its ``op_name``) and ``span``, the one host primitive. A ``span`` writes
+twice: a ``jax.profiler.TraceAnnotation`` (the entry points' host time on
+the device trace's clock, kept only while a profiler session is on) and one
+record of the process's **host log**, kept whether a session is on or not:
+a bounded in-memory ring on ``time.perf_counter_ns``, read with
+:func:`host_records` and :func:`host_summary`. The log also takes what jax
+says it traced, lowered and compiled (one ``jax.monitoring`` listener), each
+under the entry point that was open when it happened. So the program has
+two host clocks that are one: the recorder's bookkeeping runs on
+``time.perf_counter`` (seconds), the host log on the same clock in
+nanoseconds, and the two can be overlaid; the device does not share it.
+``write_chrome_trace`` is the recorder's timeline for runs without the
+profiler, the profiler's trace the one on the device's clock, and a reader
+that holds both an ``evox:run`` record and its trace span (the benchmark's
+``lib/hostlog.py``) places the log on the trace's clock by their offset.
 
 ``run_report`` merges this host-side summary with the device counters of
 any attached monitor exposing ``report(mstate)`` (TelemetryMonitor) into
@@ -59,11 +68,15 @@ JSON-lines file.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import math
+import statistics
+import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -73,8 +86,13 @@ from .xla_cost import CostAnalyzer, abstract_signature, roofline_section
 __all__ = [
     "SCOPES",
     "SPANS",
+    "LOG_ONLY",
+    "HOST_LOG_LEN",
+    "HostRecord",
     "scope",
     "span",
+    "host_records",
+    "host_summary",
     "DispatchRecorder",
     "RetraceError",
     "instrument",
@@ -90,9 +108,10 @@ __all__ = [
 # ``op_name`` of every HLO operation traced under it, which the profiler
 # writes beside each device event (the ``tf_op`` stat). Host side: ``span``
 # writes onto the calling thread's line of the profiler's host plane (the
-# main thread's is named after the executable), on the device trace's clock.
-# The benchmark's readers (benchmark/lib/scoped.py) match these strings, so
-# they are written here once and nowhere else.
+# main thread's is named after the executable), on the device trace's clock,
+# and into the host log below. The benchmark's readers (benchmark/lib/
+# scoped.py, benchmark/lib/hostlog.py) match these strings, so they are
+# written here once and nowhere else.
 
 ASK = "evox.ask"
 EVALUATE = "evox.evaluate"
@@ -144,6 +163,21 @@ FETCH = "evox:fetch"
 
 SPANS = (RUN, RUN_PEEL, RUN_LOOP, STEP, INIT, CHECKPOINT_SAVE, HOST_EVAL, FETCH)
 
+# Names the host log alone carries (``span(name, annotate=False)`` and the
+# compile listener): the accepted benchmark compares the set of ``evox:``
+# annotations the entry points write with an exact table, so these wait for
+# a ``benchmark`` issue before they may be profiler spans too (ROADMAP B8).
+RUN_TRIP_COUNT = "evox:run/trip_count"  # in evox:run/loop: n_steps made a device scalar (a little program and a transfer)
+RUN_DISPATCH = "evox:run/dispatch"  # in evox:run/loop: the run loop's jitted call; ``cpu_ns`` is the calling thread's CPU time across it
+COMPILE_TRACE = "evox:compile/trace"  # jax traced a function to a jaxpr (``fun_name``); traces under a millisecond are left out
+COMPILE_LOWER = "evox:compile/lower"  # jaxpr to MLIR module; a Pallas kernel's Mosaic lowering happens here
+COMPILE_BACKEND = "evox:compile/backend"  # the backend compiled the module, or took it from the persistent cache
+COMPILE_CACHE_HIT = "evox:compile/cache_hit"  # the persistent cache held the module (an instant inside its backend record)
+
+LOG_ONLY = (
+    RUN_TRIP_COUNT, RUN_DISPATCH, COMPILE_TRACE, COMPILE_LOWER, COMPILE_BACKEND, COMPILE_CACHE_HIT,
+)
+
 
 class scope(contextlib.ContextDecorator):
     """Name the device operations traced inside the block, or inside the
@@ -169,13 +203,172 @@ class scope(contextlib.ContextDecorator):
         return self._cm.__exit__(*exc)
 
 
-def span(name: str, **args: Any) -> jax.profiler.TraceAnnotation:
-    """Mark a stretch of host time (``with span(RUN, n_steps=n):``): a
+# ------------------------------------------------------------- host log
+# One ring for the process. A record is appended when its span closes (a
+# compile's when jax reports it), so a parent follows its children in the
+# ring and ``id`` order is the order in which records were opened.
+
+HOST_LOG_LEN = 8192  # a cell's set-up is some 500 records (three a program), a 30 s window four a chunk
+
+
+class HostRecord(NamedTuple):
+    """One closed stretch of host time. ``parent`` is the ``id`` of the
+    innermost record that was open on the same thread when this one opened
+    (0: none), so the records of one ``run`` call share its ``evox:run``
+    record as root. Times are ``time.perf_counter_ns``."""
+
+    id: int
+    parent: int
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: int
+    args: dict
+
+
+class _OpenRecords(threading.local):
+    """The calling thread's open records, outermost first, as ``(id, name)``."""
+
+    def __init__(self):
+        self.stack: list = []
+
+
+_host_log: collections.deque = collections.deque(maxlen=HOST_LOG_LEN)
+_next_id = itertools.count(1)  # ``next`` on it is one bytecode: no lock
+_open = _OpenRecords()
+# jax reports every function it traces, jnp's own too (``add``, ``multiply``:
+# thousands a program, 5 to 400 us each, nested in the trace of the function
+# that calls them): logged, they would roll a process's set-up out of the
+# ring. A program's own functions take milliseconds to seconds to trace
+_TRACE_FLOOR_S = 1e-3
+_JAX_EVENTS = {  # the three with a length, and one instant
+    "/jax/core/compile/jaxpr_trace_duration": COMPILE_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": COMPILE_LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE_BACKEND,
+    "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
+}
+
+
+class span:
+    """Mark a stretch of host time (``with span(RUN, n_steps=n):``). The one
+    host primitive, and it always writes twice: a
     ``jax.profiler.TraceAnnotation`` on the profiler's host plane, on the
-    device trace's clock. With no profiler session active the annotation
-    records nothing: what is left is building the object and its
-    enter/exit, about 0.7 us a call (PERF.md section 6, PR 26)."""
-    return jax.profiler.TraceAnnotation(name, **args)
+    device trace's clock, which records only while a profiler session is on;
+    and, on exit, one :class:`HostRecord` of the host log, session or no
+    session, with no switch. ``annotate=False`` is the log-only form of the
+    same path, for the names of ``LOG_ONLY``. ``with span(...) as s`` hands
+    out the span, whose ``args`` the body may add to before it closes
+    (``evox:run/dispatch``'s ``cpu_ns``). A ``with`` puts no Python frame
+    between the caller and its body. Two clock reads, a push, a pop and an
+    append: PERF.md section 6, PR 36 has the cost."""
+
+    __slots__ = ("name", "args", "_annotation", "_id", "_parent", "_start")
+
+    def __init__(self, name: str, annotate: bool = True, **args: Any):
+        self.name = name
+        self.args = args
+        self._annotation = jax.profiler.TraceAnnotation(name, **args) if annotate else None
+
+    def __enter__(self) -> "span":
+        stack = _open.stack
+        self._parent = stack[-1][0] if stack else 0
+        self._id = next(_next_id)
+        stack.append((self._id, self.name))
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        # read last, beside the annotation's own start: the two differ by a
+        # constant, which is what lets a reader lay the log on the trace
+        self._start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _open.stack.pop()
+        # a plain tuple in ``HostRecord``'s order: building the named one
+        # here would be a quarter of the span's cost
+        _host_log.append(
+            (self._id, self._parent, self.name, self._start, end, threading.get_ident(), self.args)
+        )
+
+
+def _on_jax_event(event: str, duration_secs: float = 0.0, **kwargs: Any) -> None:
+    """jax's monitoring events into the host log: what it traced, lowered and
+    compiled, and each hit of the persistent cache. jax reports on
+    ``time.time()`` when the work is over, on the thread that did it: the end
+    is stamped here on the log's clock and the start is the end less the
+    event's length. The parent is that thread's open record, so the entry
+    point a compile fell in is one lookup."""
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    if name == COMPILE_TRACE and duration_secs < _TRACE_FLOOR_S:
+        return
+    end = time.perf_counter_ns()
+    stack = _open.stack
+    args = {"fun_name": kwargs["fun_name"]} if "fun_name" in kwargs else {}
+    _host_log.append(
+        (next(_next_id), stack[-1][0] if stack else 0, name,
+         end - int(duration_secs * 1e9), end, threading.get_ident(), args)
+    )
+
+
+# registered once, where the names are: a builder's compiles before the first
+# entry point belong to a process's set-up too
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def host_records(since_id: int = 0) -> List[HostRecord]:
+    """The host log's records opened after record ``since_id`` was (all that
+    the ring still holds, for 0), by ``id``. A span appears once it has
+    closed. The ring keeps the last ``HOST_LOG_LEN``: a log that long has
+    dropped its oldest."""
+    return sorted(HostRecord._make(r) for r in tuple(_host_log) if r[0] > since_id)
+
+
+def host_summary() -> dict:
+    """What the host log holds, reduced: ``spans`` maps each name to its
+    ``calls``, ``median_ms`` and ``longest_ms``; ``compiles`` lists every
+    backend compile (or retrieval from the persistent cache: ``cache_hit``)
+    with the function's name, its milliseconds, the record it fell under
+    (``under``) and that record's outermost ancestor (``entry_point``:
+    ``evox:run``, ``evox:step``, ``evox:init``; None outside every span or
+    where the ring has dropped it). A slow chunk or a recompile in a run
+    without a profiler session reads here: docs/GUIDE.md."""
+    records = host_records()
+    by_id = {r.id: r for r in records}
+    by_id.update((i, HostRecord(i, 0, n, 0, 0, 0, {})) for i, n in _open.stack)  # still open here
+    durations: Dict[str, list] = {}
+    for r in records:
+        durations.setdefault(r.name, []).append((r.end_ns - r.start_ns) / 1e6)
+    hits = [r for r in records if r.name == COMPILE_CACHE_HIT]
+    compiles = []
+    for r in records:
+        if r.name != COMPILE_BACKEND:
+            continue
+        under = root = by_id.get(r.parent)
+        while root is not None and root.parent:
+            root = by_id.get(root.parent)
+        compiles.append({
+            "fun_name": r.args.get("fun_name"),
+            "ms": (r.end_ns - r.start_ns) / 1e6,
+            "cache_hit": any(
+                h.thread == r.thread and r.start_ns <= h.end_ns <= r.end_ns for h in hits
+            ),
+            "under": under.name if under else None,
+            "entry_point": root.name if root else None,
+        })
+    return {
+        "spans": {
+            name: {"calls": len(ms), "median_ms": statistics.median(ms), "longest_ms": max(ms)}
+            for name, ms in sorted(durations.items())
+        },
+        "compiles": compiles,
+        "records": len(records),
+        "full": len(records) >= HOST_LOG_LEN,
+    }
 
 
 def sanitize_json(obj: Any) -> Any:
@@ -347,7 +540,9 @@ class DispatchRecorder:
     """Per-entry-point wall-clock registry; all accounting host-side.
 
     Args:
-        clock: monotonic seconds source (default ``time.perf_counter``).
+        clock: monotonic seconds source (default ``time.perf_counter``: the
+            host log's clock, there in nanoseconds, so a recorder's spans
+            and ``host_records()`` can be overlaid).
         strict_retrace: raise :class:`RetraceError` *before* dispatching a
             call whose abstract argument signature (leaf shapes/dtypes)
             would recompile an already-compiled entry point. Static-only

@@ -94,7 +94,10 @@ def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace (XLA/TPU timeline) of the region.
 
     The profiler starts with the options under which the program's host
-    spans (``core.instrument.span``: ``evox:run``, ``evox:run/loop`` ...)
+    spans (``core.instrument.SPANS``: ``evox:init``, ``evox:step``,
+    ``evox:run`` with ``evox:run/peel`` and ``evox:run/loop`` in it,
+    ``evox:checkpoint/save``, ``evox:executor/host_eval``, ``evox:fetch``;
+    the names of ``LOG_ONLY`` are in ``host_records()`` alone)
     and the device's operations share the file and the clock: host tracer
     level 2 (the program's spans and jax's own dispatch spans), the
     Python tracer off (every Python call as an event swamps the host

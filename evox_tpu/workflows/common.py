@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Sequence, Tuple
 
 import jax
@@ -12,8 +13,10 @@ from ..core.instrument import (
     CONSTRAIN,
     FIT_TRANSFORMS,
     MONITORS,
+    RUN_DISPATCH,
     RUN_LOOP,
     RUN_PEEL,
+    RUN_TRIP_COUNT,
     TELL,
     scope,
     span,
@@ -110,11 +113,20 @@ def fused_run(wf: Any, state: Any, n_steps: int) -> Any:
             state = wf._step_impl(state)
         return state
     if n_steps > 0:
-        # the span brackets the trip count's own little program and
-        # transfer as well as the loop's dispatch, so a trace says what
-        # each costs before the device starts on the chunk
+        # the span brackets the two things the host does before the device
+        # starts on the chunk, and the host log parts them (log-only
+        # records: core/instrument.py LOG_ONLY): the trip count's own
+        # little program and transfer, and the loop's jitted call, which
+        # has two speeds (PERF.md section 6, PR 34). CPU time near the
+        # dispatch's wall time says the thread computed (jax's Python
+        # path); far under it, that it was blocked
         with span(RUN_LOOP, n_steps=n_steps):
-            state = wf._run_loop(state, jnp.asarray(n_steps, dtype=jnp.int32))
+            with span(RUN_TRIP_COUNT, annotate=False):
+                n = jnp.asarray(n_steps, dtype=jnp.int32)
+            with span(RUN_DISPATCH, annotate=False) as dispatch:
+                cpu_ns = time.thread_time_ns()
+                state = wf._run_loop(state, n)
+                dispatch.args["cpu_ns"] = time.thread_time_ns() - cpu_ns
     return state
 
 

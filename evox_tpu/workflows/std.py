@@ -416,8 +416,8 @@ class StdWorkflow:
         workflows/ipop.py). Composes with ``checkpointer``/``resume_from``
         — a resumed run rebuilds the snapshot's population size first.
 
-        The call is one ``evox:run`` span on the profiler's host plane
-        (core/instrument.py); ``fused_run`` nests its parts in it.
+        The call is one ``evox:run`` span (core/instrument.py: on the
+        profiler's host plane and in the host log); ``fused_run`` nests in it.
         """
         with span(RUN, n_steps=int(n_steps)):
             if restarts is not None:
