@@ -2,13 +2,14 @@
 
 XLA's default lowerings handle most of the framework well; these kernels
 cover the cases where they don't. Each kernel ships with a pure-XLA
-fallback of identical semantics, selected explicitly via ``use_pallas``,
-and is unit-tested against the fallback in interpret mode so the CPU mesh
-CI exercises the kernel body too. Where measurement shows the fallback
-already at the hardware roofline (see each kernel's docstring), the
-fallback stays the default.
+fallback of identical semantics, and is unit-tested against the fallback
+in interpret mode so the CPU mesh CI exercises the kernel body too. Where
+measurement shows the fallback already at the hardware roofline (see each
+kernel's docstring), the fallback stays the default.
 
-- ``dominance``: the bit-packed Pareto-dominance build.
+- ``dominance``: the bit-packed Pareto-dominance build; ``packed_dominance``
+  chooses the ``dominance_pack`` kernel by backend (the TPU's), the XLA
+  build (``packed_dominance_reference``) elsewhere.
 - ``topk``: blockwise partial top-k selection.
 - ``rollout``, ``rollout_mlp``: whole-episode policy rollouts (small and
   VMEM-resident-weights policies).

@@ -136,8 +136,9 @@ def non_dominated_sort(
     n = fitness.shape[0]
     stop = n if until is None else min(until, n)
     n_words = (n + 31) // 32
-    # fused compare + pack + count: one Pallas pass on TPU (the bool (n, n)
-    # matrix never exists in HBM), identical-output XLA fallback elsewhere
+    # compare + pack + count: the ``dominance_pack`` kernel on the TPU writes
+    # the packed matrix once (the bool (n, n) matrix never exists in HBM),
+    # the identical-output XLA build elsewhere
     with scope(DOMINANCE_BUILD):
         dom_packed, count = packed_dominance(fitness)
 

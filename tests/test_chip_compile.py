@@ -15,6 +15,7 @@ lives in this one file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,10 +132,11 @@ def _topk_kernel(n=4096, k=128):
 
 
 def _dominance_kernel(n=20000, m=3):
+    """``packed_dominance`` as the chip runs it: the backend chooses the
+    ``dominance_pack`` kernel (the test says the backend is the TPU's)."""
     from evox_tpu.kernels.dominance import packed_dominance
 
-    fn = lambda f: packed_dominance(f, use_pallas=True)  # noqa: E731
-    return fn, (jax.ShapeDtypeStruct((n, m), jnp.float32),)
+    return packed_dominance, (jax.ShapeDtypeStruct((n, m), jnp.float32),)
 
 
 def _flash_kernel(members=2, t=2048, heads=16, nope=128, rope=64, v=128):
@@ -201,6 +203,7 @@ KERNELS = {
     "fused_rollout-h16-n65536x2-T200": _pendulum_kernel,
     "partial_topk-n4096-k128": _topk_kernel,
     "packed_dominance-n20000-m3": _dominance_kernel,
+    "packed_dominance-n100000-m3": functools.partial(_dominance_kernel, 100_000),  # the NSGA-II cell's merged n
     "flash_attention-m2-h16-t2048-qk192-v128": _flash_kernel,
     "gqa_flash_attention-m2-h32-kv8-t8192-d64": _gqa_kernel,
     "kda_scan-m2-h32-t2048-k128-v128": _kda_kernel,  # two heads a grid cell, joined: (128, 128) products
@@ -212,10 +215,12 @@ KERNELS = {
 
 
 @pytest.mark.parametrize("name", list(KERNELS))
-def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
+def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache, monkeypatch):
     """Mosaic accepts the kernel at its real width, and it IS the kernel:
     the compiled text holds the custom call (an envelope that hands the
-    shape to the XLA path must never pass for the kernel)."""
+    shape to the XLA path must never pass for the kernel). Code that asks
+    the backend is told it is the TPU's, as on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, args = KERNELS[name]()
     compiled = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
@@ -235,6 +240,22 @@ def test_flat_genome_reaches_the_kernel_uncut(one_chip, no_persistent_cache):
     fn, args = _walker_kernel(n)
     text = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile().as_text()
     assert any(shape in text for shape in cut)
+
+
+def test_dominance_matrix_is_written_once_on_the_chip(one_chip, no_persistent_cache, monkeypatch):
+    """At the NSGA-II cell's merged n = 100,000 the chip's build writes the
+    packed ``(3125, 100000)`` matrix once, in the layout the peel reads: no
+    stacked slabs (``[25,128,100000]``), no padded words (``[3200,100000]``),
+    no copy or slice whose result is the matrix, and temporaries far under
+    the 3.84 GB of the ``lax.map`` build it replaced (PR 37)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = _dominance_kernel(100_000)
+    compiled = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "[25,128,100000]" not in text and "[3200,100000]" not in text
+    assert not re.search(r"\[3125,100000\]\{[^}]*\} (copy|slice)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 500_000_000
 
 
 def test_topk_outside_envelope_is_not_the_kernel(one_chip, no_persistent_cache):

@@ -1,6 +1,8 @@
 """Pallas kernel tests — the kernel must be output-identical to its XLA
 fallback (run in interpreter mode on the CPU CI mesh, compiled on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,14 +34,29 @@ def test_packed_reference_matches_dominate_relation(n, m, seed):
     np.testing.assert_array_equal(np.asarray(count), dom.sum(axis=0))
 
 
-@pytest.mark.parametrize("n,m,seed", [(100, 3, 0), (256, 2, 1), (700, 5, 2), (1024, 10, 3)])
-def test_pallas_kernel_matches_reference(n, m, seed):
+@pytest.mark.parametrize(
+    "n,m,seed,tiles",
+    [
+        (100, 3, 0, {}),  # the chip's tiles, n below one in both axes
+        (256, 2, 1, {}),
+        (700, 5, 2, {}),
+        (1024, 10, 3, {}),
+        # small tiles: the exact-shape grid's edge cells
+        (300, 3, 4, dict(tile_i=256, tile_j=128)),  # n a multiple of neither 32, 128 nor a tile
+        (520, 2, 5, dict(tile_i=256, tile_j=1024)),  # above tile_i, below tile_j
+        (200, 4, 6, dict(tile_i=512, tile_j=128)),  # below tile_i, above tile_j
+        (1000, 3, 7, dict(tile_i=256, tile_j=512)),  # three of each, n = 1000 not a word's multiple
+        (1100, 3, 8, dict(tile_i=512, tile_j=1024)),  # lanes a cell in two passes, partial last word
+    ],
+)
+def test_pallas_kernel_matches_reference(n, m, seed, tiles):
     fit = jax.random.uniform(jax.random.PRNGKey(seed), (n, m))
     if n > 2:
         fit = fit.at[n // 2].set(fit[0]).at[:, 0].set(jnp.round(fit[:, 0], 1))
     p_ref, c_ref = packed_dominance_reference(fit)
     # interpret=True so the kernel body runs on the CPU CI backend
-    p_ker, c_ker = packed_dominance(fit, use_pallas=True, interpret=True)
+    p_ker, c_ker = packed_dominance(fit, interpret=True, **tiles)
+    assert p_ker.shape == ((n + 31) // 32, n) and p_ker.dtype == jnp.uint32
     np.testing.assert_array_equal(np.asarray(p_ref), np.asarray(p_ker))
     np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_ker))
 
@@ -48,27 +65,49 @@ def test_pallas_kernel_small_tiles_cover_padding():
     # n far below one tile exercises the +inf padding rows/columns
     fit = jax.random.uniform(jax.random.PRNGKey(9), (5, 3))
     p_ref, c_ref = packed_dominance_reference(fit)
-    p_ker, c_ker = packed_dominance(fit, use_pallas=True, interpret=True)
+    p_ker, c_ker = packed_dominance(fit, interpret=True)
     np.testing.assert_array_equal(np.asarray(p_ref), np.asarray(p_ker))
     np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_ker))
 
 
-def test_pallas_kernel_inf_fitness_rows():
+@pytest.mark.parametrize(
+    "n,inf_rows,tiles",
+    [
+        (64, (10, 40), {}),
+        # in the last, partial word (rows 288 to 299) and a tile's edge
+        (300, (255, 290, 299), dict(tile_i=256, tile_j=128)),
+    ],
+)
+def test_pallas_kernel_inf_fitness_rows(n, inf_rows, tiles):
     """Algorithms mask discarded individuals with +inf fitness rows; those
     rows must never dominate and padding must not confuse them."""
-    fit = jax.random.uniform(jax.random.PRNGKey(10), (64, 3))
-    fit = fit.at[10].set(jnp.inf).at[40].set(jnp.inf)
+    fit = jax.random.uniform(jax.random.PRNGKey(10), (n, 3))
+    for row in inf_rows:
+        fit = fit.at[row].set(jnp.inf)
     p_ref, c_ref = packed_dominance_reference(fit)
-    p_ker, c_ker = packed_dominance(fit, use_pallas=True, interpret=True)
+    p_ker, c_ker = packed_dominance(fit, interpret=True, **tiles)
     np.testing.assert_array_equal(np.asarray(p_ref), np.asarray(p_ker))
     np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_ker))
-    dom = _unpack(p_ref, 64)
-    assert not dom[10].any() and not dom[40].any()
+    dom = _unpack(p_ref, n)
+    assert not dom[list(inf_rows)].any()
 
 
-def test_non_dominated_sort_unchanged_by_build_path():
+@pytest.mark.parametrize("build", ["reference", "kernel"])
+def test_non_dominated_sort_unchanged_by_build_path(build, monkeypatch):
     """The sort's ranks are identical whichever build produced the packed
-    matrix (golden 11-point set from the operator tests plus random)."""
+    matrix: the backend's own (the XLA reference on the CPU) or the chip's
+    kernel, interpreted, on tiles small enough that n = 300 spans two of
+    them in both axes."""
+    import importlib
+
+    # the package exports a function of the module's name
+    non_dominate = importlib.import_module("evox_tpu.operators.selection.non_dominate")
+    if build == "kernel":
+        monkeypatch.setattr(
+            non_dominate,
+            "packed_dominance",
+            functools.partial(packed_dominance, interpret=True, tile_i=256, tile_j=256),
+        )
     fit = jax.random.uniform(jax.random.PRNGKey(11), (300, 3))
     ranks = np.asarray(non_dominated_sort(fit))
     # brute-force ranks from the dense dominance matrix
@@ -92,9 +131,9 @@ def test_non_dominated_sort_unchanged_by_build_path():
 def test_packed_dominance_rejects_bad_tiles():
     fit = jax.random.uniform(jax.random.PRNGKey(0), (16, 2))
     with pytest.raises(ValueError, match="tile_i"):
-        packed_dominance(fit, use_pallas=True, interpret=True, tile_i=48)
+        packed_dominance(fit, interpret=True, tile_i=48)
     with pytest.raises(ValueError, match="tile_j"):
-        packed_dominance(fit, use_pallas=True, interpret=True, tile_j=100)
+        packed_dominance(fit, interpret=True, tile_j=100)
 
 
 # ------------------------------------------------------------ fused rollout
