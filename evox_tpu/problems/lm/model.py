@@ -806,11 +806,31 @@ def mlp(p, f, scale, xn, block_pairs: int) -> jax.Array:
 
 def route(cfg: LMConfig, p, f, scale, xn) -> tuple:
     """The router over all the experts: for each token its chosen experts
-    ``(…, k)`` and their weights."""
+    ``(…, k)`` and their weights.
+
+    The ``k`` largest of ``v = s + b`` are taken in ``k`` passes over the
+    experts, in ``lax.top_k``'s order (descending, ties to the lower expert):
+    a pass finds the largest ``v`` left and the lowest expert that holds it,
+    and takes that expert out of ``v``. The chosen scores are then read by a
+    compare and select of every expert's index against the chosen ones.
+    Nothing is gathered (the chip runs a gather of scalars as a serial loop:
+    PERF.md section 5), and each chosen score is ``s``'s own bits whatever the
+    bias."""
     z = linear(xn, p["router"], f["router"], scale, F32)
     s = jax.nn.sigmoid(z)
-    _, idx = jax.lax.top_k(s + p["router_bias"], cfg.num_experts_per_tok)
-    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    width = s.shape[-1]
+    experts = jnp.arange(width, dtype=jnp.int32)
+    v, idx = s + p["router_bias"], []
+    for _ in range(cfg.num_experts_per_tok):
+        top = jnp.max(v, axis=-1, keepdims=True)
+        i = jnp.min(jnp.where(v == top, experts, width), axis=-1, keepdims=True)
+        idx.append(i)
+        v = jnp.where(experts == i, -jnp.inf, v)
+    idx = jnp.concatenate(idx, axis=-1)
+    chosen = jnp.sum(jnp.where(idx[..., None] == experts, s[..., None, :], 0.0), axis=-1)
+    # held, the sum below reads the chosen scores whole, as it read the gather's;
+    # unheld, the compiler folds the two sums into one and adds in another order
+    chosen = jax.lax.optimization_barrier(chosen)
     total = jnp.sum(chosen, axis=-1, keepdims=True)
     if cfg.router_eps:  # a family's own renormalisation
         total = total + cfg.router_eps

@@ -313,6 +313,22 @@ def test_routed_path_keeps_no_row_for_every_assignment_on_the_chip(one_chip, no_
     assert stats.temp_size_in_bytes < 150_000_000, stats
 
 
+def test_routed_path_gathers_no_score_on_the_chip(one_chip, no_persistent_cache):
+    """The chip's compiler, for the same chunk: no gather reads the router's
+    scores (an operand of ``(..., n_routed_experts)``). The chosen scores are
+    a compare and select over the experts; a gather of them was a serial
+    loop of 15 ns a scalar on the chip (PERF.md section 5). The token gather of
+    the experts' loop reads rows of ``hidden`` and stays."""
+    fn, args = _routed_path()
+    width = args[0]["router"].shape[-1]
+    text = jax.jit(fn).lower(*_shapes_on(args, one_chip)).compile().as_text()
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", text))
+    operands = [tuple(int(n) for n in shapes[name].split(",") if n)
+                for name in re.findall(r"\bgather\(%([\w.-]+)", text)]
+    assert operands, "the token gather is missing: the pattern no longer reads the compiled text"
+    assert not [o for o in operands if o and o[-1] == width], operands
+
+
 def test_language_model_cell_compiles_for_v5e(topo, no_persistent_cache, monkeypatch):
     """The cell's steady run loop at its real shapes, with the body the chip
     takes (``forward`` asks the backend, steered here): the attention kernel

@@ -8,6 +8,7 @@ materialised members."""
 
 from __future__ import annotations
 
+import dataclasses
 import json
 from pathlib import Path
 
@@ -423,6 +424,83 @@ def test_held_experts_is_the_plain_weighted_sum_over_the_held_experts(case, monk
     np.testing.assert_allclose(eager, got, rtol=0, atol=1e-6)
 
 
+# the three families' routers, (width, choices): Moonlight's, the hybrid cell's, the third cell's
+ROUTERS = {"deepseek_v3": (64, 6), "kimi_linear": (256, 8), "lfm2_moe": (64, 4)}
+ROUTER_T = 20
+
+
+@jax.jit
+def _scores(p, f, xn):
+    return jax.nn.sigmoid(lm.linear(xn, p["router"], f["router"], jnp.float32(0.5), jnp.float32))
+
+
+def _router_case(width: int, k: int, case: str, eps: float = 0.0):
+    """A router of ``width`` experts choosing ``k`` over ``2 * ROUTER_T``
+    tokens, with low-rank factors. ``"no_bias"``: a zero correction bias;
+    ``"bias"``: a nonzero one; ``"ties"``: experts 8 to 28 are seven triples
+    with one router column and one bias each (ties in ``s`` and in ``s +
+    b``), and experts 0 to 3 take biases of about 4, all four among every
+    token's chosen, with expert 1's and 3's set so that ``s + b`` ties
+    exactly, though ``s`` differs, with expert 0's for token 0 and with
+    expert 2's for token 1."""
+    cfg = dataclasses.replace(
+        LMConfig.from_dict(dict(TINY, n_routed_experts_published=width, num_experts_per_tok=k)), router_eps=eps)
+    keys = jax.random.split(jax.random.PRNGKey(width + k), 4)
+    d = cfg.hidden_size
+    xn = jax.random.normal(keys[0], (1, 2, ROUTER_T, d))
+    router = np.array(jax.random.normal(keys[1], (d, width)) * 0.5)
+    bias = np.zeros(width, np.float32) if case == "no_bias" else np.array(jax.random.normal(keys[2], (width,)) * 0.05)
+    if case == "ties":
+        for e in range(8, 29, 3):
+            router[:, e + 1] = router[:, e + 2] = router[:, e]
+            bias[e + 1] = bias[e + 2] = bias[e]
+    f = {"router": tree_factors(keys[3], {"r": jnp.zeros((d, width))}, 1, 1)["r"]}
+    p = {"router": jnp.asarray(router), "router_bias": jnp.asarray(bias)}
+    if case == "ties":
+        s = np.asarray(_scores(p, f, xn)).reshape(-1, width)
+        for token, (lo, hi) in enumerate([(0, 1), (2, 3)]):
+            bias[lo] = 4.0
+            v = np.float32(s[token, lo] + bias[lo])
+            near = np.float32(v - s[token, hi])
+            fits = [b for b in (near, np.nextafter(near, np.float32(9)), np.nextafter(near, np.float32(-9)))
+                    if np.float32(s[token, hi] + b) == v]
+            assert fits, "no bias ties the two"
+            bias[hi] = fits[0]
+        p["router_bias"] = jnp.asarray(bias)
+    return cfg, p, f, xn
+
+
+@pytest.mark.parametrize("eps", [0.0, 1e-6])
+@pytest.mark.parametrize("case", ["no_bias", "bias", "ties"])
+@pytest.mark.parametrize("family", list(ROUTERS))
+def test_route_is_top_k_and_the_chosen_scores_bit_for_bit(family, case, eps):
+    """``route``'s ``(idx, w)`` are, bit for bit, the formula: ``lax.top_k``
+    of ``s + b`` (descending, ties to the lower expert), the chosen ``s`` read
+    at those experts, their float32 sum in that order, ``router_eps`` and the
+    scaling, whatever the bias and wherever ``s + b`` ties."""
+    cfg, p, f, xn = _router_case(*ROUTERS[family], case, eps)
+
+    @jax.jit
+    def formula(p, f, xn):
+        s = _scores(p, f, xn)
+        _, idx = jax.lax.top_k(s + p["router_bias"], cfg.num_experts_per_tok)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        if eps:
+            total = total + eps
+        return idx, cfg.routed_scaling_factor * chosen / total
+
+    got = jax.jit(lambda p, f, xn: lm.route(cfg, p, f, jnp.float32(0.5), xn))(p, f, xn)
+    want = formula(p, f, xn)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(np.asarray(got[1]).view(np.uint32), np.asarray(want[1]).view(np.uint32))
+    if case == "ties":  # the planted ties are among the chosen and the lower expert comes first
+        idx = np.asarray(got[0]).reshape(-1, cfg.num_experts_per_tok)
+        for token, (lo, hi) in enumerate([(0, 1), (2, 3)]):
+            at = list(idx[token])
+            assert lo in at and hi in at and at.index(lo) + 1 == at.index(hi), at
+
+
 def _sub_jaxprs(jaxpr):
     yield jaxpr
     for eqn in jaxpr.eqns:
@@ -461,6 +539,18 @@ def test_the_routed_path_sorts_once_and_keeps_no_row_for_every_assignment():
     fill = chunks * TINY["n_routed_experts"] * (BLOCKS["expert_block_rows"] - 1)
     assert np.all(held > 0) and np.all(moved % BLOCKS["expert_block_rows"] == 0)
     assert np.all(held <= moved) and np.all(moved <= held + fill)
+
+
+@pytest.mark.parametrize("family", list(ROUTERS))
+def test_route_gathers_nothing(family):
+    """The law of the router: each chosen score is read by a compare and
+    select over the experts, never by a gather of scalars (which the chip
+    runs as a serial loop); so ``route``'s jaxpr, its low-rank term's
+    included, holds no ``gather``."""
+    cfg, p, f, xn = _router_case(*ROUTERS[family], "bias")
+    jaxpr = jax.make_jaxpr(lambda p, f, xn: lm.route(cfg, p, f, jnp.float32(0.5), xn))(p, f, xn).jaxpr
+    names = {e.primitive.name for j in _sub_jaxprs(jaxpr) for e in j.eqns}
+    assert "gather" not in names, names
 
 
 def test_packed_row_packs_documents_until_the_row_is_full():
