@@ -21,7 +21,7 @@ from benchmark.reference import ouro_2_6b_es as reference
 from evox_tpu.core.lowrank import tree_factors
 from evox_tpu.problems.lm import LMConfig, init_params
 from evox_tpu.problems.lm import model as lm
-from test_token_lm import BLOCKS, SEED, TINY_OURO, TRAFFIC, _snapshots, _workflow
+from _token_lm import BLOCKS, SEED, TINY_OURO, TRAFFIC, _snapshots, _workflow
 
 ROOT = Path(__file__).resolve().parents[1]
 CONFIG = json.loads((ROOT / "benchmark/configs/ouro_2_6b_es.json").read_text())

@@ -114,7 +114,12 @@ def test_pod_sigkill_mid_chunk_detect_reform_resume():
     detected within the deadline, classified worker_dead with the dead
     peer named in the census, and the pod re-forms at n-1 resuming the
     uninjured trajectory from the newest barrier."""
-    s = dryrun_multihost(2, chaos="sigkill_mid_chunk", chaos_opts=_OPTS)
+    # each leg's wait is bounded at twice what a passing scenario of this
+    # size takes in all (the slow-marked sigkill_pre_barrier: about 20 s on
+    # an 8-core CPU host), so a survivor held in jaxlib's shutdown barrier
+    # (ROADMAP D1) fails the test within 40 s, not after the coordination
+    # service's heartbeat timeout of about 100 s
+    s = dryrun_multihost(2, chaos="sigkill_mid_chunk", chaos_opts=_OPTS, timeout=40.0)
     assert s["victim_rc"] == -9  # a real SIGKILL, not a polite exit
     assert s["detections"][0]["census"]["dead"] == [s["victim"]]
     _assert_crash_law(s, "worker_dead")
